@@ -9,9 +9,6 @@ Run: python examples/serving/personalized_adapters.py
 """
 import http.client
 import json
-import os
-
-os.environ.setdefault("FEDML_TPU_PLATFORM", "cpu")
 
 import jax
 import jax.numpy as jnp

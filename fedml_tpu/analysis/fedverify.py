@@ -248,6 +248,9 @@ def parse_collectives(hlo: str,
     (docs/COLLECTIVE_PRECISION.md)."""
     ops: List[CollectiveOp] = []
     for line in hlo.splitlines():
+        # tuple shapes carry ``/*index=5*/`` markers whose ``=`` would cut
+        # the result segment short
+        line = re.sub(r"/\*.*?\*/", "", line)
         m = _COLLECTIVE_RE.search(line)
         if m is None:
             continue
@@ -271,8 +274,18 @@ def parse_collectives(hlo: str,
             else:
                 groups = []
         axis = classify_groups(groups, mesh_shape)
-        operand_bytes = _shape_nbytes(operand_seg)
         result_bytes = _shape_nbytes(result_seg)
+        if _SHAPE_RE.search(operand_seg):
+            operand_bytes = _shape_nbytes(operand_seg)
+        else:
+            # the installed XLA prints operands by name only: recover the
+            # operand bytes from the result (a scatter's operand is the
+            # result times the group size, a gather's the result over it;
+            # every other collective preserves the shape)
+            gsize = max((len(g) for g in groups), default=1)
+            operand_bytes = {"reduce-scatter": result_bytes * gsize,
+                             "all-gather": result_bytes // gsize}.get(
+                                 kind, result_bytes)
         nbytes = result_bytes if kind == "all-gather" else operand_bytes
         ops.append(CollectiveOp(
             kind=kind, axis=axis, nbytes=nbytes,

@@ -1,6 +1,6 @@
 """Host/system introspection for agents (reference ``comm_utils/
 sys_utils.py`` — GPU inventory via nvidia-smi, versions, env collection).
-TPU-era: accelerator inventory from jax, cpu/mem from /proc.
+TPU-era: accelerator inventory from the PCI bus, cpu/mem from /proc.
 """
 
 from __future__ import annotations
@@ -8,34 +8,22 @@ from __future__ import annotations
 import os
 import platform
 import sys
-import threading
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 
-def _probe_accelerator(timeout_s: float) -> Tuple[str, int, Optional[str]]:
-    """Query jax devices in a side thread so a wedged accelerator runtime
-    (e.g. an unreachable TPU tunnel) degrades the inventory to CPU instead
-    of hanging the agent."""
-    result: Dict[str, Any] = {}
+def _accelerator_inventory() -> Tuple[str, int]:
+    """Count this host's TPU chips WITHOUT creating a jax backend.
 
-    def probe():
-        try:
-            import jax
-            devs = jax.devices()
-            result["platform"] = devs[0].platform if devs else "none"
-            result["num_chips"] = len(devs)
-            result["jax_version"] = jax.__version__
-        except Exception:
-            result["platform"] = "none"
-            result["num_chips"] = 0
-
-    t = threading.Thread(target=probe, daemon=True)
-    t.start()
-    t.join(timeout=timeout_s)
-    if t.is_alive():  # runtime wedged — report no accelerator
-        return "none", 0, None
-    return (result.get("platform", "none"), result.get("num_chips", 0),
-            result.get("jax_version"))
+    An agent is a launcher: the jobs it starts are the processes that use
+    the chips, and a chip belongs to one process at a time — so the agent
+    itself never initializes jax's backend (docs/ARCHITECTURE.md "Devices
+    and processes").  The chips are counted the way jax itself decides
+    whether a TPU is there: PCI ids in sysfs.  A scan that finds none says
+    nothing about other accelerators, or about a sysfs this process cannot
+    see, so it reports ``"unknown"`` rather than "no accelerator"."""
+    from jax._src import hardware_utils
+    n_chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    return ("tpu", n_chips) if n_chips else ("unknown", 0)
 
 
 def get_sys_runner_info() -> Dict[str, Any]:
@@ -54,12 +42,9 @@ def get_sys_runner_info() -> Dict[str, Any]:
                     info["mem_available_bytes"] = int(line.split()[1]) * 1024
     except OSError:
         pass
-    timeout_s = float(os.environ.get("FEDML_TPU_DEVICE_PROBE_TIMEOUT", "15"))
-    platform_name, num_chips, jax_version = _probe_accelerator(timeout_s)
-    info["accelerator"] = platform_name
-    info["num_chips"] = num_chips
-    if jax_version:
-        info["jax_version"] = jax_version
+    import jax          # the import creates no backend
+    info["accelerator"], info["num_chips"] = _accelerator_inventory()
+    info["jax_version"] = jax.__version__
     try:
         import fedml_tpu
         info["fedml_tpu_version"] = fedml_tpu.__version__

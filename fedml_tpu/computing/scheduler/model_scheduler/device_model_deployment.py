@@ -40,9 +40,12 @@ class WorkerProcess:
         self.cache = cache
         port_file = os.path.join(
             tempfile.mkdtemp(prefix="fedml_worker_"), "port")
-        env = dict(os.environ)
-        env.setdefault("FEDML_TPU_PLATFORM", "cpu")  # workers shouldn't
-        # grab the accelerator unless the predictor asks for it
+        # replicas are HOST processes: the autoscaler runs several beside a
+        # gateway whose own process may hold the chip, and a chip belongs
+        # to one process at a time.  A predictor that needs the chip is
+        # deployed with mode="thread", inside the one process that owns it
+        # (docs/ARCHITECTURE.md "Devices and processes").
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         self.proc = subprocess.Popen(
             [sys.executable, "-m",
              "fedml_tpu.computing.scheduler.model_scheduler.worker_main",

@@ -31,15 +31,13 @@ class DeviceResource:
 
 def local_inventory(device_id: int = 0) -> DeviceResource:
     """Inventory of this host, built from the same introspection the agents
-    report (``comm_utils.sys_utils.get_sys_runner_info`` — accelerator probe
-    timeout-guarded there)."""
+    report (``comm_utils.sys_utils.get_sys_runner_info`` — the chips are
+    counted there without creating a jax backend)."""
     from ..comm_utils.sys_utils import get_sys_runner_info
     info = get_sys_runner_info()
-    platform = str(info.get("accelerator", "none"))
-    platform = platform.upper() if platform != "none" else "CPU"
-    num_chips = int(info.get("num_chips", 0)) if platform != "CPU" else 0
     return DeviceResource(
-        device_id=device_id, num_chips=num_chips, device_type=platform,
+        device_id=device_id, num_chips=int(info["num_chips"]),
+        device_type=str(info["accelerator"]).upper(),
         num_cpus=int(info.get("cpu_count", 1)),
         mem_bytes=int(info.get("mem_total_bytes", 0)))
 

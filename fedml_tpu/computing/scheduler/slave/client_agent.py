@@ -128,7 +128,12 @@ class FedMLClientAgent:
                      logf) -> subprocess.Popen:
         """Run the entry script with its exit code mirrored to ``run.rc``
         in the workspace — a pid-adopted orphan's true exit code is
-        unknowable across the reparent, so the job persists it itself."""
+        unknowable across the reparent, so the job persists it itself.
+
+        The job is the process that uses the chip; the agent that starts
+        it never creates a jax backend (one process for each chip)."""
+        from ....device import require_chip_free
+        require_chip_free("the device agent")
         with open(os.path.join(ws, "entry.sh"), "w") as f:
             f.write(entry if entry.endswith("\n") else entry + "\n")
         cmd = "bash entry.sh; rc=$?; echo $rc > run.rc; exit $rc"

@@ -8,6 +8,10 @@ respawns it whenever it dies: crash (any rc) → respawn with backoff, up to
 respawn with the staged upgrade dir prepended to ``PYTHONPATH``.  Run
 recovery on the agent side (``FedMLClientAgent.recover_runs``) re-adopts or
 respawns the jobs the dead agent stranded.
+
+Daemon and agent are both launchers and stay off jax: only the jobs the
+agent starts create a backend (one process for each chip —
+docs/ARCHITECTURE.md "Devices and processes").
 """
 
 from __future__ import annotations

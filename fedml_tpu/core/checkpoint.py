@@ -136,14 +136,20 @@ class RoundCheckpointer:
         step = round_idx if round_idx is not None else self.mngr.latest_step()
         if step is None:
             return None
-        meta = self.mngr.item_metadata(step)
-        if not (isinstance(meta, dict) and "state" in meta):
+        meta = self._item_tree(step)
+        if "state" not in meta:
             return None
         template = jax.tree_util.tree_map(
             lambda m: np.zeros(m.shape, m.dtype), meta["state"])
         restored = self.mngr.restore(
             step, args=ocp.args.StandardRestore({"state": template}))
         return restored["state"]
+
+    def _item_tree(self, step: int) -> dict:
+        """The saved composite's structure with an ``ArrayMetadata``
+        (shape, dtype) at every leaf — what a reader that was not the
+        writer builds its restore template from."""
+        return self.mngr.item_metadata(step).tree
 
     def _restore_into_store(self, step: int, state_template: Any, store):
         """Store-backed restore: the ServerState comes from orbax against
@@ -156,9 +162,9 @@ class RoundCheckpointer:
         comp = {"state": state_template}
         legacy_key = None
         if not os.path.exists(sidecar):
-            meta = self.mngr.item_metadata(step)
+            meta = self._item_tree(step)
             for key in ("client_table", "client_state"):
-                if isinstance(meta, dict) and key in meta:
+                if key in meta:
                     legacy_key = key
                     comp[key] = jax.tree_util.tree_map(
                         lambda m: np.zeros(m.shape, m.dtype), meta[key])

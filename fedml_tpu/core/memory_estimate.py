@@ -278,11 +278,15 @@ def estimate_round_footprint(lo: MeshStateLayout, *,
     one gathered cohort per fused round: XLA hoists the loop-invariant
     dataset gather out of the scan, materializing every round's cohort
     tensors at once (fedverify's census of the compiled block pinned
-    this — the block's temp plane is ~K cohorts, not 1).  Errs high by
-    the layout's ``safety`` like every estimate here."""
+    this — the block's temp plane is ~K cohorts, not 1).  A pipeline
+    layout (stage factor > 1) holds one more cohort copy: the
+    microbatched view the GPipe schedule scans over (the census of the
+    compiled 3-D round under jax 0.9.0 pinned this).  Errs high by the
+    layout's ``safety`` like every estimate here."""
     st = estimate_mesh_state_memory(lo)
     k = max(1, int(rounds_fused))
-    work = (2.0 + float(k)) * float(cohort_bytes) * lo.safety
+    copies = 2.0 + float(k) + (1.0 if lo.n_stage_shards > 1 else 0.0)
+    work = copies * float(cohort_bytes) * lo.safety
     members = max(1, int(members))
     total = members * (st["total"] + work) + float(data_bytes)
     return {

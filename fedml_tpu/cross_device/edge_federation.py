@@ -290,6 +290,10 @@ def build_client_binary() -> str:
             or any(os.path.getmtime(s) > os.path.getmtime(out)
                    for s in srcs)
             or not _loads_here()):
-        subprocess.run(["g++", "-O2", "-std=c++17", *srcs, "-o", out],
+        # build beside the target and rename: two processes that find no
+        # binary (test workers) must not write one file at once
+        tmp = f"{out}.{os.getpid()}.tmp"
+        subprocess.run(["g++", "-O2", "-std=c++17", *srcs, "-o", tmp],
                        check=True)
+        os.replace(tmp, out)
     return out

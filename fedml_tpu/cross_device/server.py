@@ -85,6 +85,8 @@ class ServerMNN:
             export_client_data(path, self.dataset.train_x[idx],
                                self.dataset.train_y[idx])
             if spawn:
+                # native C++ clients: they never load jax, so this server
+                # process may hold the chip while they run
                 procs.append(subprocess.Popen(
                     [binary, work_dir, str(c), path, "20"],
                     stderr=subprocess.DEVNULL))

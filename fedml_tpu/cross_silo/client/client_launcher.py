@@ -7,6 +7,11 @@ with rank/role passed by environment (``FEDML_TPU_RANK`` / ``FEDML_TPU_ROLE``
 / ``FEDML_TPU_RUN_ID``), which is how multi-host deployments launch too —
 the entry script calls ``fedml_tpu.init()`` and the comm backend (filestore /
 gRPC / MQTT) rendezvouses by run_id.
+
+The launcher itself stays off jax: its children create the backends, and a
+chip belongs to one process at a time (``device.require_chip_free``).  On a
+host whose chips do not cover every participant, the caller pins the rest
+to the host with ``extra_env={"JAX_PLATFORMS": "cpu"}``.
 """
 
 from __future__ import annotations
@@ -54,6 +59,8 @@ class CrossSiloLauncher:
         return proc
 
     def launch(self) -> None:
+        from ...device import require_chip_free
+        require_chip_free("CrossSiloLauncher")
         self.procs = [self._spawn(0, "server")] + [
             self._spawn(r, "client") for r in self.client_ranks]
 

@@ -153,7 +153,14 @@ class FedLLMAPI:
         if mesh is not None:
             self.global_lora = jax.device_put(self.global_lora,
                                               replicated(mesh))
-        self._round_fn = jax.jit(self._build_round_fn())
+        # on a mesh, pin the merged adapters (and the loss) back to the
+        # replicated resting placement: left to GSPMD they come out sharded
+        # over ``model``, and round 1 then compiles a second program for the
+        # new input layout
+        self._round_fn = jax.jit(
+            self._build_round_fn(),
+            out_shardings=(None if mesh is None
+                           else (replicated(mesh), replicated(mesh))))
 
     # -- pure round --------------------------------------------------------
     def _build_round_fn(self):

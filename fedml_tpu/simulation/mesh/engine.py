@@ -359,14 +359,14 @@ def _make_mesh_round_core(trainer: LocalTrainer, server_opt: ServerOptimizer,
             merge_scatter, mesh=mesh,
             in_specs=(state_spec, shard, shard, shard, shard),
             out_specs=(state_spec, shard, P()),
-            check_vma=False, auto=layout.auto_axes,
+            check_vma=False, axis_names=layout.manual_axes,
         )
     else:
         sharded_merge = jax.shard_map(
             merge_replicated, mesh=mesh,
             in_specs=(state_spec, shard, shard, shard),
             out_specs=(state_spec, P()),
-            check_vma=False, auto=layout.auto_axes,
+            check_vma=False, axis_names=layout.manual_axes,
         )
 
     def assemble_metrics(mraw, old_params, new_params, x, y):

@@ -75,17 +75,19 @@ class MeshLayout:
                 "a mesh with n_stage_shards > 1 needs a staged model: "
                 "stage_leaves is empty (use model='pipe_mlp' or any "
                 "FlaxModel carrying a PipelineDef — docs/PIPELINE.md)")
-        #: shard_map axes GSPMD partitions automatically in the MERGE
-        #: program (docs/MESH_2D.md); empty on the 1-D layout so the
-        #: historical fully-manual program is byte-identical.  The train
-        #: phase on the pipeline layout does NOT consult this — it runs
-        #: fully manual (module docstring).
+        #: axes the MERGE program's ``shard_map`` is manual over
+        #: (``axis_names=``); the rest — ``model`` on the 2-D layout,
+        #: ``stage`` too on the pipeline layout — GSPMD partitions
+        #: automatically (docs/MESH_2D.md).  On the 1-D layout this is every
+        #: mesh axis: the fully-manual program.  The train phase on the
+        #: pipeline layout does NOT consult this — it runs fully manual
+        #: (module docstring).
         auto = set()
         if self.two_d:
             auto.add(MODEL_AXIS)
         if self.pipeline:
             auto.add(STAGE_AXIS)
-        self.auto_axes = frozenset(auto)
+        self.manual_axes = frozenset(mesh.axis_names) - auto
         self.flat_multiple = (self.n_client_shards * self.n_stage_shards
                               * self.n_model_shards)
         # -- shard_map PartitionSpecs (manual axes only) -------------------

@@ -1,7 +1,6 @@
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["FEDML_TPU_PLATFORM"] = "cpu"
 import fedml_tpu
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P

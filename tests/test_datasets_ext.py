@@ -192,7 +192,7 @@ def test_leaf_char_encoding(tmp_path):
 def test_digits_real_data_learns():
     """REAL data end-to-end (sklearn digits): hetero-partitioned FedAvg LR
     must clearly learn — the in-image accuracy-parity workload (MNIST pixels
-    aren't downloadable here; BASELINE.md records the full curve)."""
+    aren't downloadable here)."""
     import fedml_tpu
     from fedml_tpu.arguments import load_arguments
     from fedml_tpu import data as data_mod, device as device_mod, model as model_mod

@@ -90,6 +90,27 @@ def test_parse_collectives_census():
     assert by_kind["collective-permute"].nbytes == 16
 
 
+def test_parse_collectives_operands_by_name():
+    """The installed XLA prints operands by name only and combines
+    reductions into one tuple-shaped op whose ``/*index=5*/`` markers
+    carry an ``=``: bytes come from the result shapes."""
+    hlo = (
+        "  %all-reduce = (f32[10]{0}, f32[], f32[784,10]{1,0}, f32[], "
+        "f32[], /*index=5*/f32[]) all-reduce(%a, %b, %c, %d, %e, "
+        "/*index=5*/%f), channel_id=1, "
+        "replica_groups={{0,1,2,3,4,5,6,7}}, to_apply=%region\n"
+        "  %reduce_scatter.7 = f32[982]{0} reduce-scatter(%fusion), "
+        "channel_id=1, replica_groups={{0,1,2,3,4,5,6,7}}, "
+        "dimensions={0}, to_apply=%region\n"
+        "  %all-gather.2 = f32[7840]{0} all-gather(%fusion.3), "
+        "channel_id=13, replica_groups=[1,8]<=[8], dimensions={0}\n")
+    by_kind = {o.kind: o for o in fv.parse_collectives(hlo, (8, 1))}
+    assert by_kind["all-reduce"].nbytes == (10 + 784 * 10 + 4) * 4
+    assert by_kind["reduce-scatter"].nbytes == 982 * 8 * 4
+    assert by_kind["all-gather"].nbytes == 7840 * 4
+    assert by_kind["all-gather"].operand_bytes == 7840 * 4 // 8
+
+
 def test_parse_io_aliases_nested_braces():
     # the alias map nests {} (the empty output-shape-index tuple): a
     # naive first-} regex sees only the first entry
@@ -309,21 +330,20 @@ def test_gate_covers_every_program_family(verified):
 # -- 3. lowering-level mutants ----------------------------------------------
 
 def test_injected_rereplication_mutant_fails():
-    """The PR 6 bug class, re-injected: with the layout's resting-
-    placement pins disabled, GSPMD re-replicates the model factor of the
-    flat aux state on round exit — the checker MUST flag it."""
+    """The PR 6 bug class, re-injected: the flat aux state leaves the
+    round fully replicated instead of on its resting placement — the
+    checker MUST flag it.  (Under jax 0.9.0 merely dropping the layout's
+    pins no longer re-replicates: sharding propagation keeps the state
+    where it came in.  So the mutant pins it replicated.)"""
     from fedml_tpu.simulation.mesh.layout import MeshLayout
     orig_cs = MeshLayout.constrain_state
-    orig_cp = MeshLayout.constrain_params
     MeshLayout.constrain_state = \
-        lambda self, state, scatter, quantized: state
-    MeshLayout.constrain_params = lambda self, params: params
+        lambda self, state, scatter, quantized: self.replicate_leaves(state)
     try:
         rep = fv.build_mesh2d_scatter()
     finally:
         MeshLayout.constrain_state = orig_cs
-        MeshLayout.constrain_params = orig_cp
-    assert rep.rereplicated, "constrain_state off must re-replicate"
+    assert rep.rereplicated, "a replicated exit must be seen"
     assert any("opt_state" in p for p in rep.rereplicated)
     entry = fv.load_manifest()["programs"]["mesh2d_scatter"]
     rules = _rules(fv.run_checks(rep, entry))

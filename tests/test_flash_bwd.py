@@ -96,7 +96,7 @@ def _walk_dots(jaxpr, out):
 
 
 def test_bf16_score_dots_accumulate_f32():
-    """Round-3 TPU regression (tools/tpu_blockwise_bisect.py): with bf16
+    """Round-3 TPU regression: with bf16
     inputs, the attention dots must request f32 accumulation
     (preferred_element_type) — a bf16-rounded score matrix through the
     transposed scan produced NaN gradients on real TPU v5e while CPU bf16

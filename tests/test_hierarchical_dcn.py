@@ -32,9 +32,8 @@ def test_hierarchical_mesh_intra_silo_grpc_cross_silo(tmp_path):
             # the intra-silo data-parallel mesh
             os.environ["XLA_FLAGS"] = \\
                 "--xla_force_host_platform_device_count=4"
-        os.environ["FEDML_TPU_PLATFORM"] = "cpu"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import jax
-        jax.config.update("jax_platforms", "cpu")
         if role == "client":
             jax.config.update("jax_num_cpu_devices", 4)
 

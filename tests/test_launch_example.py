@@ -13,7 +13,7 @@ def test_hello_job_launch():
     job = os.path.join(os.path.dirname(__file__), "..", "examples", "launch",
                        "hello_job.yaml")
     run = api.launch_job(job, wait=True, timeout_s=600,
-                         env={"FEDML_TPU_PLATFORM": "cpu"})
+                         env={"JAX_PLATFORMS": "cpu"})
     try:
         assert run.status == "FINISHED", (
             run.status, api.run_logs(run.run_id)[-10:])

@@ -725,6 +725,10 @@ def test_flash_autotune_fallback_policy(tmp_path, monkeypatch):
     monkeypatch.delenv("FEDML_TPU_FLASH_MODE")
     monkeypatch.setattr(A, "_on_tpu", lambda: False)
     assert not A._use_pallas(*tuned_key)         # CPU -> always blockwise
+    monkeypatch.setenv("FEDML_TPU_FLASH_MODE", "force")
+    with pytest.raises(RuntimeError, match="FEDML_TPU_FLASH_MODE=force"):
+        A._use_pallas(*tuned_key)                # never a silent False
+    monkeypatch.delenv("FEDML_TPU_FLASH_MODE")
 
     # loader: winner registered, loser skipped, junk lines tolerated
     art = tmp_path / "TPU_FLASH_TUNE.json"

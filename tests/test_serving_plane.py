@@ -257,7 +257,7 @@ def test_kv_cache_decode_is_faster():
     t_cached = timed(model=model)
     t_plain = timed()
     speedup = t_plain / t_cached
-    # CPU CI bar is conservative; BASELINE.md records the measured number.
+    # the CPU CI bar is conservative
     assert speedup > 2.0, f"cached decode only {speedup:.2f}x faster"
 
 
@@ -692,32 +692,6 @@ def test_server_speculative_batching_mode():
         assert st == 200 and _json.loads(body)["choices"][0]["text"]
     finally:
         srv.stop()
-
-
-@pytest.mark.slow
-def test_serve_rtt_harness_smoke(tmp_path):
-    """The RTT-injection harness (VERDICT r4 item 4) must run end-to-end,
-    keep greedy parity under injected latency, and show batching/horizon
-    amortizing dispatches vs sequential decode."""
-    import subprocess
-    import sys
-
-    out = str(tmp_path / "serve_rtt_sim.json")
-    r = subprocess.run(
-        [sys.executable, "tools/serve_rtt_harness.py", "--rtt-ms", "20",
-         "--tokens", "12", "--out", out],
-        cwd=REPO, capture_output=True, text=True, timeout=900)
-    assert r.returncode == 0, r.stderr[-2000:]
-    with open(out) as f:
-        res = json.load(f)
-    lev = res["levers"]
-    # dispatch-count arithmetic is deterministic even when timings jitter
-    assert lev["batched_h8"]["tokens_per_dispatch"] > \
-        lev["batched_h1"]["tokens_per_dispatch"] > \
-        lev["seq_kv"]["tokens_per_dispatch"]
-    assert lev["spec_fused_selfdraft"]["acceptance"] == 1.0
-    # under 20ms injected RTT the horizon path must beat sequential
-    assert lev["batched_h8"]["tok_s"] > lev["seq_kv"]["tok_s"]
 
 
 def test_prefix_cache_greedy_parity_and_reuse():
@@ -1252,7 +1226,7 @@ def test_personalized_adapters_example():
     import subprocess
     import sys
 
-    env = dict(os.environ, FEDML_TPU_PLATFORM="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=f"{REPO}:{os.environ.get('PYTHONPATH', '')}")
     r = subprocess.run(
         [sys.executable, "examples/serving/personalized_adapters.py"],

@@ -129,7 +129,10 @@ def test_scatter_matches_sp_engine():
     sp_losses = [round(float(sp.train_one_round(r)["train_loss"]), 6)
                  for r in range(3)]
     sc, sc_losses = run_mesh(update_sharding="scatter")
-    assert sp_losses == sc_losses, (sp_losses, sc_losses)
+    # psum_scatter sums in another order than sp's one reduction: an ULP,
+    # which may straddle a rounding boundary of the sixth decimal
+    assert sp_losses == pytest.approx(sc_losses, abs=2e-6), (sp_losses,
+                                                             sc_losses)
     assert_tree_close(sp.state.global_params, sc.state.global_params)
 
 

@@ -33,7 +33,6 @@ sys.path.insert(0, REPO)
 
 def _force_cpu_mesh():
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ.setdefault("FEDML_TPU_PLATFORM", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

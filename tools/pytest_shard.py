@@ -4,7 +4,8 @@ Partitions the test FILES across N worker processes (greedy longest-
 processing-time bin packing over the duration hints below) and runs one
 pytest per shard concurrently.  File granularity keeps every existing
 module-scoped fixture/process assumption intact — tests within a file never
-split across workers.
+split across workers.  This parent never imports jax; each shard is a
+pytest process of its own, on the CPU platform tests/conftest.py sets.
 
 Duration hints come from a full-suite run (2026-07-31, 296 tests, 47 min
 contended / ~25 min solo); unknown files get a middle weight.  Exact values

@@ -1,4 +1,4 @@
-"""Measure the BASELINE.md accuracy rows beyond digits (VERDICT r2 item 4).
+"""Measure the accuracy rows of BASELINE_ROWS.json beyond digits.
 
 Runs the reference-config workloads end-to-end through the REAL parsers
 (LEAF femnist, CIFAR binary) on format-faithful generated files (see
@@ -26,10 +26,6 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-
-# these rows are CPU workloads (accuracy dynamics, not device perf); skip
-# the TPU liveness probe unless the caller explicitly overrides
-os.environ.setdefault("FEDML_TPU_PLATFORM", "cpu")
 
 
 def _run_row(name, overrides, backend="sp"):

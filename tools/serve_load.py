@@ -52,9 +52,6 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-if os.environ.get("FEDML_TPU_PLATFORM") is None:
-    os.environ["FEDML_TPU_PLATFORM"] = "cpu"   # tunnel discipline
-
 # the traffic shapes are shared with the async arrival simulator
 # (fedml_tpu/core/traffic.py, docs/ASYNC.md); zipf_weights stays re-exported
 # here so `from serve_load import zipf_weights` keeps working

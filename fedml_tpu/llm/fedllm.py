@@ -26,6 +26,8 @@ import optax
 from ..core import rng as rng_util
 from ..core import tree as tree_util
 from ..data.federated_dataset import FederatedDataset
+from ..obs import get_tracer
+from ..obs.jaxhooks import count_put
 from .model import (LlamaLM, causal_nll, config_from_args,
                     per_sequence_loglik)
 
@@ -252,35 +254,45 @@ class FedLLMAPI:
         return (np.arange(R)[None, :] < ranks[:, None]).astype(np.float32)
 
     def train_one_round(self, round_idx: int):
-        clients = rng_util.sample_clients(self.seed, round_idx,
-                                          self.dataset.num_clients,
-                                          self.clients_per_round)
-        rank_masks = self._cohort_rank_masks(clients)
-        x, y, mask, w = self.dataset.cohort_batches(
-            clients, self.batch_size, self.seed, round_idx, self.epochs,
-            max_steps=self.max_steps)
-        if self._client_sharding is not None:
-            # host-pad then ONE sharded transfer — never stage the whole
-            # cohort on a single chip (the pattern mesh_simulator uses)
-            from ..core.mesh import CLIENT_AXIS, pad_to_multiple
-            n_shards = self.mesh.shape[CLIENT_AXIS]
-            pad_c = pad_to_multiple(len(clients), n_shards) - len(clients)
-            if pad_c:  # cohort must tile evenly over the client axis
-                padc = lambda a: np.pad(
-                    a, [(0, pad_c)] + [(0, 0)] * (a.ndim - 1))
-                x, y, mask, w = padc(x), padc(y), padc(mask), padc(w)
-                rank_masks = padc(rank_masks)
-            put = lambda a: jax.device_put(jnp.asarray(a),
-                                           self._client_sharding)
-            x, y, mask, w = put(x), put(y), put(mask), put(w)
-            rank_masks = put(rank_masks)
-        else:
-            x, y = jnp.asarray(x), jnp.asarray(y)
-            mask, w = jnp.asarray(mask), jnp.asarray(w)
-            rank_masks = jnp.asarray(rank_masks)
-        self.global_lora, loss = self._round_fn(
-            self.base_params, self.global_lora, x, y, mask, w, rank_masks)
-        return {"train_loss": float(loss)}
+        tracer = get_tracer()
+        with tracer.span("fedllm.round", cat="round",
+                         round=int(round_idx)) as rnd:
+            with tracer.span("fedllm.round.sample", cat="round"):
+                clients = rng_util.sample_clients(
+                    self.seed, round_idx, self.dataset.num_clients,
+                    self.clients_per_round)
+                rank_masks = self._cohort_rank_masks(clients)
+            with tracer.span("fedllm.round.batches", cat="round"):
+                x, y, mask, w = self.dataset.cohort_batches(
+                    clients, self.batch_size, self.seed, round_idx,
+                    self.epochs, max_steps=self.max_steps)
+            # clients x steps x batch x sequence, before any padding
+            rnd.set(clients=len(clients), tokens=int(np.prod(x.shape)))
+            with tracer.span("fedllm.round.stage", cat="round") as stage:
+                staged = self._stage(clients, (x, y, mask, w, rank_masks))
+                stage.set(bytes=count_put(tracer, staged))
+            with tracer.span("fedllm.round.dispatch", cat="round"):
+                self.global_lora, loss = self._round_fn(
+                    self.base_params, self.global_lora, *staged)
+            with tracer.span("fedllm.round.readback", cat="round"):
+                loss = float(loss)
+        return {"train_loss": loss}
+
+    def _stage(self, clients, arrays):
+        """The round's host arrays on the device: padded to tile the
+        client axis and put sharded over it where there is a mesh."""
+        if self._client_sharding is None:
+            return tuple(jnp.asarray(a) for a in arrays)
+        # host-pad then ONE sharded transfer — never stage the whole
+        # cohort on a single chip (the pattern mesh_simulator uses)
+        from ..core.mesh import CLIENT_AXIS, pad_to_multiple
+        n_shards = self.mesh.shape[CLIENT_AXIS]
+        pad_c = pad_to_multiple(len(clients), n_shards) - len(clients)
+        if pad_c:  # cohort must tile evenly over the client axis
+            arrays = tuple(np.pad(a, [(0, pad_c)] + [(0, 0)] * (a.ndim - 1))
+                           for a in arrays)
+        return tuple(jax.device_put(jnp.asarray(a), self._client_sharding)
+                     for a in arrays)
 
     def evaluate(self):
         xb, yb, mb = self.dataset.test_batches(batch_size=self.batch_size)

@@ -13,10 +13,10 @@ steady-state compiles on the round hot path — pinned by the
 2. **Host spans + counters** (:mod:`.tracer`): a thread-safe
    :class:`Tracer` recording staging spans + queue depth, XLA compile
    events with durations (through the shared :mod:`.jaxhooks` monitoring
-   hub the runtime auditor also uses), ``device_put``/``device_get``
-   byte counters, and comm-manager RTT spans — exported as Chrome
-   trace-event JSON (loadable in Perfetto / ``chrome://tracing``) plus a
-   Prometheus-style aggregate text dump.
+   hub the runtime auditor also uses), the ``device_put_bytes`` counter
+   of what staging put on the device, and comm-manager RTT spans —
+   exported as Chrome trace-event JSON (loadable in Perfetto /
+   ``chrome://tracing``) plus a Prometheus-style aggregate text dump.
 3. **Analysis** (``tools/fedtrace.py``): ``summarize`` turns a trace
    into a per-phase (staging / gather / client steps / merge / server
    update) time breakdown; ``diff`` compares two traces.

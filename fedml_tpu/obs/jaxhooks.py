@@ -1,4 +1,4 @@
-"""Process-wide jax monitoring hub + transfer probes.
+"""Process-wide jax monitoring hub + the staged-bytes count.
 
 jax's ``monitoring.register_event_duration_secs_listener`` has no public
 unregister, so every consumer registering its own listener leaks one per
@@ -8,10 +8,13 @@ to subscribers — the runtime auditor (:mod:`fedml_tpu.analysis.runtime`)
 and the fedtrace tracer both attach here, so audits and traces observe
 the identical compile stream.
 
-The transfer probe wraps ``jax.device_put``/``jax.device_get`` to count
-bytes.  Wrapping intercepts — it never ADDS a transfer or a sync, which
-is what keeps ``JaxRuntimeAudit`` counters identical between traced and
-untraced runs (pinned in ``tests/test_fedtrace.py``).
+``device_put_bytes`` is counted where staging happens: every call site
+that puts a round's inputs on the device (under its ``staging`` /
+``fedllm.round.stage`` span) calls :func:`count_put` with the host tree it
+stages.  The count reads ``nbytes`` of host arrays — it never adds a
+transfer or a sync, which is what keeps ``JaxRuntimeAudit`` counters
+identical between traced and untraced runs (pinned in
+``tests/test_fedtrace.py``).
 """
 
 from __future__ import annotations
@@ -71,30 +74,24 @@ def tree_nbytes(x) -> int:
     return total
 
 
+def count_put(tracer, tree) -> int:
+    """Add the bytes of ``tree``'s array leaves to the ``device_put_bytes``
+    counter; called by the code that stages ``tree`` on the device.  One
+    attribute check when tracing is off.  Returns the bytes counted."""
+    if not tracer.enabled:
+        return 0
+    n = tree_nbytes(tree)
+    tracer.add_bytes("device_put_bytes", n)
+    return n
+
+
 def install_tracer_hooks(tracer) -> Callable[[], None]:
-    """Subscribe ``tracer`` to compile events and install the transfer
-    byte probes; returns an uninstall callable restoring both."""
-    import jax
+    """Subscribe ``tracer`` to compile events; returns the callable that
+    unsubscribes it."""
 
     def on_event(event: str, duration: float):
         if event == BACKEND_COMPILE_EVENT:
             tracer.complete("xla_compile", duration, cat="compile")
 
     subscribe(on_event)
-    orig_put, orig_get = jax.device_put, jax.device_get
-
-    def traced_put(x, *a, **kw):
-        tracer.add_bytes("device_put_bytes", tree_nbytes(x))
-        return orig_put(x, *a, **kw)
-
-    def traced_get(x, *a, **kw):
-        tracer.add_bytes("device_get_bytes", tree_nbytes(x))
-        return orig_get(x, *a, **kw)
-
-    jax.device_put, jax.device_get = traced_put, traced_get
-
-    def uninstall():
-        unsubscribe(on_event)
-        jax.device_put, jax.device_get = orig_put, orig_get
-
-    return uninstall
+    return lambda: unsubscribe(on_event)

@@ -81,21 +81,31 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        """Swallowed: see :meth:`_SpanCtx.set`."""
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "span_id",
-                 "duration_s")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_end_args",
+                 "span_id", "duration_s")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._end_args: dict = {}
         self.span_id: Optional[str] = None
         self.duration_s: Optional[float] = None
+
+    def set(self, **args):
+        """Args only known when the span closes (tokens a tick emitted,
+        requests it finished): the ``with`` body sets them, they ride on
+        the ``E`` event."""
+        self._end_args.update(args)
 
     def __enter__(self):
         self.span_id = self._tracer.begin(self._name, cat=self._cat,
@@ -103,7 +113,7 @@ class _SpanCtx:
         return self
 
     def __exit__(self, *exc):
-        self.duration_s = self._tracer.end(self._name)
+        self.duration_s = self._tracer.end(self._name, **self._end_args)
         return False
 
 
@@ -153,6 +163,14 @@ class Tracer:
             self.trace_id, self.current_span_id() or "0" * 16)
 
     # -- clock -------------------------------------------------------------
+    @property
+    def origin_s(self) -> float:
+        """The ``time.perf_counter`` reading that every event's ``ts``
+        counts from: ``origin_s + ts / 1e6`` puts an event on that clock
+        (a profiler trace taken in the same process ties to it by one
+        annotation)."""
+        return self._origin
+
     def _ts(self) -> float:
         """Microseconds since tracer origin (Chrome trace ts unit)."""
         return (time.perf_counter() - self._origin) * 1e6
@@ -276,7 +294,8 @@ class Tracer:
                 pass
 
     def add_bytes(self, name: str, n: int):
-        """Cumulative byte counter (device_put/get probes)."""
+        """Cumulative byte counter (``device_put_bytes``: what staging
+        put on the device, counted by the code that stages)."""
         if not self.enabled:
             return
         ev = {"name": name, "ph": "C", "ts": self._ts(), "pid": self._pid,
@@ -439,9 +458,10 @@ def configure(enabled: Optional[bool] = None, path: Optional[str] = None,
     """Configure the global tracer.
 
     Enabling subscribes the tracer to the shared jax monitoring hub
-    (XLA compile events) and wraps ``jax.device_put``/``device_get`` with
-    byte counters (:mod:`.jaxhooks`); disabling restores both.  The hooks
-    never add a transfer, a sync, or a compile — the CI smoke pins
+    (XLA compile events, :mod:`.jaxhooks`) unless ``jax_hooks`` is false;
+    disabling unsubscribes it.  Staged bytes are counted by the staging
+    code itself (``jaxhooks.count_put``).  Neither adds a transfer, a
+    sync, or a compile — the CI smoke pins
     ``JaxRuntimeAudit`` counter equality between traced and untraced runs.
 
     ``label`` names this process's lane on a merged multi-process

@@ -88,7 +88,10 @@ class _Slot:
                  # clocks, engine-thread-confined like the decode state)
                  "t_submit", "t_admit", "t_prefill_end", "t_first",
                  "prompt_tokens", "out_tokens", "adapter_label",
-                 "traceparent", "drafts_proposed", "drafts_accepted")
+                 "traceparent", "drafts_proposed", "drafts_accepted",
+                 # the running integer submit() gave the request: every
+                 # span of one request carries it
+                 "request")
 
     def __init__(self):
         self.live = False
@@ -115,6 +118,7 @@ class _Slot:
         self.traceparent: Optional[str] = None
         self.drafts_proposed = 0
         self.drafts_accepted = 0
+        self.request: Optional[int] = None
 
 
 class ContinuousBatchingEngine:
@@ -476,6 +480,8 @@ class ContinuousBatchingEngine:
         # thread once live slots drain (admission pauses meanwhile)
         self._pending_params = None
         self._ticks = 0  # batched steps executed (observability)
+        self._iters = 0  # engine-loop passes (the serve.iter span's index)
+        self._requests = 0  # requests submitted; the next one's id
         # host-side serving telemetry (always maintained; mirrored onto
         # fedtrace counters when tracing is on — host ints only, the
         # engine never adds a device sync for observability)
@@ -529,7 +535,9 @@ class ContinuousBatchingEngine:
                 if self._stopped or not self._thread.is_alive():
                     raise RuntimeError("engine stopped")
                 name = adapter if adapter is not None else "base"
+                self._requests += 1
                 self._waiting.put({
+                    "request": self._requests,
                     "prompt_ids": list(prompt_ids)[-(self.buf_len - 1):],
                     "max_new_tokens": int(max_new_tokens),
                     "temperature": float(temperature),
@@ -720,7 +728,9 @@ class ContinuousBatchingEngine:
         the phase breakdown lands in the serve histograms, the objective
         windows, and — when tracing is on — a retroactive span tree on a
         per-slot synthetic lane (same-slot requests never overlap, so
-        B/E pairing survives the export's timestamp sort)."""
+        B/E pairing survives the export's timestamp sort).  That tree is
+        one request's lifetime, not what this thread was doing: the live
+        ``serve.iter`` tree (``_run_loop``) says that."""
         now = time.monotonic()
         queue_s = max(s.t_admit - s.t_submit, 0.0)
         prefill_s = max(s.t_prefill_end - s.t_admit, 0.0)
@@ -744,9 +754,13 @@ class ContinuousBatchingEngine:
         if not tracer.enabled:
             return
         lane = -16 - i  # per-slot synthetic lane, clear of COMPILE_TID
+        # each call reads the clock anew, a little later: the child that
+        # ends with its parent is written first, so that it ends inside it
+        tracer.complete("serve.decode", decode_s, cat="serve", tid=lane,
+                        slot=i, request=s.request)
         tracer.complete(
             "serve.request", e2e_s, cat="serve", tid=lane,
-            adapter=s.adapter_label, slot=i,
+            adapter=s.adapter_label, slot=i, request=s.request,
             prompt_tokens=s.prompt_tokens, output_tokens=s.out_tokens,
             queue_s=round(queue_s, 6), prefill_s=round(prefill_s, 6),
             ttft_s=round(ttft_s, 6) if ttft_s is not None else None,
@@ -756,9 +770,8 @@ class ContinuousBatchingEngine:
             drafts_accepted=(s.drafts_accepted if s.drafts_proposed
                              else None))
         tracer.complete("serve.queue", queue_s, cat="serve", tid=lane,
-                        end_s_ago=max(e2e_s - queue_s, 0.0), slot=i)
-        tracer.complete("serve.decode", decode_s, cat="serve", tid=lane,
-                        slot=i)
+                        end_s_ago=max(e2e_s - queue_s, 0.0), slot=i,
+                        request=s.request)
 
     def _emit(self, i: int, tok: int) -> bool:
         """Deliver one sampled token; returns False when the slot is done
@@ -858,6 +871,7 @@ class ContinuousBatchingEngine:
         s.out_tokens = 0
         s.adapter_label = req.get("adapter_label", "base")
         s.traceparent = req.get("traceparent")
+        s.request = req.get("request")
         s.drafts_proposed = 0
         s.drafts_accepted = 0
         self._aids[slot] = row  # fedrace: disable=unguarded-shared-write
@@ -942,6 +956,7 @@ class ContinuousBatchingEngine:
         s.out_tokens = 0
         s.adapter_label = req.get("adapter_label", "base")
         s.traceparent = req.get("traceparent")
+        s.request = req.get("request")
         s.drafts_proposed = 0
         s.drafts_accepted = 0
         self._aids[slot] = s.adapter_row  # fedrace: disable=unguarded-shared-write
@@ -954,6 +969,7 @@ class ContinuousBatchingEngine:
         a 4k-token prompt costs each tick one chunk, not a stall."""
         lanes = self.prefill_lanes
         C = self.prefill_chunk
+        tracer = get_tracer()
         for i, s in enumerate(self._slots):
             if lanes <= 0:
                 break
@@ -962,41 +978,59 @@ class ContinuousBatchingEngine:
             lanes -= 1
             cs = s.pf_next
             n = s.pf_n
+            final = cs + C >= n
+            with tracer.span("serve.chunk", cat="engine", slot=i,
+                             request=s.request, start=cs,
+                             tokens=min(C, n - cs), final=int(final)):
+                self._prefill_chunk(tracer, i, s, cs, final)
+
+    def _prefill_chunk(self, tracer, i: int, s: "_Slot", cs: int,
+                       final: bool) -> None:
+        """One chunk of slot ``i``'s prompt from position ``cs``; the
+        final one flips the slot live and emits its first token."""
+        C = self.prefill_chunk
+        n = s.pf_n
+        with tracer.span("serve.chunk.gather", cat="engine",
+                         adapter_row=s.adapter_row):
+            lora = (self.registry.lora_for_row(s.adapter_row)
+                    if self.registry is not None else None)
+        with tracer.span("serve.chunk.dispatch", cat="engine"):
             chunk = np.zeros((1, C), np.int32)
             seg = s.pf_ids[cs:cs + C]
             chunk[0, :len(seg)] = seg
-            final = cs + C >= n
             # sample index is traced: intermediate chunks discard token 0,
             # the final chunk samples at the prompt's last position
             idx = max(n - 1 - cs, 0) if final else 0
-            lora = (self.registry.lora_for_row(s.adapter_row)
-                    if self.registry is not None else None)
+            # the page pool is engine-thread-confined like the other
+            # decode state (see _admit); step_programs reads it at rest
+            # fedrace: disable-next-line=unguarded-shared-write
             tok, self._pool = self._chunk(
                 self.raw_params, lora, self._pool, jnp.asarray(chunk),
                 jnp.asarray(self._btabs[i][None]),
                 jnp.asarray([cs], jnp.int32), jnp.int32(idx), s.pf_sub,
                 jnp.float32(self._temps[i]))
-            with self._stats_lock:
-                self._chunks_total += 1
-            if not final:
-                s.pf_next = cs + C
-                continue
+        with self._stats_lock:
+            self._chunks_total += 1
+        if not final:
+            s.pf_next = cs + C
+            return
+        with tracer.span("serve.chunk.readback", cat="engine"):
             tok_host = int(tok)
-            s.prefilling = False
-            s.live = True
-            s.pos = n
-            s.t_prefill_end = time.monotonic()
-            if self.prefix_cache is not None and n > 0:
-                fullpages = n // self.kv_page_tokens
-                if fullpages:
-                    self.prefix_cache.insert(
-                        s.pf_ids,
-                        [int(p) for p in self._btabs[i, :fullpages]],
-                        self.raw_params, s.pf_atok)
-            s.pf_ids = None
-            s.pf_sub = None
-            if not self._emit(i, tok_host):
-                self._finish(i)
+        s.prefilling = False
+        s.live = True
+        s.pos = n
+        s.t_prefill_end = time.monotonic()
+        if self.prefix_cache is not None and n > 0:
+            fullpages = n // self.kv_page_tokens
+            if fullpages:
+                self.prefix_cache.insert(
+                    s.pf_ids,
+                    [int(p) for p in self._btabs[i, :fullpages]],
+                    self.raw_params, s.pf_atok)
+        s.pf_ids = None
+        s.pf_sub = None
+        if not self._emit(i, tok_host):
+            self._finish(i)
 
     def _admit_one(self, req: dict, slot: int, tracer) -> bool:
         """Admission front door for both engines: cache-mode adapter pin
@@ -1031,7 +1065,8 @@ class ContinuousBatchingEngine:
             req["q"].put(None)
             return False
         with tracer.span("serve.admit", cat="serve", slot=slot,
-                         adapter_row=req.get("adapter_row", 0)):
+                         adapter_row=req.get("adapter_row", 0),
+                         request=req.get("request")):
             if self.paged:
                 self._admit_paged(req, slot)
             else:
@@ -1085,6 +1120,15 @@ class ContinuousBatchingEngine:
                 self.registry.release(req["adapter_row"])
         self._parked.clear()
 
+    def _iter_args(self, tracer) -> Dict[str, int]:
+        """The ``serve.iter`` span's args; counted only when tracing."""
+        if not tracer.enabled:
+            return {}
+        return {"iter": self._iters,
+                "live": sum(s.live for s in self._slots),
+                "prefilling": sum(s.prefilling for s in self._slots),
+                "queued": self._waiting.qsize() + len(self._parked)}
+
     def _run(self):
         try:
             self._run_loop()
@@ -1108,7 +1152,8 @@ class ContinuousBatchingEngine:
                        and not any(s.live or s.prefilling
                                    for s in self._slots)
                        and not self._parked_actionable()):
-                    self._cond.wait(timeout=0.5)
+                    with get_tracer().span("serve.wait", cat="engine"):
+                        self._cond.wait(timeout=0.5)
                 if self._stopped:
                     for i, s in enumerate(self._slots):
                         if s.live or s.prefilling:
@@ -1148,33 +1193,38 @@ class ContinuousBatchingEngine:
             # head never blocks fresh admissions behind it — _admit_one
             # re-parks and the loop moves on.
             tracer = get_tracer()
-            if retry_parked:
-                retry, self._parked = self._parked, []
-                for j, req in enumerate(retry):
+            # one live span tree per pass (admit, chunk, tick): what this
+            # thread is doing, on the clock a profiler trace ties to
+            self._iters += 1
+            with tracer.span("serve.iter", cat="engine",
+                             **self._iter_args(tracer)):
+                if retry_parked:
+                    retry, self._parked = self._parked, []
+                    for j, req in enumerate(retry):
+                        slot = self._free_slot()
+                        if slot is None:
+                            self._parked.extend(retry[j:])
+                            break
+                        self._admit_one(req, slot, tracer)
+                while not swap_pending and not self._waiting.empty():
                     slot = self._free_slot()
                     if slot is None:
-                        self._parked.extend(retry[j:])
                         break
+                    req = self._waiting.get()
                     self._admit_one(req, slot, tracer)
-            while not swap_pending and not self._waiting.empty():
-                slot = self._free_slot()
-                if slot is None:
-                    break
-                req = self._waiting.get()
-                self._admit_one(req, slot, tracer)
-            if tracer.enabled:
-                tracer.counter("serve.queue_depth",
-                               self._waiting.qsize() + len(self._parked))
+                if tracer.enabled:
+                    tracer.counter("serve.queue_depth",
+                                   self._waiting.qsize() + len(self._parked))
 
-            if self.paged:
-                self._prefill_tick()
-            live = [i for i, s in enumerate(self._slots) if s.live]
-            if live:
-                self._dispatch(live)
-                with self._stats_lock:
-                    self._ticks += 1
-            elif not any(s.prefilling for s in self._slots):
-                continue
+                if self.paged:
+                    self._prefill_tick()
+                live = [i for i, s in enumerate(self._slots) if s.live]
+                if live:
+                    self._dispatch(live)
+                    with self._stats_lock:
+                        self._ticks += 1
+                elif not any(s.prefilling for s in self._slots):
+                    continue
             if tracer.enabled:
                 now = time.monotonic()
                 rolled = None
@@ -1209,69 +1259,97 @@ class ContinuousBatchingEngine:
                     tracer.counter("serve.adapter_miss_rate",
                                    st["cache_misses"] / tot if tot else 0.0)
 
+    def _tick_span(self, tracer, live, tracing: bool):
+        """The ``serve.tick`` span both engines' ``_dispatch`` open;
+        what needs a sum over the slots is summed only when tracing."""
+        return tracer.span(
+            "serve.tick", cat="engine", live=len(live),
+            live_kv_tokens=(sum(self._slots[i].pos for i in live)
+                            if tracing else None))
+
+    def _delivered(self, live) -> int:
+        """Tokens the ``live`` slots' requests have received so far (a
+        slot keeps its count until its next admission): the difference
+        over a tick is what the tick emitted."""
+        return sum(self._slots[i].out_tokens for i in live)
+
     def _dispatch(self, live):
         """One device tick for the live slots (overridden by the
         speculative engine): horizon-scanned batched decode + emission."""
-        for i in live:
-            # engine-thread-confined decode state (see _admit)
-            self._toks[i] = self._slots[i].cur_tok  # fedrace: disable=unguarded-shared-write
-            self._poss[i] = self._slots[i].pos  # fedrace: disable=unguarded-shared-write
-        if self.paged:
-            # block tables ride as TRACED data — page moves, admissions
-            # and evictions between ticks never recompile.  Non-live slots
-            # must see all-trash tables so their burn writes land in
-            # garbage: freed rows are already zeroed, but PREFILLING slots
-            # have real (possibly shared-prefix) pages wired — mask their
-            # rows here or the burn write at their stale position would
-            # scribble into a page another slot is reading
-            bt = self._btabs
-            prefilling = [i for i, s in enumerate(self._slots)
-                          if s.prefilling]
-            if prefilling:
-                bt = bt.copy()
-                bt[prefilling] = 0
-            btabs = jnp.asarray(bt)
-            if self.registry is not None:
-                with self.registry.lock:
-                    toks, self._pool, keys = self._step(
-                        self.raw_params, self.registry.bank, self._pool,
-                        btabs, jnp.asarray(self._toks),
-                        jnp.asarray(self._poss), jnp.asarray(self._keys),
-                        jnp.asarray(self._temps), jnp.asarray(self._aids))
-            else:
-                toks, self._pool, keys = self._step(
-                    self.raw_params, self._pool, btabs,
-                    jnp.asarray(self._toks), jnp.asarray(self._poss),
-                    jnp.asarray(self._keys), jnp.asarray(self._temps))
-        elif self.registry is not None:
-            # snapshot + dispatch under the registry lock so a concurrent
-            # register()'s donated row write cannot invalidate the bank
-            # buffer between the read and the launch (the dispatch itself
-            # is async and fast; registration is the rare path)
-            with self.registry.lock:
-                toks, self._caches, keys = self._step(
-                    self.raw_params, self.registry.bank, self._caches,
-                    jnp.asarray(self._toks), jnp.asarray(self._poss),
-                    jnp.asarray(self._keys), jnp.asarray(self._temps),
-                    jnp.asarray(self._aids))
-        else:
-            toks, self._caches, keys = self._step(
-                self.raw_params, self._caches, jnp.asarray(self._toks),
-                jnp.asarray(self._poss), jnp.asarray(self._keys),
-                jnp.asarray(self._temps))
-        toks_host = np.asarray(toks)  # (n_slots, horizon)
-        # copy carry keys back for LIVE slots only: a prefilling slot's
-        # admission key must not advance with the burn splits its lane
-        # rode along for (its first real sample comes later)
-        keys_host = np.asarray(keys)
-        for i in live:
-            self._keys[i] = keys_host[i]  # fedrace: disable=unguarded-shared-write
-        for i in live:
-            for j in range(self.horizon):
-                self._slots[i].pos += 1
-                if not self._emit(i, int(toks_host[i, j])):
-                    self._finish(i)
-                    break
+        tracer = get_tracer()
+        tracing = tracer.enabled
+        with self._tick_span(tracer, live, tracing) as tick:
+            before = self._delivered(live) if tracing else 0
+            with tracer.span("serve.tick.stage", cat="engine"):
+                for i in live:
+                    # engine-thread-confined decode state (see _admit)
+                    self._toks[i] = self._slots[i].cur_tok  # fedrace: disable=unguarded-shared-write
+                    self._poss[i] = self._slots[i].pos  # fedrace: disable=unguarded-shared-write
+                if self.paged:
+                    # block tables ride as TRACED data — page moves,
+                    # admissions and evictions between ticks never
+                    # recompile.  Non-live slots must see all-trash tables
+                    # so their burn writes land in garbage: freed rows are
+                    # already zeroed, but PREFILLING slots have real
+                    # (possibly shared-prefix) pages wired — mask their
+                    # rows here or the burn write at their stale position
+                    # would scribble into a page another slot is reading
+                    bt = self._btabs
+                    prefilling = [i for i, s in enumerate(self._slots)
+                                  if s.prefilling]
+                    if prefilling:
+                        bt = bt.copy()
+                        bt[prefilling] = 0
+                    state = (jnp.asarray(bt),)
+                else:
+                    state = ()
+                state += (jnp.asarray(self._toks), jnp.asarray(self._poss),
+                          jnp.asarray(self._keys), jnp.asarray(self._temps))
+                if self.registry is not None:
+                    state += (jnp.asarray(self._aids),)
+            with tracer.span("serve.tick.dispatch", cat="engine"):
+                kv = self._pool if self.paged else self._caches
+                if self.registry is not None:
+                    # snapshot + dispatch under the registry lock so a
+                    # concurrent register()'s donated row write cannot
+                    # invalidate the bank buffer between the read and the
+                    # launch (the dispatch itself is async and fast;
+                    # registration is the rare path)
+                    with self.registry.lock:
+                        toks, kv, keys = self._step(
+                            self.raw_params, self.registry.bank, kv, *state)
+                else:
+                    toks, kv, keys = self._step(self.raw_params, kv, *state)
+                if self.paged:
+                    self._pool = kv
+                else:
+                    self._caches = kv
+            with tracer.span("serve.tick.readback", cat="engine"):
+                toks_host = np.asarray(toks)  # (n_slots, horizon)
+                # copy carry keys back for LIVE slots only: a prefilling
+                # slot's admission key must not advance with the burn
+                # splits its lane rode along for (its first real sample
+                # comes later)
+                keys_host = np.asarray(keys)
+            with tracer.span("serve.tick.emit", cat="engine") as emit:
+                finished = 0
+                for i in live:
+                    self._keys[i] = keys_host[i]  # fedrace: disable=unguarded-shared-write
+                for i in live:
+                    for j in range(self.horizon):
+                        self._slots[i].pos += 1
+                        if not self._emit(i, int(toks_host[i, j])):
+                            self._finish(i)
+                            finished += 1
+                            break
+                emit.set(finished=finished)
+            with tracer.span("serve.tick.free", cat="engine"):
+                # the tick's device arrays go here, by name, and not at
+                # the return: the first one freed after its program has
+                # run blocks for milliseconds on the TPU runtime
+                del state, toks, keys
+            if tracing:
+                tick.set(tokens=self._delivered(live) - before)
 
 
 class SpeculativeBatchingEngine(ContinuousBatchingEngine):
@@ -1423,28 +1501,45 @@ class SpeculativeBatchingEngine(ContinuousBatchingEngine):
         return cont
 
     def _dispatch(self, live):
-        kp1 = self.k + 1
-        sync_bufs = np.zeros((self.n_slots, kp1), np.int32)
-        sync_lens = np.ones(self.n_slots, np.int32)
-        for i in live:
-            s = self._slots[i]
-            hist = self._hist[i]
-            self._toks[i] = s.cur_tok
-            self._poss[i] = s.pos
-            sync = hist[self._fds[i]: s.pos + 1]
-            assert 1 <= len(sync) <= kp1, (len(sync), self.k)
-            sync_bufs[i, :len(sync)] = sync
-            sync_lens[i] = len(sync)
+        tracer = get_tracer()
+        tracing = tracer.enabled
+        with self._tick_span(tracer, live, tracing) as tick:
+            before = self._delivered(live) if tracing else 0
+            with tracer.span("serve.tick.stage", cat="engine"):
+                kp1 = self.k + 1
+                sync_bufs = np.zeros((self.n_slots, kp1), np.int32)
+                sync_lens = np.ones(self.n_slots, np.int32)
+                for i in live:
+                    s = self._slots[i]
+                    hist = self._hist[i]
+                    self._toks[i] = s.cur_tok
+                    self._poss[i] = s.pos
+                    sync = hist[self._fds[i]: s.pos + 1]
+                    assert 1 <= len(sync) <= kp1, (len(sync), self.k)
+                    sync_bufs[i, :len(sync)] = sync
+                    sync_lens[i] = len(sync)
+                state = (jnp.asarray(sync_bufs), jnp.asarray(sync_lens),
+                         jnp.asarray(self._fds), jnp.asarray(self._toks),
+                         jnp.asarray(self._poss))
+            with tracer.span("serve.tick.dispatch", cat="engine"):
+                d_tokens, greedy, self._d_caches, self._caches = \
+                    self._spec_tick(self.raw_draft, self.raw_params,
+                                    self._d_caches, self._caches, *state)
+            with tracer.span("serve.tick.readback", cat="engine"):
+                d_host = np.asarray(d_tokens)
+                g_host = np.asarray(greedy)
+            self.stats["target_block_forwards"] += len(live)
+            with tracer.span("serve.tick.emit", cat="engine") as emit:
+                emit.set(finished=self._accept(live, d_host, g_host))
+            with tracer.span("serve.tick.free", cat="engine"):
+                del state, d_tokens, greedy      # see the base engine's tick
+            if tracing:
+                tick.set(tokens=self._delivered(live) - before)
 
-        d_tokens, greedy, self._d_caches, self._caches = self._spec_tick(
-            self.raw_draft, self.raw_params, self._d_caches, self._caches,
-            jnp.asarray(sync_bufs), jnp.asarray(sync_lens),
-            jnp.asarray(self._fds), jnp.asarray(self._toks),
-            jnp.asarray(self._poss))
-        d_host = np.asarray(d_tokens)
-        g_host = np.asarray(greedy)
-        self.stats["target_block_forwards"] += len(live)
-
+    def _accept(self, live, d_host, g_host) -> int:
+        """Emit each live slot's accepted draft tokens and the target's
+        own next one; returns how many slots finished."""
+        finished = 0
         for i in live:
             s = self._slots[i]
             self._fds[i] = s.pos + 1  # draft confirmed through old cur
@@ -1460,14 +1555,18 @@ class SpeculativeBatchingEngine(ContinuousBatchingEngine):
                     # first disagreement: the target's own token replaces it
                     if not self._emit(i, gj):
                         self._finish(i)
+                        finished += 1
                     break
                 self.stats["accepted"] += 1
                 s.drafts_accepted += 1
                 if not self._emit(i, dj):
                     self._finish(i)
+                    finished += 1
                     break
             else:
                 # every proposal accepted: the target's continuation token
                 s.pos += 1
                 if not self._emit(i, int(g_host[i, self.k])):
                     self._finish(i)
+                    finished += 1
+        return finished

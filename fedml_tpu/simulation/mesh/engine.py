@@ -63,6 +63,7 @@ from ...core.mesh import CLIENT_AXIS
 from ...ml.aggregator.agg_operator import ServerOptimizer, ServerState
 from ...ml.trainer.local_trainer import LocalTrainer
 from ...obs.carry import OPT_FLOPS, round_obs
+from ...obs.jaxhooks import count_put
 from ..round_engine import QUANT_KEY_TAG, next_pow2
 from ..sp.fedavg_api import FedAvgAPI
 from ..staging import AsyncCohortStager  # noqa: F401  (re-export: the
@@ -713,6 +714,8 @@ class MeshFedAvgAPI(FedAvgAPI):
         shard = NamedSharding(self.mesh, P(None, CLIENT_AXIS))
         put = lambda a: jax.device_put(jnp.asarray(a), shard)
         repl = lambda a: jax.device_put(jnp.asarray(a), self._repl_sharding)
+        count_put(self._tracer,
+                  (idx_blk, mask_blk, w_blk, keys_blk, cohort_blk))
         return (k, steps, put(idx_blk), put(mask_blk), put(w_blk),
                 repl(keys_blk), repl(cohort_blk))
 
@@ -748,6 +751,9 @@ class MeshFedAvgAPI(FedAvgAPI):
                 w = np.pad(w, (0, pad_c))
             data_x, data_y = x, y
         put = lambda a: jax.device_put(jnp.asarray(a), self._data_sharding)
+        # the resident dataset of gather mode is not staged here
+        count_put(self._tracer, (data_x, mask, w) if self._gather
+                  else (data_x, data_y, mask, w))
         dy = data_y if self._gather else put(data_y)
         return clients, pad_c, put(data_x), dy, put(mask), put(w)
 

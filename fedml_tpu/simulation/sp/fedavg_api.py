@@ -33,6 +33,7 @@ from ...ml.trainer.local_trainer import LocalTrainer
 from ...mlops import event, log_round_info
 from ...obs import get_tracer
 from ...obs.carry import obs_host, obs_host_rows, obs_population_rows
+from ...obs.jaxhooks import count_put
 from ..round_engine import make_round_fn, next_pow2
 from ..staging import AsyncCohortStager
 
@@ -541,6 +542,7 @@ class FedAvgAPI:
                                    round=round_idx):
                 clients, idx, mask, w, steps = self._stage_round_arrays(
                     round_idx)
+                count_put(self._tracer, (idx, mask, w))
                 idx, mask, w = (jnp.asarray(idx), jnp.asarray(mask),
                                 jnp.asarray(w))
             key = rng_util.round_key(rng_util.root_key(self.seed),
@@ -577,6 +579,7 @@ class FedAvgAPI:
                     y = np.pad(y,
                                [(0, 0), (0, pad)] + [(0, 0)] * (y.ndim - 2))
                     mask = np.pad(mask, [(0, 0), (0, pad)])
+                count_put(self._tracer, (x, y, mask, w))
                 x, y, mask, w = (jnp.asarray(x), jnp.asarray(y),
                                  jnp.asarray(mask), jnp.asarray(w))
             self.state, metrics, new_c = self.round_fn(
@@ -646,6 +649,8 @@ class FedAvgAPI:
         root = rng_util.root_key(self.seed)
         keys_blk = np.stack([np.asarray(rng_util.round_key(root, r))
                              for r in rounds])
+        count_put(self._tracer,
+                  (idx_blk, mask_blk, w_blk, keys_blk, cohort_blk))
         return (k, steps, jnp.asarray(idx_blk), jnp.asarray(mask_blk),
                 jnp.asarray(w_blk), jnp.asarray(keys_blk),
                 jnp.asarray(cohort_blk))

@@ -40,6 +40,7 @@ from ..core.distributed.communication.fault_injection import (
 from ..core.distributed.reliability import (KEY_UNRELIABLE,
                                              ReliableEndpoint, RoundWAL)
 from ..obs import get_tracer
+from ..obs.jaxhooks import count_put
 from ..simulation.round_engine import make_run_clients, next_pow2
 from ..simulation.sp.fedavg_api import FedAvgAPI
 
@@ -156,6 +157,8 @@ class HierarchicalSiloAPI(FedAvgAPI):
                                + [(0, 0)] * (y.ndim - 2))
                     mask = np.pad(mask, [(0, 0), (0, pad)])
                 idx = None
+            # what silo_partial puts on the device, slice by slice
+            count_put(self._tracer, (idx, x, y, mask, w))
         # identical per-client streams to the flat round: ONE split of the
         # round key over the whole cohort, then sliced per silo
         rngs = np.asarray(jax.random.split(key, len(clients)))

@@ -338,12 +338,21 @@ class Attention(nn.Module):
         batched decode step at b=slots mutate the same buffers), addressed
         through a per-slot ``block_tables`` (b, max_blocks) int32 carried
         as traced data.  ``positions`` is (b, s) — every slot at its own
-        depth.  Writes scatter each new token into
-        ``pool[table[pos // P], :, pos % P]``; reads gather the slot's
-        whole block-table window and mask ``kv_pos <= position``.  The
-        window index of a gathered token IS its logical position, so the
-        softmax (masked to -1e30, exp -> 0.0 exactly in f32) is bitwise
-        what the dense cache computes over the same prefix.
+        depth.  Reads gather the slot's whole block-table window and mask
+        ``kv_pos <= position``.  The window index of a gathered token IS
+        its logical position, so the softmax (masked to -1e30, exp -> 0.0
+        exactly in f32) is bitwise what the dense cache computes over the
+        same prefix.
+
+        The pool is ``(pool_pages, page_tokens, h_kv, d)``: one token's K
+        (or V) for all kv heads is ONE contiguous row, so the write
+        ``pool[table[pos // P], pos % P] = row`` is a scatter of whole
+        trailing slices that XLA performs in place on the donated buffer.
+        A decode program never moves a whole pool
+        (``tests/test_chip_compile.py`` holds the compiled tick and chunk
+        programs to that): a head axis between the two indexed axes makes
+        the chip's compiler re-lay every pool out to this order at entry
+        and back at the aliased output, four pool copies a layer.
 
         Unallocated block-table entries are 0 — the trash page.  Writes
         past a slot's reservation (chunk padding, horizon burn-out) land
@@ -356,25 +365,22 @@ class Attention(nn.Module):
         int8_kv = cfg.kv_cache_dtype == "int8"
         store_dtype = jnp.int8 if int8_kv else cfg.dtype
         pk = self.variable("cache", "k", jnp.zeros,
-                           (pool_pages, cfg.n_kv_heads, ptok, head_dim),
+                           (pool_pages, ptok, cfg.n_kv_heads, head_dim),
                            store_dtype)
         pv = self.variable("cache", "v", jnp.zeros,
-                           (pool_pages, cfg.n_kv_heads, ptok, head_dim),
+                           (pool_pages, ptok, cfg.n_kv_heads, head_dim),
                            store_dtype)
         pos = positions.astype(jnp.int32)                   # (b, s)
         page = jnp.take_along_axis(block_tables, pos // ptok, axis=1)
         offs = pos % ptok                                   # (b, s)
-        # (b, s, hkv, d) — advanced indices (page at axis 0, offs at axis
-        # 2) are separated by the head slice, so numpy indexing moves them
-        # to the front: the scatter target is exactly (b, s, hkv, d)
-        k_w = k.transpose(0, 2, 1, 3)
+        k_w = k.transpose(0, 2, 1, 3)                       # (b, s, hkv, d)
         v_w = v.transpose(0, 2, 1, 3)
         if int8_kv:
             pks = self.variable("cache", "k_scale", jnp.zeros,
-                                (pool_pages, cfg.n_kv_heads, ptok),
+                                (pool_pages, ptok, cfg.n_kv_heads),
                                 jnp.float32)
             pvs = self.variable("cache", "v_scale", jnp.zeros,
-                                (pool_pages, cfg.n_kv_heads, ptok),
+                                (pool_pages, ptok, cfg.n_kv_heads),
                                 jnp.float32)
 
             def quant_rows(x):
@@ -387,24 +393,22 @@ class Attention(nn.Module):
 
             k8, ks = quant_rows(k_w)
             v8, vs = quant_rows(v_w)
-            pk.value = pk.value.at[page, :, offs].set(k8)
-            pv.value = pv.value.at[page, :, offs].set(v8)
-            pks.value = pks.value.at[page, :, offs].set(ks)
-            pvs.value = pvs.value.at[page, :, offs].set(vs)
+            pk.value = pk.value.at[page, offs].set(k8)
+            pv.value = pv.value.at[page, offs].set(v8)
+            pks.value = pks.value.at[page, offs].set(ks)
+            pvs.value = pvs.value.at[page, offs].set(vs)
         else:
-            pk.value = pk.value.at[page, :, offs].set(
-                k_w.astype(cfg.dtype))
-            pv.value = pv.value.at[page, :, offs].set(
-                v_w.astype(cfg.dtype))
+            pk.value = pk.value.at[page, offs].set(k_w.astype(cfg.dtype))
+            pv.value = pv.value.at[page, offs].set(v_w.astype(cfg.dtype))
         # gather the slot windows AFTER the write so a chunk attends to
         # its own earlier tokens (in-chunk causality via the mask below)
         max_blocks = block_tables.shape[1]
         window = max_blocks * ptok
 
         def gather_window(pool):                     # -> (b, hkv, W, ...)
-            g = pool[block_tables]                   # (b, MB, hkv, P, ...)
-            g = jnp.moveaxis(g, 2, 1)                # (b, hkv, MB, P, ...)
-            return g.reshape((b, cfg.n_kv_heads, window) + g.shape[4:])
+            g = pool[block_tables]                   # (b, MB, P, hkv, ...)
+            g = g.reshape((b, window) + g.shape[3:])
+            return jnp.moveaxis(g, 2, 1)
 
         kf = gather_window(pk.value)
         vf = gather_window(pv.value)

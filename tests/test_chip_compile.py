@@ -8,7 +8,9 @@ module is imported — and every such compile lives in THIS file: the process
 that describes the topology loads the TPU's library and keeps it.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,3 +129,88 @@ def test_scatter_merge_compiles_on_four_chip_mesh(topo):
     per_chip = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 + mem.output_size_in_bytes)
     assert per_chip < 16e9
+
+
+# -- the paged decode programs never move a whole page pool -----------------
+
+@pytest.fixture(scope="module")
+def paged_programs(one_chip):
+    """The multi-adapter tick and the prefill chunk program at the serving
+    cell's pool and slot shapes (``benchmarks/workloads/serve-saturated.
+    internlm2-1.8b.json``: 32 slots, 1,281 pages of 16 tokens, 8 kv heads of
+    128, chunk 128, 17 bank rows), two layers deep — what a pool costs is
+    per layer — compiled for the described chip from abstract arguments."""
+    from fedml_tpu.llm.model import LlamaConfig, LlamaLM
+    from fedml_tpu.serving.batching import ContinuousBatchingEngine
+
+    cfg = LlamaConfig(vocab_size=512, dim=2048, n_layers=2, n_heads=16,
+                      n_kv_heads=8, ffn_dim=8192, max_seq_len=1040,
+                      rope_theta=1e6, norm_eps=1e-5, dtype=jnp.bfloat16,
+                      lora_rank=16, lora_alpha=16.0)
+    model = LlamaLM(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ContinuousBatchingEngine(
+        model, params, slots=32, buf_len=1040, adapter_slots=17,
+        kv_page_tokens=16, kv_pool_pages=1281, prefill_chunk_tokens=128)
+    try:
+        pools = jax.tree_util.tree_leaves(eng._pool)
+        out = {}
+        for name, fn, args, _ in eng.step_programs():
+            described = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            out[name] = fn.lower(*described).compile()
+        return out, [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools]
+    finally:
+        eng.stop()
+
+
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%[\w.\-]+ = (?P<type>.*?) (?P<op>[a-z][\w\-]*)\(")
+_HLO_ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]+)\]")
+
+
+def _pool_sized_instructions(hlo: str, pool_elems: int):
+    """(opcode, line) of every instruction, in any computation of the module,
+    whose result holds an array of the pool's element count."""
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            continue
+        for dims in _HLO_ARRAY.findall(m.group("type")):
+            if math.prod(int(d) for d in dims.split(",")) == pool_elems:
+                found.append((m.group("op"), line.strip()))
+                break
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_paged_program_never_moves_a_whole_pool(paged_programs, program):
+    """docs/SERVING.md, Memory plane: the write of the new tokens' K/V is an
+    in-place update of the donated pool.  In the optimized module a pool is
+    the result of a parameter, of the scatter that updates it (one per pool,
+    alone in its fusion) and of the plumbing that names it, and of nothing
+    that reads or writes all of it: no copy, transpose, relayout or
+    prefetch.  Every pool argument is aliased to its output."""
+    compiled, pools = paged_programs
+    compiled = compiled[program]
+    hlo = compiled.as_text()
+    assert len({p.shape for p in pools}) == 1
+    instrs = _pool_sized_instructions(hlo, pools[0].size)
+    naming = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+    moving = [(op, line[:200]) for op, line in instrs
+              if op not in naming | {"scatter", "fusion"}]
+    assert not moving, moving
+    # the update: one scatter per pool, each the root of its own fusion
+    scatters = [line for op, line in instrs if op == "scatter"]
+    fusions = [line for op, line in instrs if op == "fusion"]
+    assert len(scatters) == len(pools), scatters
+    assert all(line.startswith("ROOT ") for line in scatters)
+    assert len(fusions) == len(pools), [f[:200] for f in fusions]
+    # donated and aliased: the update writes the argument's own buffer
+    aliases = re.search(r"input_output_alias=\{(.*?) \}, ", hlo).group(1)
+    assert aliases.count("-alias)") == len(pools), aliases
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes

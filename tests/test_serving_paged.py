@@ -147,6 +147,188 @@ def test_paged_matches_dense_multi_token_horizon(paged_setup):
         paged.stop()
 
 
+# ------------------------------------------------ where a write lands
+#
+# The pool is (pool_pages, page_tokens, h_kv, d): ``pool[page, offset]`` is
+# one token's row for every kv head.  Each case drives the paged model the
+# way a tick (s=1) or a chunk (b=1) does, holds its logits to the dense
+# cache's bit for bit, and then reads the pool itself: the row is where the
+# block table says, and no other page was touched.
+
+def _paged_model(cfg, pages, **kw):
+    return LlamaLM(dataclasses.replace(cfg, kv_page_tokens=PTOK,
+                                       kv_pool_pages=pages, **kw))
+
+
+def _apply(m, params, cache, toks, start, btab=None):
+    """One decode call: paged with a block table (``start`` per slot), the
+    dense cache's without (``start`` a scalar).  ``cache=None`` makes it."""
+    variables = {"params": params}
+    if cache is not None:
+        variables["cache"] = cache
+    logits, mut = m.apply(
+        variables, jnp.asarray(toks, jnp.int32), decode=True,
+        start_pos=jnp.asarray(start, jnp.int32),
+        block_tables=None if btab is None else jnp.asarray(btab, jnp.int32),
+        mutable=["cache"])
+    return np.asarray(logits), mut["cache"]
+
+
+def _leaf(cache, name, layer=1):
+    return np.asarray(cache[f"layer_{layer}"]["attention"][name])
+
+
+def _touched(pool, name="k"):
+    """Pages of the layer-1 pool that hold anything but zeros."""
+    a = _leaf(pool, name)
+    return sorted(np.flatnonzero(a.reshape(a.shape[0], -1).any(axis=1)))
+
+
+def _case_page_boundary(cfg, model, params):
+    """Ticks across a page's end: position 7 is the last row of page 3,
+    position 8 the first of page 5."""
+    pm = _paged_model(cfg, 8)
+    btab = [[3, 5, 0, 0, 0, 0]]
+    pool = cache = None
+    for pos, tok in enumerate(range(11, 20)):           # positions 0..8
+        lp, pool = _apply(pm, params, pool, [[tok]], [pos], btab)
+        ld, cache = _apply(model, params, cache, [[tok]], pos)
+        assert np.array_equal(lp, ld), pos
+    for name in ("k", "v"):
+        got, want = _leaf(pool, name), _leaf(cache, name)
+        assert np.array_equal(got[3, 7], want[0, :, 7])
+        assert np.array_equal(got[5, 0], want[0, :, 8])
+        assert np.array_equal(got[3], want[0, :, :8].transpose(1, 0, 2))
+        assert not got[5, 1:].any()
+        assert _touched(pool, name) == [3, 5]
+
+
+def _case_chunk_padding_trash(cfg, model, params):
+    """A 16-token chunk over a 5-token prompt that reserved one page: rows
+    8..15 are padding, their block-table entry is 0, they land in page 0."""
+    pm = _paged_model(cfg, 8)
+    chunk = [[21, 22, 23, 24, 25] + [0] * 11]
+    lp, pool = _apply(pm, params, None, chunk, [0], [[2, 0, 0, 0, 0, 0]])
+    ld, cache = _apply(model, params, None, chunk, 0)
+    assert np.array_equal(lp[0, :5], ld[0, :5])
+    for name in ("k", "v"):
+        got, want = _leaf(pool, name), _leaf(cache, name)
+        assert np.array_equal(got[2], want[0, :, :8].transpose(1, 0, 2))
+        assert np.array_equal(got[0], want[0, :, 8:16].transpose(1, 0, 2))
+        assert _touched(pool, name) == [0, 2]
+
+
+def _case_idle_slots_trash(cfg, model, params):
+    """A tick with two idle slots (zero block tables, position 0) beside a
+    live one: both write row 0 of the trash page at once, and the live
+    slot's row and logits are what the same tick gives with the idle slots
+    on pages of their own."""
+    pm = _paged_model(cfg, 8)
+    live = [4, 0, 0, 0, 0, 0]
+    _, pool = _apply(pm, params, None, [[31, 32, 33]], [0], [live])
+    toks, poss = [[0], [34], [0]], [0, 3, 0]
+    apart_l, apart = _apply(
+        pm, params, pool, toks, poss,
+        [[6, 0, 0, 0, 0, 0], live, [7, 0, 0, 0, 0, 0]])
+    both_l, both = _apply(pm, params, pool, toks, poss,
+                          [[0] * 6, live, [0] * 6])
+    assert np.array_equal(both_l, apart_l)
+    for name in ("k", "v"):
+        got = _leaf(both, name)
+        assert np.array_equal(got[4], _leaf(apart, name)[4])
+        assert got[4, 3].any()
+        assert np.array_equal(got[0, 0], _leaf(apart, name)[6, 0])
+        assert _touched(both, name) == [0, 4]
+        assert not got[0, 1:].any()
+
+
+def _case_sharer_keeps_lent_pages(cfg, model, params):
+    """Two full pages lent to a second slot: its replay starts past them
+    (a chunk from position 16, then a tick beside the owner), reads them,
+    and leaves them bit for bit as the owner wrote them."""
+    pm = _paged_model(cfg, 10)
+    prefix = [list(range(40, 56))]                        # pages 2 and 3
+    owner, sharer = [2, 3, 7, 0, 0, 0], [2, 3, 6, 0, 0, 0]
+    _, pool = _apply(pm, params, None, prefix, [0], [owner])
+    _, cache = _apply(model, params, None, prefix, 0)
+    lent = {n: _leaf(pool, n)[[2, 3]].copy() for n in ("k", "v")}
+    tail = [[61, 62, 63] + [0] * 13]
+    lp, pool = _apply(pm, params, pool, tail, [16], [sharer])
+    ld, cache = _apply(model, params, cache, tail, 16)
+    assert np.array_equal(lp[0, :3], ld[0, :3])
+    # one tick of both: the owner at 16 in its own page 7, the sharer at
+    # 19 (two slots against the dense cache's one: close, not bitwise)
+    lp, pool = _apply(pm, params, pool, [[70], [64]], [16, 19],
+                      [owner, sharer])
+    ld, cache = _apply(model, params, cache, [[64]], 19)
+    np.testing.assert_allclose(lp[1], ld[0], rtol=1e-5, atol=1e-5)
+    for name in ("k", "v"):
+        got, want = _leaf(pool, name), _leaf(cache, name)
+        assert np.array_equal(got[[2, 3]], lent[name])
+        assert np.array_equal(got[6, :3],
+                              want[0, :, 16:19].transpose(1, 0, 2))
+        np.testing.assert_allclose(got[6, 3], want[0, :, 19], rtol=1e-5,
+                                   atol=1e-6)
+        assert got[7, 0].any() and not got[7, 1:].any()
+
+
+def _case_int8_pools_and_scales(cfg, model, params):
+    """The int8 twin: four pools a layer; a chunk and a tick across a page's
+    end put each row's values and its scale where the dense int8 cache has
+    them."""
+    dense8 = LlamaLM(dataclasses.replace(cfg, kv_cache_dtype="int8"))
+    pm = _paged_model(cfg, 8, kv_cache_dtype="int8")
+    btab = [[5, 1, 0, 0, 0, 0]]
+    chunk = [list(range(3, 11))]                          # positions 0..7
+    lp, pool = _apply(pm, params, None, chunk, [0], btab)
+    ld, cache = _apply(dense8, params, None, chunk, 0)
+    assert np.array_equal(lp, ld)
+    lp, pool = _apply(pm, params, pool, [[12]], [8], btab)
+    ld, cache = _apply(dense8, params, cache, [[12]], 8)
+    assert np.array_equal(lp, ld)
+    for name in ("k", "v"):
+        got, want = _leaf(pool, name), _leaf(cache, name)
+        assert got.dtype == np.int8 and got.shape == (8, PTOK, 2, 8)
+        assert np.array_equal(got[5], want[0, :, :8].transpose(1, 0, 2))
+        assert np.array_equal(got[1, 0], want[0, :, 8])
+        sc, want_sc = _leaf(pool, name + "_scale"), \
+            _leaf(cache, name + "_scale")
+        assert sc.shape == (8, PTOK, 2)
+        assert np.array_equal(sc[5], want_sc[0, :, :8].T)
+        assert np.array_equal(sc[1, 0], want_sc[0, :, 8])
+        assert _touched(pool, name) == [1, 5]
+        assert _touched(pool, name + "_scale") == [1, 5]
+
+
+def _case_horizon4(cfg, model, params):
+    """horizon=4: the pool is the scan's carry.  Ten tokens burn two lanes
+    of the third dispatch past the reservation (into the trash page), the
+    answers cross a page's end mid-horizon, and the streams are those of
+    the dense engine one token at a time."""
+    dense = ContinuousBatchingEngine(model, params, slots=2, buf_len=BUF)
+    paged = _paged(model, params, slots=2, horizon=4)
+    reqs = [([5, 17, 42, 8, 9], 0.0, 0), (list(range(1, 15)), 0.8, 3)]
+    try:
+        def battery(eng):
+            qs = [eng.submit(p, max_new_tokens=10, temperature=t, seed=s)
+                  for p, t, s in reqs]
+            return [_drain(q) for q in qs]
+        assert battery(paged) == battery(dense)
+        kv = paged.kv_stats()
+        assert kv["pages_free"] == kv["pool_pages"] - 1
+    finally:
+        dense.stop()
+        paged.stop()
+
+
+@pytest.mark.parametrize("case", [
+    _case_page_boundary, _case_chunk_padding_trash, _case_idle_slots_trash,
+    _case_sharer_keeps_lent_pages, _case_int8_pools_and_scales,
+    _case_horizon4], ids=lambda f: f.__name__[len("_case_"):])
+def test_paged_write_lands_where_the_block_table_says(paged_setup, case):
+    case(*paged_setup)
+
+
 # -------------------------------------------- pages, sharing, parking
 
 def test_prefix_page_sharing_and_release(paged_setup):

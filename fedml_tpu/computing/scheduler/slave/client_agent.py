@@ -264,8 +264,17 @@ class FedMLClientAgent:
             return
         try:
             dest = os.path.join(self.work_dir, "agent_upgrade", version)
-            ws = fetch_job_package(str(pkg), dest)
             marker = os.path.join(self.work_dir, "agent_upgrade", "current")
+            if os.path.isdir(dest) and os.path.exists(marker):
+                with open(marker) as f:
+                    if f.read().splitlines()[:1] == [version]:
+                        # the agent this upgrade respawned reads the
+                        # plane's messages anew: staging it again would
+                        # empty the directory it runs from and exit again
+                        log.info("agent %d: OTA %s is already current",
+                                 self.device_id, version)
+                        return
+            ws = fetch_job_package(str(pkg), dest)
             tmp = marker + ".tmp"
             with open(tmp, "w") as f:
                 f.write(f"{version}\n{ws}\n")

@@ -18,7 +18,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+import json
+import math
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -28,6 +30,17 @@ from jax.sharding import PartitionSpec as P
 from ..core.mesh import MODEL_AXIS, SEQ_AXIS
 from ..models.base import FlaxModel
 from ..ops.attention import blockwise_attention, flash_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """A published ``rope_scaling`` of type ``yarn`` (arXiv:2309.00071)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,6 +82,32 @@ class LlamaConfig:
     #: (llm/moe.py — EP has no reference counterpart, SURVEY §2.9).
     n_experts: int = 0
     moe_top_k: int = 2
+    #: the experts' width; 0 = ``ffn_dim``.  The first
+    #: ``first_dense_layers`` layers keep the dense SwiGLU of ``ffn_dim``.
+    moe_ffn_dim: int = 0
+    first_dense_layers: int = 0
+    #: shared experts: one SwiGLU of ``n_shared_experts * moe_ffn_dim``
+    #: beside the routed ones, for every token
+    n_shared_experts: int = 0
+    moe_scoring: str = "softmax"        # softmax | sigmoid
+    #: group-limited routing: the ``moe_topk_group`` best of ``moe_n_group``
+    #: groups of experts stay (one group: plain top-k)
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_norm_topk: bool = True
+    moe_routed_scale: float = 1.0
+    #: (first, count): the routed experts whose weights live here — the
+    #: router keeps ``n_experts`` outputs, the layer computes its own
+    #: experts' part (llm/moe.py).  None = all.
+    experts_held: Optional[Tuple[int, int]] = None
+    #: latent attention (llm/mla.py): ``kv_lora_rank`` > 0 replaces the
+    #: grouped-query attention by MLA with these published widths
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[YarnScaling] = None
     #: >0 fuses the lm_head matmul into a vocab-chunked streaming softmax
     #: cross-entropy on the training path (ops/xent.py) — peak activation
     #: memory O(B*S*chunk) instead of the O(B*S*V) logit tensor.
@@ -113,6 +152,45 @@ class LlamaConfig:
         if self.kv_pool_pages == 1:
             raise ValueError("kv_pool_pages=1 is only the reserved trash "
                              "page — need at least 2")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scoring={self.moe_scoring!r}: must be "
+                             "'softmax' or 'sigmoid'")
+        if self.n_experts > 0:
+            first, count = self.experts_held or (0, self.n_experts)
+            if not (0 <= first and count >= 1
+                    and first + count <= self.n_experts):
+                raise ValueError(
+                    f"experts_held={self.experts_held}: not a run of the "
+                    f"{self.n_experts} routed experts")
+            if self.n_experts % self.moe_n_group \
+                    or not 1 <= self.moe_topk_group <= self.moe_n_group:
+                raise ValueError(
+                    f"{self.n_experts} experts do not form "
+                    f"{self.moe_n_group} groups of which "
+                    f"{self.moe_topk_group} stay")
+        if self.kv_lora_rank > 0:
+            if min(self.q_lora_rank, self.qk_nope_head_dim,
+                   self.qk_rope_head_dim, self.v_head_dim) <= 0:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, qk_nope_head_dim, "
+                    "qk_rope_head_dim and v_head_dim beside kv_lora_rank")
+            if self.kv_cache_dtype == "int8":
+                raise ValueError(
+                    "kv_cache_dtype='int8' is not defined for latent "
+                    "attention: the cached row [c_kv ; k_r] is one "
+                    "normalised latent shared by every head and read twice, "
+                    "as keys through W_UK and as values through W_UV; the "
+                    "int8 pools hold one scale per kv head and position, "
+                    "and no int8 layout of the latent has been compared "
+                    "with the reference")
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def sparse_layer(self, i: int) -> bool:
+        """Does layer ``i`` carry routed experts?"""
+        return self.n_experts > 0 and i >= self.first_dense_layers
 
     @property
     def store_dtype(self):
@@ -125,14 +203,41 @@ TINY = LlamaConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
 LLAMA2_7B = LlamaConfig()
 
 
-def _rope(x, positions, theta: float):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(d: int, theta: float, y: YarnScaling):
+    """YaRN's inverse frequencies for a rotary width ``d``: pairs that turn
+    more than ``beta_fast`` times within the original context keep their
+    frequency, those that turn less than ``beta_slow`` times are slowed by
+    ``factor``, a linear ramp between.  The blend holds at every position."""
+    def turns_at(turns):        # the pair that makes ``turns`` turns
+        return d * math.log(y.original_max_position_embeddings
+                            / (turns * 2.0 * math.pi)) / (2.0 * math.log(theta))
+    low = max(math.floor(turns_at(y.beta_fast)), 0)
+    high = min(math.ceil(turns_at(y.beta_slow)), d - 1)
+    extra = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / y.factor * ramp + extra * (1.0 - ramp)
+
+
+def _rope(x, positions, theta: float, scaling: Optional[YarnScaling] = None):
     """Rotary position embedding; x: (B, H, S, D_head).  ``positions`` is
     (S,) shared across the batch, or (B, S) per-row (the paged serving
     step, where every slot sits at its own depth)."""
     d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if scaling is not None:
+        freqs = yarn_inv_freq(d, theta, scaling)
+    else:
+        freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, d/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) \
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim)
+        cos, sin = cos * m, sin * m
     if positions.ndim == 2:          # (B, S, d/2) -> (B, 1, S, d/2)
         cos, sin = cos[:, None], sin[:, None]
     x1, x2 = x[..., 0::2], x[..., 1::2]
@@ -195,6 +300,26 @@ class LoRADense(nn.Module):
         return y
 
 
+def _projection(cfg: "LlamaConfig"):
+    """``(features, name) -> module``: an attention projection, with its
+    LoRA adapter where the configuration has a rank."""
+    if cfg.lora_rank > 0:
+        return lambda feats, name: LoRADense(
+            feats, cfg.lora_rank, cfg.lora_alpha, dtype=cfg.dtype,
+            param_dtype=cfg.store_dtype, name=name)
+    return lambda feats, name: nn.Dense(
+        feats, use_bias=False, dtype=cfg.dtype,
+        param_dtype=cfg.store_dtype, name=name)
+
+
+def _attn_impl(cfg: "LlamaConfig") -> str:
+    """``cfg.attn_impl`` with ``auto`` resolved: the Pallas kernel on the
+    TPU, the scan elsewhere."""
+    if cfg.attn_impl != "auto":
+        return cfg.attn_impl
+    return "flash" if jax.default_backend() == "tpu" else "blockwise"
+
+
 class Attention(nn.Module):
     cfg: LlamaConfig
 
@@ -203,14 +328,7 @@ class Attention(nn.Module):
                  block_tables=None):
         cfg = self.cfg
         head_dim = cfg.dim // cfg.n_heads
-        if cfg.lora_rank > 0:
-            dense = lambda feats, name: LoRADense(
-                feats, cfg.lora_rank, cfg.lora_alpha, dtype=cfg.dtype,
-                param_dtype=cfg.store_dtype, name=name)
-        else:
-            dense = lambda feats, name: nn.Dense(
-                feats, use_bias=False, dtype=cfg.dtype,
-                param_dtype=cfg.store_dtype, name=name)
+        dense = _projection(cfg)
         q = dense(cfg.n_heads * head_dim, "wq")(x)
         k = dense(cfg.n_kv_heads * head_dim, "wk")(x)
         v = dense(cfg.n_kv_heads * head_dim, "wv")(x)
@@ -229,10 +347,7 @@ class Attention(nn.Module):
             return self._decode_attend(q, k, v, positions, b, s, head_dim,
                                        dense)
 
-        impl = cfg.attn_impl
-        if impl == "auto":
-            impl = "flash" if jax.default_backend() == "tpu" \
-                else "blockwise"
+        impl = _attn_impl(cfg)
         if impl == "ring":
             from ..ops.ring_attention import ring_attention
             if cfg.n_kv_heads != cfg.n_heads:  # ring path still repeats
@@ -437,36 +552,53 @@ class Attention(nn.Module):
 
 class MLP(nn.Module):
     cfg: LlamaConfig
+    #: 0 = ``cfg.ffn_dim``; a shared expert passes its own
+    width: int = 0
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
+        width = self.width or cfg.ffn_dim
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.store_dtype, name=name)
-        gate = dense(cfg.ffn_dim, "w_gate")(x)
-        up = dense(cfg.ffn_dim, "w_up")(x)
+        gate = dense(width, "w_gate")(x)
+        up = dense(width, "w_up")(x)
         return dense(cfg.dim, "w_down")(nn.silu(gate) * up)
 
 
 class Block(nn.Module):
     cfg: LlamaConfig
+    #: routed experts (and the shared one) in place of the dense SwiGLU
+    sparse: bool = False
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False,
                  block_tables=None):
-        h = x + Attention(self.cfg, name="attention")(
-            RMSNorm(self.cfg.norm_eps, name="attn_norm")(x), positions,
-            decode=decode, block_tables=block_tables)
-        if self.cfg.n_experts > 0:
-            from .moe import MoEMLP
-            ffn = MoEMLP(dim=self.cfg.dim, ffn_dim=self.cfg.ffn_dim,
-                         n_experts=self.cfg.n_experts,
-                         top_k=self.cfg.moe_top_k, dtype=self.cfg.dtype,
-                         param_dtype=self.cfg.store_dtype, name="moe_mlp")
+        cfg = self.cfg
+        if cfg.latent_attention:
+            from .mla import MLA
+            attend = MLA(cfg, name="attention")
         else:
-            ffn = MLP(self.cfg, name="mlp")
-        return h + ffn(RMSNorm(self.cfg.norm_eps, name="mlp_norm")(h))
+            attend = Attention(cfg, name="attention")
+        h = x + attend(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions,
+                       decode=decode, block_tables=block_tables)
+        hn = RMSNorm(cfg.norm_eps, name="mlp_norm")(h)
+        if not self.sparse:
+            return h + MLP(cfg, name="mlp")(hn)
+        from .moe import MoEMLP
+        width = cfg.moe_ffn_dim or cfg.ffn_dim
+        y = MoEMLP(dim=cfg.dim, ffn_dim=width, n_experts=cfg.n_experts,
+                   top_k=cfg.moe_top_k, scoring=cfg.moe_scoring,
+                   n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
+                   norm_topk=cfg.moe_norm_topk,
+                   routed_scale=cfg.moe_routed_scale, held=cfg.experts_held,
+                   dtype=cfg.dtype, param_dtype=cfg.store_dtype,
+                   name="moe_mlp")(hn)
+        if cfg.n_shared_experts:
+            y = y + MLP(cfg, width=cfg.n_shared_experts * width,
+                        name="shared_expert")(hn)
+        return h + y
 
 
 class LlamaLM(nn.Module):
@@ -508,7 +640,8 @@ class LlamaLM(nn.Module):
         else:   # "full": recompute block activations in backward — HBM for
             mk_block = nn.remat(Block, static_argnums=(3,))  # FLOPs
         for i in range(cfg.n_layers):
-            block = mk_block(cfg, name=f"layer_{i}")
+            block = mk_block(cfg, sparse=cfg.sparse_layer(i),
+                             name=f"layer_{i}")
             x = block(x, positions, decode, block_tables)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         if return_hidden:
@@ -523,6 +656,58 @@ class LlamaLM(nn.Module):
         return logits
 
 
+#: published ``config.json`` key -> ``LlamaConfig`` field
+_PUBLISHED_KEYS = {
+    "vocab_size": "vocab_size", "hidden_size": "dim",
+    "num_hidden_layers": "n_layers", "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads", "intermediate_size": "ffn_dim",
+    "max_position_embeddings": "max_seq_len", "rope_theta": "rope_theta",
+    "rms_norm_eps": "norm_eps", "q_lora_rank": "q_lora_rank",
+    "kv_lora_rank": "kv_lora_rank", "qk_nope_head_dim": "qk_nope_head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim", "v_head_dim": "v_head_dim",
+    "n_routed_experts": "n_experts", "num_experts_per_tok": "moe_top_k",
+    "moe_intermediate_size": "moe_ffn_dim",
+    "first_k_dense_replace": "first_dense_layers",
+    "n_shared_experts": "n_shared_experts", "scoring_func": "moe_scoring",
+    "n_group": "moe_n_group", "topk_group": "moe_topk_group",
+    "norm_topk_prob": "moe_norm_topk",
+    "routed_scaling_factor": "moe_routed_scale",
+}
+
+
+def config_from_published(published) -> dict:
+    """``LlamaConfig`` fields from a published ``config.json`` (a path, or
+    the object it holds).  Keys that say nothing about the shape are passed
+    over; a key that asks for mathematics this model does not compute
+    raises."""
+    if not isinstance(published, dict):
+        with open(published) as f:
+            published = json.load(f)
+    out = {field: type(getattr(LlamaConfig, field))(published[key])
+           for key, field in _PUBLISHED_KEYS.items()
+           if published.get(key) is not None}
+    scaling = published.get("rope_scaling")
+    if scaling:
+        kind = scaling.get("type", scaling.get("rope_type"))
+        if kind != "yarn":
+            raise ValueError(f"rope_scaling of type {kind!r}: only 'yarn' "
+                             "is computed here")
+        names = {f.name for f in dataclasses.fields(YarnScaling)}
+        out["rope_scaling"] = YarnScaling(
+            **{k: v for k, v in scaling.items() if k in names})
+    if published.get("topk_method", "none") not in (
+            "none", "greedy", "group_limited_greedy"):
+        # e.g. "noaux_tc": selection by scores plus a learned bias
+        raise ValueError(f"topk_method {published['topk_method']!r} is not "
+                         "computed here (llm/moe.py::route has no "
+                         "score-correction bias)")
+    if published.get("attention_bias") or published.get(
+            "hidden_act", "silu") != "silu":
+        raise ValueError("attention_bias and activations other than silu "
+                         "are not computed here")
+    return out
+
+
 def config_from_args(args, vocab: Optional[int] = None) -> LlamaConfig:
     name = str(getattr(args, "model", "tiny_llama")).lower()
     if name in ("llama", "llama2_7b", "llama-2-7b"):
@@ -530,11 +715,23 @@ def config_from_args(args, vocab: Optional[int] = None) -> LlamaConfig:
     else:
         base = TINY
     overrides = {}
+    # a published config.json first: the arguments below override it
+    published = getattr(args, "llm_config_json", None)
+    if published:
+        overrides.update(config_from_published(published))
     for field in ("dim", "n_layers", "n_heads", "n_kv_heads", "ffn_dim",
                   "max_seq_len"):
         v = getattr(args, f"llm_{field}", None)
         if v is not None:
             overrides[field] = int(v)
+    for field in ("rope_theta", "norm_eps"):
+        v = getattr(args, f"llm_{field}", None)
+        if v is not None:
+            overrides[field] = float(v)
+    held = getattr(args, "llm_experts_held", None)
+    if held:        # "first,count" or a pair
+        first, count = (held.split(",") if isinstance(held, str) else held)
+        overrides["experts_held"] = (int(first), int(count))
     if vocab:
         overrides["vocab_size"] = int(vocab)
     impl = getattr(args, "attn_impl", None)

@@ -3,29 +3,47 @@ parallelism inventory (SURVEY §2.9 lists EP as absent from the reference;
 it exists here because a TPU-native LLM stack should scale FFN capacity
 without scaling per-token FLOPs).
 
-Design (XLA-first, static shapes throughout):
+Design (static shapes throughout, nothing dropped):
 
-- **Router**: top-k softmax gating with a load-balancing auxiliary loss
-  (mean(token-fraction · prob-fraction) · E², the standard switch loss).
-- **Dispatch**: capacity-limited one-hot dispatch/combine einsums — the
-  dense-mask formulation XLA turns into all-to-alls when the expert axis
-  is sharded.  Tokens over capacity are dropped (their combine weight is
-  zero), which keeps every shape static.
-- **EP sharding**: expert-indexed tensors carry a
-  ``with_sharding_constraint`` over the ``model`` mesh axis, so under jit
-  each device holds ``E / ep`` experts and the dispatch einsum lowers to
-  an ICI all-to-all.
+- **Router** (:func:`route`): softmax or sigmoid scores over ALL experts,
+  group-limited top-k (the experts form ``n_group`` groups, a group scores
+  the sum of its two best experts, the ``topk_group`` best groups stay and
+  the k best experts among them are chosen; one group is plain top-k —
+  the same code), optional renormalisation over the chosen k and a
+  constant scale.  The softmax router also sows the load-balancing
+  auxiliary loss (mean(token-fraction · prob-fraction) · E², the standard
+  switch loss).
+- **Dispatch** (:func:`expert_ffn`): the ``N·k`` (token, expert) pairs are
+  sorted by expert, the held experts' SwiGLU runs as three grouped matmuls
+  (``jax.lax.ragged_dot``) over the sorted rows, and every pair's output
+  goes back to its token with its gate weight.  The shapes depend on
+  ``N·k`` alone, so no skew drops a token.
+- **Which experts live here**: ``held = (first, count)`` — the layer routes
+  over all ``n_experts``, holds the weights of ``count`` consecutive ones
+  and computes their part of the result (the gate keeps its denominator
+  over all chosen experts).  What the absent experts would add is another
+  holder's part; on one chip the layer runs without its exchange.
+- **EP sharding**: under a mesh with a ``model`` axis the experts' weights
+  are sharded over it (``with_sharding_constraint``), every shard computes
+  the part of its own ``E / ep`` experts by the same function and the parts
+  are summed over the axis.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.mesh import MODEL_AXIS
+
+#: the collection a layer sows ``[pairs, experts_hit, load_max]`` into
+#: (int32): pairs computed by the held experts, held experts that got at
+#: least one, the most any one got.  Mutable only where a caller asks.
+COUNTERS = "moe_counters"
 
 
 def _active_mesh(explicit):
@@ -38,26 +56,82 @@ def _active_mesh(explicit):
     return None if ctx.empty else ctx
 
 
+def _ep_mesh(explicit):
+    """The mesh to run expert-parallel on, or None."""
+    mesh = _active_mesh(explicit)
+    if mesh is None or mesh.shape.get(MODEL_AXIS, 1) == 1:
+        return None
+    return mesh
+
+
 def _ep_constraint(x, mesh):
     """Shard axis 0 (experts) over the model axis when a mesh is active."""
-    mesh = _active_mesh(mesh)
-    if mesh is None or MODEL_AXIS not in mesh.shape \
-            or mesh.shape[MODEL_AXIS] == 1:
+    mesh = _ep_mesh(mesh)
+    if mesh is None:
         return x
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    spec = P(MODEL_AXIS) if x.ndim == 1 else \
-        P(*((MODEL_AXIS,) + (None,) * (x.ndim - 1)))
+    spec = P(*((MODEL_AXIS,) + (None,) * (x.ndim - 1)))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def route(scores, top_k: int, n_group: int = 1, topk_group: int = 1,
+          norm_topk: bool = True, scale: float = 1.0):
+    """``scores`` (N, E) float32, positive → ``(gates, experts)``, both
+    (N, k): the k best experts of the ``topk_group`` best groups and their
+    weights."""
+    n, e = scores.shape
+    per = e // n_group
+    best2, _ = jax.lax.top_k(scores.reshape(n, n_group, per), min(2, per))
+    _, groups = jax.lax.top_k(best2.sum(-1), topk_group)        # (N, tg)
+    kept = jnp.any(groups[:, :, None] == jnp.arange(n_group), axis=1)
+    masked = jnp.where(jnp.repeat(kept, per, axis=1), scores, -jnp.inf)
+    gates, experts = jax.lax.top_k(masked, top_k)
+    if norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-20)
+    return gates * scale, experts
+
+
+def expert_ffn(x, gates, experts, w_gate, w_up, w_down, first):
+    """The held experts' part of ``sum_j gates[n, j] * E_experts[n, j](x[n])``.
+
+    ``x`` (N, d); ``gates``, ``experts`` (N, k); ``w_gate``/``w_up``
+    (count, d, f) and ``w_down`` (count, f, d) hold experts ``first`` ..
+    ``first + count - 1`` (``first`` may be traced).  Returns the (N, d)
+    float32 part and the (count,) int32 pairs each held expert computed."""
+    n, k = experts.shape
+    count = w_gate.shape[0]
+    local = experts.reshape(-1) - first
+    here = (local >= 0) & (local < count)
+    key = jnp.where(here, local, count)                # absent experts last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    rows = x[order // k]                               # (N·k, d), by expert
+    dot = lambda a, w: jax.lax.ragged_dot(
+        a, w, sizes, preferred_element_type=jnp.float32)
+    act = (nn.silu(dot(rows, w_gate)) * dot(rows, w_up)).astype(x.dtype)
+    y = dot(act, w_down)
+    # back to (token, choice) order; rows past the held groups count nothing
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    g = jnp.where(here, gates.reshape(-1), 0.0)
+    y = jnp.where(here[:, None], y[back], 0.0) * g[:, None]
+    return y.reshape(n, k, -1).sum(1), sizes
+
+
 class MoEMLP(nn.Module):
-    """Drop-in SwiGLU FFN replacement with E experts, top-k routing."""
+    """Drop-in SwiGLU FFN replacement with E routed experts, top-k routing.
+    (A shared expert is an ordinary ``MLP`` beside it, in ``Block``.)"""
 
     dim: int
     ffn_dim: int
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    scoring: str = "softmax"            # softmax | sigmoid
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    #: (first, count): the experts whose weights this layer holds; None =
+    #: all of them
+    held: Optional[Tuple[int, int]] = None
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     mesh: Optional[Any] = None
@@ -67,64 +141,51 @@ class MoEMLP(nn.Module):
         b, s, dim = x.shape
         n_tok = b * s
         e, k = self.n_experts, self.top_k
-        cap = max(1, int(self.capacity_factor * k * n_tok / e))
+        first, count = self.held or (0, e)
 
         xt = x.reshape(n_tok, dim)
         logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
                           name="router")(xt.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)             # (N, E)
+        if self.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)             # (N, E)
+        gates, experts = route(scores, k, self.n_group, self.topk_group,
+                               self.norm_topk, self.routed_scale)
 
-        # top-k selection, positions assigned per expert by prefix count
-        gate_vals, gate_idx = jax.lax.top_k(probs, k)       # (N, k)
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9)
+        if self.scoring == "softmax":
+            # load-balancing aux loss (store for the trainer to read)
+            me = scores.mean(0)                             # prob fraction
+            ce = jnp.zeros((e,), jnp.float32).at[experts.reshape(-1)].add(
+                1.0) / (n_tok * k)                          # token fraction
+            self.sow("losses", "moe_aux", jnp.sum(me * ce) * e * e)
 
-        # load-balancing aux loss (store for the trainer to read)
-        me = probs.mean(0)                                  # prob fraction
-        ce = jnp.zeros((e,), jnp.float32).at[gate_idx.reshape(-1)].add(
-            1.0) / (n_tok * k)                              # token fraction
-        self.sow("losses", "moe_aux", jnp.sum(me * ce) * e * e)
+        init = nn.initializers.lecun_normal()
+        w = [self.param(name, init, shape, self.param_dtype).astype(self.dtype)
+             for name, shape in (("w_gate", (count, dim, self.ffn_dim)),
+                                 ("w_up", (count, dim, self.ffn_dim)),
+                                 ("w_down", (count, self.ffn_dim, dim)))]
+        xt = xt.astype(self.dtype)
+        mesh = _ep_mesh(self.mesh)
+        if mesh is not None and count % mesh.shape[MODEL_AXIS] == 0:
+            per = count // mesh.shape[MODEL_AXIS]
 
-        # dispatch tensor (N, E, C): token n → slot (e, position) if within
-        # capacity; everything one-hot/static so GSPMD can all-to-all it
-        disp = jnp.zeros((n_tok, e, cap), jnp.float32)
-        comb = jnp.zeros((n_tok, e, cap), jnp.float32)
-        base = jnp.zeros((e,), jnp.float32)  # queue depth is SHARED across
-        # the k branches — independent counters would collide two tokens
-        # into one (expert, slot) and jumble their outputs
-        for j in range(k):                                  # k is tiny (2)
-            ej = gate_idx[:, j]                             # (N,)
-            onehot = jax.nn.one_hot(ej, e, dtype=jnp.float32)
-            pos = jnp.cumsum(onehot, axis=0) - onehot + base[None, :]
-            posj = jnp.take_along_axis(pos, ej[:, None], 1)[:, 0]
-            keep = posj < cap
-            slot = jax.nn.one_hot(posj.astype(jnp.int32), cap,
-                                  dtype=jnp.float32) * keep[:, None]
-            contrib = onehot[:, :, None] * slot[:, None, :]
-            disp = disp + contrib
-            comb = comb + contrib * gate_vals[:, j][:, None, None]
-            base = base + onehot.sum(0)
+            def part(xt, gates, experts, *w):
+                mine = first + jax.lax.axis_index(MODEL_AXIS) * per
+                out, sizes = expert_ffn(xt, gates, experts, *w, mine)
+                return jax.lax.psum(out, MODEL_AXIS), sizes
 
-        expert_in = jnp.einsum("nec,nd->ecd", disp,
-                               xt.astype(jnp.float32)).astype(self.dtype)
-        expert_in = _ep_constraint(expert_in, self.mesh)
-
-        w_gate = self.param("w_gate", nn.initializers.lecun_normal(),
-                            (e, dim, self.ffn_dim), self.param_dtype)
-        w_up = self.param("w_up", nn.initializers.lecun_normal(),
-                          (e, dim, self.ffn_dim), self.param_dtype)
-        w_down = self.param("w_down", nn.initializers.lecun_normal(),
-                            (e, self.ffn_dim, dim), self.param_dtype)
-        h = jnp.einsum("ecd,edf->ecf", expert_in,
-                       _ep_constraint(w_gate.astype(self.dtype), self.mesh))
-        u = jnp.einsum("ecd,edf->ecf", expert_in,
-                       _ep_constraint(w_up.astype(self.dtype), self.mesh))
-        y = jnp.einsum("ecf,efd->ecd", nn.silu(h) * u,
-                       _ep_constraint(w_down.astype(self.dtype), self.mesh))
-        y = _ep_constraint(y, self.mesh)
-
-        out = jnp.einsum("nec,ecd->nd", comb, y.astype(jnp.float32))
+            out, sizes = jax.shard_map(
+                part, mesh=mesh,
+                in_specs=(P(), P(), P()) + (P(MODEL_AXIS),) * 3,
+                out_specs=(P(), P(MODEL_AXIS)), check_vma=False)(
+                    xt, gates, experts,
+                    *[_ep_constraint(m, self.mesh) for m in w])
+        else:
+            out, sizes = expert_ffn(xt, gates, experts, *w, first)
+        self.sow(COUNTERS, "layer", jnp.stack(
+            [sizes.sum(), (sizes > 0).sum(), sizes.max()]).astype(jnp.int32))
         return out.reshape(b, s, dim).astype(x.dtype)
 
 
-__all__ = ["MoEMLP"]
+__all__ = ["MoEMLP", "route", "expert_ffn", "COUNTERS"]

@@ -41,15 +41,22 @@ def _block_scores(q, k, sm_scale):
 
 def blockwise_attention(q, k, v, causal: bool = True,
                         sm_scale: Optional[float] = None,
-                        block_k: int = 256):
+                        block_k: int = 256, q_positions=None):
     """Streaming-softmax attention.
 
-    q, k, v: (..., S, D).  Scans KV in blocks of ``block_k``, carrying the
-    running max m, normalizer l, and unnormalized accumulator — the flash
-    attention recurrence expressed in XLA.
+    q, k: (..., S, D); v: (..., S, Dv), Dv = D unless the values have a
+    width of their own (latent attention: q/k 192, v 128).  Scans KV in
+    blocks of ``block_k``, carrying the running max m, normalizer l, and
+    unnormalized accumulator — the flash attention recurrence expressed in
+    XLA.
 
     GQA: 4-D inputs where k/v carry fewer heads than q are handled by
     broadcasting a grouped view — no repeated-KV materialization.
+
+    ``q_positions`` (broadcastable to q's ``(..., S)``): the position of
+    every query row among the keys, where the queries are not the keys'
+    own first S rows (a prefill chunk over a cache window); the causal
+    mask is then ``key index <= position``.
     """
     if (q.ndim == 4 and k.ndim == 4 and k.shape[1] != q.shape[1]):
         b, h, s_q_, d_ = q.shape
@@ -57,12 +64,15 @@ def blockwise_attention(q, k, v, causal: bool = True,
         assert h % h_kv == 0, (h, h_kv)
         rep = h // h_kv
         qg = q.reshape(b, h_kv, rep, s_q_, d_)
+        if q_positions is not None:
+            q_positions = jnp.broadcast_to(
+                q_positions, (b, h, s_q_)).reshape(b, h_kv, rep, s_q_)
         out = blockwise_attention(qg, k[:, :, None], v[:, :, None],
                                   causal=causal, sm_scale=sm_scale,
-                                  block_k=block_k)
-        return out.reshape(b, h, s_q_, d_)
+                                  block_k=block_k, q_positions=q_positions)
+        return out.reshape(b, h, s_q_, v.shape[-1])
     *lead, s_q, d = q.shape
-    s_k = k.shape[-2]
+    s_k, d_v = k.shape[-2], v.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
     block_k = min(block_k, s_k)
@@ -77,13 +87,13 @@ def blockwise_attention(q, k, v, causal: bool = True,
     # group axis that broadcasts against q's rep axis)
     klead = kp.shape[:-2]
     kb = kp.reshape(*klead, n_blocks, block_k, d)
-    vb = vp.reshape(*klead, n_blocks, block_k, d)
+    vb = vp.reshape(*klead, n_blocks, block_k, d_v)
     # move block axis to front for scan
     perm = (len(lead),) + tuple(range(len(lead))) + (len(lead) + 1, len(lead) + 2)
     kb = jnp.transpose(kb, perm)
     vb = jnp.transpose(vb, perm)
 
-    q_pos = jnp.arange(s_q)
+    q_pos = jnp.arange(s_q) if q_positions is None else q_positions
 
     def body(carry, inp):
         m, l, acc, blk = carry[0], carry[1], carry[2], carry[3]
@@ -92,7 +102,7 @@ def blockwise_attention(q, k, v, causal: bool = True,
         kv_pos = blk * block_k + jnp.arange(block_k)
         valid = kv_pos < s_k
         if causal:
-            valid = valid[None, :] & (kv_pos[None, :] <= q_pos[:, None])
+            valid = valid[None, :] & (kv_pos[None, :] <= q_pos[..., None])
             scores = jnp.where(valid, scores, NEG_INF)
         else:
             scores = jnp.where(valid, scores, NEG_INF)
@@ -107,7 +117,7 @@ def blockwise_attention(q, k, v, causal: bool = True,
 
     m0 = jnp.full((*lead, s_q), NEG_INF, jnp.float32)
     l0 = jnp.zeros((*lead, s_q), jnp.float32)
-    acc0 = jnp.zeros((*lead, s_q, d), jnp.float32)
+    acc0 = jnp.zeros((*lead, s_q, d_v), jnp.float32)
     (m, l, acc, _), _ = jax.lax.scan(body, (m0, l0, acc0, 0), (kb, vb))
     out = acc / jnp.maximum(l[..., None], 1e-30)
     return out.astype(q.dtype)
@@ -563,7 +573,8 @@ def _note_impl(impl: str, q, k) -> None:
 
 
 def _fa_fwd(q, k, v, causal, sm_scale):
-    if _use_pallas(k.shape[2], k.shape[3]):
+    # the kernels take one head width: values of another take the scan
+    if v.shape[-1] == q.shape[-1] and _use_pallas(k.shape[2], k.shape[3]):
         _note_impl("pallas", q, k)
         out, lse = flash_attention_fwd_pallas(q, k, v, causal, sm_scale,
                                               return_lse=True)

@@ -75,6 +75,30 @@ def _unwrap_params(params):
         else params
 
 
+def _moe_counters(mut):
+    """What the sparse layers of one apply sowed (``llm/moe.py``:
+    ``[pairs, experts_hit, load_max]`` a layer), one row a layer; None for
+    a model without such layers."""
+    from ..llm.moe import COUNTERS
+    leaves = jax.tree_util.tree_leaves(mut.get(COUNTERS, {}))
+    return _fold_counters(jnp.stack(leaves)) if leaves else None
+
+
+def _fold_counters(rows):
+    """Rows of ``[pairs, experts_hit, load_max]`` as one: pairs and experts
+    hit summed, the largest load of any."""
+    return jnp.stack([rows[:, 0].sum(), rows[:, 1].sum(), rows[:, 2].max()])
+
+
+def _with_counters(tokens, rows):
+    """The program's int32 result: the tokens, and behind them the experts'
+    counters (``rows`` folded) where the model has any — one array, so they
+    ride the read-back of the tokens."""
+    if rows is None:
+        return tokens
+    return jnp.concatenate([tokens.reshape(-1), _fold_counters(rows)])
+
+
 class _Slot:
     __slots__ = ("live", "q", "pos", "remaining", "eos_id", "cur_tok",
                  "adapter_row",
@@ -83,7 +107,7 @@ class _Slot:
                  # prefill lanes, the admission-split sample key, and the
                  # slot's block-table reservation size
                  "prefilling", "pf_ids", "pf_next", "pf_n", "pf_sub",
-                 "pf_atok", "n_blocks",
+                 "pf_atok", "pf_acc", "n_blocks",
                  # fedslo request-lifecycle telemetry (host monotonic
                  # clocks, engine-thread-confined like the decode state)
                  "t_submit", "t_admit", "t_prefill_end", "t_first",
@@ -107,6 +131,7 @@ class _Slot:
         self.pf_n = 0
         self.pf_sub = None
         self.pf_atok = None
+        self.pf_acc = None
         self.n_blocks = 0
         self.t_submit = 0.0
         self.t_admit: Optional[float] = None
@@ -335,6 +360,7 @@ class ContinuousBatchingEngine:
             else batched_step_mt
 
         if self.paged:
+            from ..llm.moe import COUNTERS
             pm = self.paged_model
 
             @partial(jax.jit, donate_argnums=(1,))
@@ -351,19 +377,20 @@ class ContinuousBatchingEngine:
                     logits, mut = pm.apply(
                         {"params": params, "cache": pool}, toks[:, None],
                         decode=True, start_pos=poss, block_tables=btabs,
-                        mutable=["cache"])
+                        mutable=["cache", COUNTERS])
                     split = jax.vmap(jax.random.split)(keys)
                     keys2, subs = split[:, 0], split[:, 1]
                     nxt = jax.vmap(
                         lambda lg, sub, temp: _sample_live(
                             lg, sub, temp, self.top_k, self.top_p)
                     )(logits[:, 0], subs, temps)
-                    return (mut["cache"], nxt, poss + 1, keys2), nxt
+                    return (mut["cache"], nxt, poss + 1, keys2), \
+                        (nxt, _moe_counters(mut))
 
-                (pool, toks, poss, keys), hist = jax.lax.scan(
+                (pool, toks, poss, keys), (hist, counts) = jax.lax.scan(
                     body, (pool, toks, poss, keys), None,
                     length=self.horizon)
-                return hist.T, pool, keys
+                return _with_counters(hist.T, counts), pool, keys
 
             @partial(jax.jit, donate_argnums=(2,))
             def paged_step_mt(params, bank, pool, btabs, toks, poss, keys,
@@ -378,37 +405,45 @@ class ContinuousBatchingEngine:
                         {"params": params, "lora": lora_slots,
                          "cache": pool}, toks[:, None],
                         decode=True, start_pos=poss, block_tables=btabs,
-                        mutable=["cache"])
+                        mutable=["cache", COUNTERS])
                     split = jax.vmap(jax.random.split)(keys)
                     keys2, subs = split[:, 0], split[:, 1]
                     nxt = jax.vmap(
                         lambda lg, sub, temp: _sample_live(
                             lg, sub, temp, self.top_k, self.top_p)
                     )(logits[:, 0], subs, temps)
-                    return (mut["cache"], nxt, poss + 1, keys2), nxt
+                    return (mut["cache"], nxt, poss + 1, keys2), \
+                        (nxt, _moe_counters(mut))
 
-                (pool, toks, poss, keys), hist = jax.lax.scan(
+                (pool, toks, poss, keys), (hist, counts) = jax.lax.scan(
                     body, (pool, toks, poss, keys), None,
                     length=self.horizon)
-                return hist.T, pool, keys
+                return _with_counters(hist.T, counts), pool, keys
 
             @partial(jax.jit, donate_argnums=(2,))
             def paged_chunk(params, lora, pool, chunk, btab, start, idx,
-                            key, temp):
+                            key, temp, acc):
                 # one fixed-shape (1, C) prefill chunk for one slot; the
                 # sample index is TRACED so intermediate chunks (token
                 # discarded) and the final chunk (token at n-1-chunk_start)
-                # ride one compiled program
+                # ride one compiled program.  ``acc`` is the last chunk's
+                # result for the same request (None for a model without
+                # sparse layers): the experts' counters add up behind the
+                # token from chunk to chunk, and the final chunk's one
+                # read-back brings the request's
                 params = dequantize_params(params, wdtype)
                 variables = {"params": params, "cache": pool}
                 if lora is not None:
                     variables["lora"] = lora
                 logits, mut = pm.apply(
                     variables, chunk, decode=True, start_pos=start,
-                    block_tables=btab, mutable=["cache"])
+                    block_tables=btab, mutable=["cache", COUNTERS])
                 tok = _sample_live(logits[0, idx], key, temp, self.top_k,
                                    self.top_p)
-                return tok, mut["cache"]
+                counts = _moe_counters(mut)
+                if counts is not None:      # added to the request's so far
+                    counts = jnp.stack([acc[1:], counts])
+                return _with_counters(tok, counts), mut["cache"]
 
             self._step = paged_step if self.registry is None \
                 else paged_step_mt
@@ -442,6 +477,17 @@ class ContinuousBatchingEngine:
             _, shapes = jax.eval_shape(_shape_probe, self.raw_params)
             self._pool = jax.tree_util.tree_map(
                 lambda s: jnp.zeros(s.shape, s.dtype), shapes["cache"])
+            # what one cached token costs over all layers, whatever a page
+            # holds (K and V rows of every kv head; one latent row)
+            self._kv_bytes_per_token = sum(
+                p.nbytes for p in jax.tree_util.tree_leaves(self._pool)
+            ) // (pool_pages * ptok)
+            # sparse layers: the chunk program's first accumulator
+            cfg = self.paged_model.cfg
+            self._moe_layers = sum(
+                cfg.sparse_layer(i) for i in range(cfg.n_layers))
+            self._chunk_acc0 = jnp.zeros((4,), jnp.int32) \
+                if self._moe_layers else None
         else:
             # materialize the stacked cache template from one dummy
             # prefill (MT engines pass the zero bank row — a lora_rank>0
@@ -480,6 +526,13 @@ class ContinuousBatchingEngine:
         # thread once live slots drain (admission pauses meanwhile)
         self._pending_params = None
         self._ticks = 0  # batched steps executed (observability)
+        # what the sparse layers did (paged engines; kv_stats): pairs the
+        # held experts computed in ticks and finished prefills; held
+        # experts that got a token and sparse layers run, over the ticks
+        self._expert_pairs = 0
+        self._experts_hit = 0
+        self._moe_layers_ticked = 0
+        self._expert_load_max = 0
         self._iters = 0  # engine-loop passes (the serve.iter span's index)
         self._requests = 0  # requests submitted; the next one's id
         # host-side serving telemetry (always maintained; mirrored onto
@@ -672,7 +725,8 @@ class ContinuousBatchingEngine:
                           jnp.zeros((1, self.prefill_chunk), jnp.int32),
                           jnp.zeros((1, self.max_blocks), jnp.int32),
                           jnp.zeros((1,), jnp.int32), jnp.int32(0),
-                          jax.random.PRNGKey(0), jnp.float32(0.0))
+                          jax.random.PRNGKey(0), jnp.float32(0.0),
+                          self._chunk_acc0)
             return [
                 ("decode_step", self._step, step_args, step_donate),
                 ("prefill_chunk", self._chunk, chunk_args, (2,)),
@@ -727,10 +781,12 @@ class ContinuousBatchingEngine:
         (engine thread, host clocks only — the jitted step is untouched):
         the phase breakdown lands in the serve histograms, the objective
         windows, and — when tracing is on — a retroactive span tree on a
-        per-slot synthetic lane (same-slot requests never overlap, so
-        B/E pairing survives the export's timestamp sort).  That tree is
-        one request's lifetime, not what this thread was doing: the live
-        ``serve.iter`` tree (``_run_loop``) says that."""
+        synthetic lane of the request's own (a lane per slot crossed: a
+        request that waited while its slot still served another begins,
+        with its queue span, before that one ends, and B/E pairing after
+        the export's timestamp sort then depended on which thread ran
+        first).  That tree is one request's lifetime, not what this thread
+        was doing: the live ``serve.iter`` tree (``_run_loop``) says that."""
         now = time.monotonic()
         queue_s = max(s.t_admit - s.t_submit, 0.0)
         prefill_s = max(s.t_prefill_end - s.t_admit, 0.0)
@@ -753,7 +809,8 @@ class ContinuousBatchingEngine:
         tracer = get_tracer()
         if not tracer.enabled:
             return
-        lane = -16 - i  # per-slot synthetic lane, clear of COMPILE_TID
+        # clear of COMPILE_TID; 4096 requests later a lane is free again
+        lane = -16 - (s.request or 0) % 4096
         # each call reads the clock anew, a little later: the child that
         # ends with its parent is written first, so that it ends inside it
         tracer.complete("serve.decode", decode_s, cat="serve", tid=lane,
@@ -947,6 +1004,7 @@ class ContinuousBatchingEngine:
         s.pf_next = full * self.kv_page_tokens
         s.pf_sub = sub
         s.pf_atok = req.get("adapter_token")
+        s.pf_acc = self._chunk_acc0
         s.n_blocks = need_blocks
         s.t_submit = req.get("t_submit", t_admit)
         s.t_admit = t_admit
@@ -981,13 +1039,15 @@ class ContinuousBatchingEngine:
             final = cs + C >= n
             with tracer.span("serve.chunk", cat="engine", slot=i,
                              request=s.request, start=cs,
-                             tokens=min(C, n - cs), final=int(final)):
-                self._prefill_chunk(tracer, i, s, cs, final)
+                             tokens=min(C, n - cs), final=int(final)) as span:
+                self._prefill_chunk(tracer, span, i, s, cs, final)
 
-    def _prefill_chunk(self, tracer, i: int, s: "_Slot", cs: int,
+    def _prefill_chunk(self, tracer, span, i: int, s: "_Slot", cs: int,
                        final: bool) -> None:
         """One chunk of slot ``i``'s prompt from position ``cs``; the
-        final one flips the slot live and emits its first token."""
+        final one flips the slot live and emits its first token (and reads,
+        behind it, what the experts computed over the request's chunks:
+        ``span`` gets it as ``expert_pairs``)."""
         C = self.prefill_chunk
         n = s.pf_n
         with tracer.span("serve.chunk.gather", cat="engine",
@@ -1008,14 +1068,22 @@ class ContinuousBatchingEngine:
                 self.raw_params, lora, self._pool, jnp.asarray(chunk),
                 jnp.asarray(self._btabs[i][None]),
                 jnp.asarray([cs], jnp.int32), jnp.int32(idx), s.pf_sub,
-                jnp.float32(self._temps[i]))
+                jnp.float32(self._temps[i]), s.pf_acc)
         with self._stats_lock:
             self._chunks_total += 1
         if not final:
             s.pf_next = cs + C
+            if s.pf_acc is not None:
+                s.pf_acc = tok
             return
         with tracer.span("serve.chunk.readback", cat="engine"):
-            tok_host = int(tok)
+            out = np.asarray(tok).reshape(-1)
+        tok_host = int(out[0])
+        s.pf_acc = None
+        if out.size > 1:
+            with self._stats_lock:
+                self._expert_pairs += int(out[1])
+            span.set(expert_pairs=int(out[1]))
         s.prefilling = False
         s.live = True
         s.pos = n
@@ -1092,6 +1160,8 @@ class ContinuousBatchingEngine:
             out: Dict[str, Any] = {"ticks": self._ticks}
             chunks = self._chunks_total
             shared, private = self._pages_shared, self._pages_private
+            pairs, hit = self._expert_pairs, self._experts_hit
+            layers = self._moe_layers_ticked
         if self.paged:
             out["pool"] = dict(self.page_pool.stats)
             out["pages_free"] = self.page_pool.pages_free
@@ -1099,6 +1169,10 @@ class ContinuousBatchingEngine:
             out["prefill_chunks"] = chunks
             out["pages_shared"] = shared
             out["pages_private"] = private
+            out["kv_bytes_per_token"] = self._kv_bytes_per_token
+            out["expert_pairs"] = pairs
+            out["experts_hit"] = hit
+            out["moe_layers_ticked"] = layers
             if self.prefix_cache is not None:
                 out["prefix"] = dict(self.prefix_cache.stats)
         if self.registry is not None:
@@ -1247,6 +1321,11 @@ class ContinuousBatchingEngine:
                     tracer.counter("serve.kv_page_hit_rate",
                                    shared / tot if tot else 0.0)
                     tracer.counter("serve.prefill_chunks", chunks)
+                    tracer.counter("serve.kv_bytes_per_token",
+                                   self._kv_bytes_per_token)
+                    if self._moe_layers:
+                        tracer.counter("serve.expert_load_max",
+                                       self._expert_load_max)
                 if self._store_mode:
                     st = self.registry.stats
                     tracer.counter("serve.adapter_cache_hits",
@@ -1325,12 +1404,29 @@ class ContinuousBatchingEngine:
                 else:
                     self._caches = kv
             with tracer.span("serve.tick.readback", cat="engine"):
-                toks_host = np.asarray(toks)  # (n_slots, horizon)
+                # (n_slots, horizon) tokens; behind them the experts'
+                # counters where the model has sparse layers
+                flat = np.asarray(toks).reshape(-1)
+                n_tok = self.n_slots * self.horizon
+                toks_host = flat[:n_tok].reshape(self.n_slots, self.horizon)
+                counters = flat[n_tok:]
                 # copy carry keys back for LIVE slots only: a prefilling
                 # slot's admission key must not advance with the burn
                 # splits its lane rode along for (its first real sample
                 # comes later)
                 keys_host = np.asarray(keys)
+            if counters.size:
+                # counted before a token goes out, so that a caller who has
+                # its last token finds the tick that made it in kv_stats()
+                pairs, hit, most = (int(c) for c in counters)
+                with self._stats_lock:
+                    self._expert_pairs += pairs
+                    self._experts_hit += hit
+                    self._moe_layers_ticked += self._moe_layers * self.horizon
+                self._expert_load_max = most  # fedrace: disable=unguarded-shared-write
+                if tracing:
+                    tick.set(expert_pairs=pairs, experts_hit=hit,
+                             expert_load_max=most)
             with tracer.span("serve.tick.emit", cat="engine") as emit:
                 finished = 0
                 for i in live:

@@ -153,17 +153,7 @@ def paged_programs(one_chip):
     eng = ContinuousBatchingEngine(
         model, params, slots=32, buf_len=1040, adapter_slots=17,
         kv_page_tokens=16, kv_pool_pages=1281, prefill_chunk_tokens=128)
-    try:
-        pools = jax.tree_util.tree_leaves(eng._pool)
-        out = {}
-        for name, fn, args, _ in eng.step_programs():
-            described = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=one_chip), args)
-            out[name] = fn.lower(*described).compile()
-        return out, [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools]
-    finally:
-        eng.stop()
+    return _engine_programs(eng, one_chip)
 
 
 _HLO_INSTR = re.compile(
@@ -186,18 +176,71 @@ def _pool_sized_instructions(hlo: str, pool_elems: int):
     return found
 
 
+def _engine_programs(eng, one_chip):
+    """The engine's step programs compiled for the described chip from
+    abstract arguments, and the shapes of its pools."""
+    try:
+        pools = jax.tree_util.tree_leaves(eng._pool)
+        out = {}
+        for name, fn, args, _ in eng.step_programs():
+            described = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            out[name] = fn.lower(*described).compile()
+        return out, [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools]
+    finally:
+        eng.stop()
+
+
+@pytest.fixture(scope="module")
+def latent_programs(one_chip):
+    """The same two programs of a latent-attention, sparse-expert model at
+    the widths and the engine geometry of ``benchmarks/workloads/
+    serve-saturated-2k.a.x-k1-ep16-d7.json`` (64 slots, 10,241 pages of 16
+    tokens of 640 numbers: the 576 of the latent in whole lane tiles, chunk
+    512, 17 bank rows; 12 of 192 experts held), one dense and one sparse
+    layer deep.  The weights are shapes only: at
+    these widths two layers are 2.3 GB."""
+    from fedml_tpu.llm.model import LlamaConfig, LlamaLM, YarnScaling
+    from fedml_tpu.serving.batching import ContinuousBatchingEngine
+
+    cfg = LlamaConfig(
+        vocab_size=512, dim=7168, n_layers=2, n_heads=64, n_kv_heads=64,
+        ffn_dim=18432, max_seq_len=2896, rope_theta=1e4, norm_eps=1e-6,
+        dtype=jnp.bfloat16, lora_rank=16, lora_alpha=16.0,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling=YarnScaling(32, 4096, 32, 1, 1, 1),
+        n_experts=192, moe_top_k=8, moe_ffn_dim=2048, first_dense_layers=1,
+        n_shared_experts=1, moe_scoring="sigmoid", moe_n_group=8,
+        moe_topk_group=4, moe_routed_scale=2.5, experts_held=(0, 12))
+    model = LlamaLM(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ContinuousBatchingEngine(
+        model, params, slots=64, buf_len=2896, adapter_slots=17,
+        kv_page_tokens=16, kv_pool_pages=10241, prefill_chunk_tokens=512)
+    return _engine_programs(eng, one_chip)
+
+
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
-def test_paged_program_never_moves_a_whole_pool(paged_programs, program):
-    """docs/SERVING.md, Memory plane: the write of the new tokens' K/V is an
-    in-place update of the donated pool.  In the optimized module a pool is
-    the result of a parameter, of the scatter that updates it (one per pool,
-    alone in its fusion) and of the plumbing that names it, and of nothing
-    that reads or writes all of it: no copy, transpose, relayout or
-    prefetch.  Every pool argument is aliased to its output."""
-    compiled, pools = paged_programs
+@pytest.mark.parametrize("model", ["dense", "latent"])
+def test_paged_program_never_moves_a_whole_pool(request, model, program):
+    """docs/SERVING.md, Memory plane: the write of the new tokens' K/V (or
+    latent row) is an in-place update of the donated pool.  In the optimized
+    module a pool is the result of a parameter, of the scatter that updates
+    it (one per pool, alone in its fusion) and of the plumbing that names it,
+    and of nothing that reads or writes all of it: no copy, transpose,
+    relayout or prefetch.  Every pool argument is aliased to its output."""
+    compiled, pools = request.getfixturevalue(
+        {"dense": "paged_programs", "latent": "latent_programs"}[model])
     compiled = compiled[program]
     hlo = compiled.as_text()
     assert len({p.shape for p in pools}) == 1
+    if model == "latent":
+        assert pools[0].shape == (10241, 16, 640) and len(pools) == 2
+        # the experts run as grouped matmuls over the sorted pairs
+        assert hlo.count("ragged-dot") >= 3
     instrs = _pool_sized_instructions(hlo, pools[0].size)
     naming = {"parameter", "get-tuple-element", "tuple", "bitcast"}
     moving = [(op, line[:200]) for op, line in instrs

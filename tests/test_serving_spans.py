@@ -247,6 +247,88 @@ def test_off_means_no_event_and_no_other_transfer(mt_model):
     assert (on.device_puts, on.device_gets) == (base.device_puts, base.device_gets)
 
 
+# -- a model with sparse layers: what the expert layers did ------------------
+
+@pytest.fixture(scope="module")
+def traced_sparse():
+    """A traced run of a paged engine over a small latent-attention model
+    with one dense and two sparse layers (8 of 32 experts held)."""
+    from fedml_tpu.llm.model import YarnScaling
+    cfg = LlamaConfig(vocab_size=97, dim=32, n_layers=3, n_heads=4, n_kv_heads=4,
+                      ffn_dim=48, max_seq_len=BUF, dtype=jnp.float32,
+                      attn_impl="blockwise", lora_rank=4, q_lora_rank=12,
+                      kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+                      v_head_dim=8, rope_scaling=YarnScaling(4, 16, 32, 1, 1, 1),
+                      n_experts=32, moe_top_k=4, moe_ffn_dim=16,
+                      first_dense_layers=1, n_shared_experts=1,
+                      moe_scoring="sigmoid", moe_n_group=4, moe_topk_group=2,
+                      moe_routed_scale=2.5, experts_held=(0, 8))
+    model = LlamaLM(cfg)
+    variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    tracer = obs.configure(enabled=True, reset=True, jax_hooks=False)
+    try:
+        eng = ContinuousBatchingEngine(model, variables["params"], slots=3,
+                                       buf_len=BUF, adapter_slots=4,
+                                       kv_page_tokens=PTOK,
+                                       prefill_chunk_tokens=CHUNK)
+        try:
+            outputs = [[t for t in iter(q.get, None)] for q in [
+                eng.submit(p, max_new_tokens=n) for p, n, _ in REQUESTS]]
+            stats = eng.kv_stats()
+        finally:
+            eng.stop()
+        events = tracer.export_chrome()["traceEvents"]
+    finally:
+        obs.configure(enabled=False, reset=True)
+    return {"events": events, "outputs": outputs, "stats": stats,
+            "spans": spans_of([e for e in events if e["ph"] in "BE"])}
+
+
+def test_sparse_ticks_say_what_the_experts_did(traced_sparse):
+    assert [len(o) for o in traced_sparse["outputs"]] == [n for _, n, _ in REQUESTS]
+    ticks = named(traced_sparse["spans"], "serve.tick")
+    assert ticks and all({"expert_pairs", "experts_hit", "expert_load_max"}
+                         <= set(t["args"]) for t in ticks)
+    for t in ticks:
+        a = t["args"]
+        # three lanes, two sparse layers, four experts a token, eight held
+        assert 0 <= a["expert_pairs"] <= 3 * 2 * 4
+        assert 0 <= a["experts_hit"] <= min(2 * 8, a["expert_pairs"])
+        assert a["expert_load_max"] <= 3 and (a["expert_load_max"] > 0) == (a["expert_pairs"] > 0)
+        assert all(isinstance(a[k], int) for k in ("expert_pairs", "experts_hit", "expert_load_max"))
+    stats = traced_sparse["stats"]
+    assert stats["moe_layers_ticked"] == 2 * len(ticks)
+    assert stats["experts_hit"] == sum(t["args"]["experts_hit"] for t in ticks)
+    chunks = named(traced_sparse["spans"], "serve.chunk")
+    finals = [c for c in chunks if c["args"]["final"]]
+    assert len(finals) == len(REQUESTS)
+    assert all("expert_pairs" in c["args"] for c in finals)
+    assert all("expert_pairs" not in c["args"] for c in chunks if not c["args"]["final"])
+    # a prompt of three chunks computes 48 rows in two sparse layers
+    assert all(0 <= c["args"]["expert_pairs"] <= 48 * 2 * 4 for c in finals)
+    assert stats["expert_pairs"] == sum(t["args"]["expert_pairs"] for t in ticks) \
+        + sum(c["args"]["expert_pairs"] for c in finals) > 0
+
+
+def test_sparse_gauges_and_bytes_a_token(traced_sparse):
+    counters = {}
+    for e in traced_sparse["events"]:
+        if e["ph"] == "C":
+            counters.setdefault(e["name"], []).append(e["args"])
+    # three layers, one latent row (8 + 4 float32 in one lane tile) a token and layer
+    assert traced_sparse["stats"]["kv_bytes_per_token"] == 3 * 128 * 4
+    assert {tuple(a.values()) for a in counters["serve.kv_bytes_per_token"]} == {(1536,)}
+    assert "serve.expert_load_max" in counters
+
+
+def test_dense_engine_has_no_expert_args(traced):
+    ticks = named(traced["spans"], "serve.tick")
+    assert ticks and not any("expert_pairs" in t["args"] for t in ticks)
+    assert not any("expert_pairs" in c["args"] for c in named(traced["spans"], "serve.chunk"))
+    assert not any(e["ph"] == "C" and e["name"] == "serve.expert_load_max"
+                   for e in traced["events"])
+
+
 # -- what the tracer gained for this ---------------------------------------
 
 def test_span_end_args_and_public_origin():

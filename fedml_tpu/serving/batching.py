@@ -24,6 +24,12 @@ gather(bank, slot_adapter_ids) @ x`` via grouped (slot-batched) adapter
 einsums — bank capacity is static, membership is data, so serving a new
 or different adapter never recompiles.
 
+The loop runs one tick ahead (docs/SERVING.md, "The loop"): the slot state
+(token, position, key, temperature, adapter row, block table, steps left)
+stays on the device and the tick program carries it from launch to launch,
+so tick k+1 is launched before tick k is read and the host's delivery and
+book-keeping run under the device's work.
+
 Greedy (temp=0) output is bit-identical to the single-request
 :func:`fedml_tpu.serving.templates.openai_compat.generate` path (tested);
 the per-request threefry key splits follow the same sequence as that path,
@@ -33,6 +39,7 @@ so sampling streams match it too.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
 import os
 import queue
@@ -65,6 +72,20 @@ class _UnservableError(Exception):
     """A request whose page reservation can NEVER succeed on this pool
     (need exceeds total non-trash pages) — failed open instead of parked,
     or parking would deadlock the engine."""
+
+
+#: what a staged slot row asks of ``slot_rows``: write the whole row, or
+#: only end the lane (``left`` := 0)
+_ROW_PUT, _ROW_MASK = 1, 2
+
+#: how the TPU's compiler is asked to build the paged tick and chunk
+#: programs.  By default it prefetches every weight matrix and adapter bank
+#: in four slices, each a start and a done operation of its own: 2.3 of the
+#: tick's 3.6 thousand device operations were those halves.  One slice a
+#: prefetch moves the same bytes in 0.55 of the operations
+#: (tests/test_chip_compile.py holds both to no slice; PERF.md section 6,
+#: PR 32, has the device times).
+PAGED_TPU_COMPILER_OPTIONS = {"xla_tpu_sliced_prefetch_max_slices": 1}
 
 
 def _unwrap_params(params):
@@ -102,6 +123,11 @@ def _with_counters(tokens, rows):
 class _Slot:
     __slots__ = ("live", "q", "pos", "remaining", "eos_id", "cur_tok",
                  "adapter_row",
+                 # tick steps still to be dispatched for the request: what
+                 # budget and buffer end leave (eos is learned later).  The
+                 # device counts the same down (``left``); ``pos`` and
+                 # ``remaining`` follow delivery, a dispatch behind
+                 "steps",
                  # paged-KV prefill state machine (free → prefilling →
                  # live): prompt ids + replay cursor for the chunked
                  # prefill lanes, the admission-split sample key, and the
@@ -125,6 +151,7 @@ class _Slot:
         self.eos_id: Optional[int] = None
         self.cur_tok = 0
         self.adapter_row = 0
+        self.steps = 0
         self.prefilling = False
         self.pf_ids: Optional[List[int]] = None
         self.pf_next = 0
@@ -245,6 +272,9 @@ class ContinuousBatchingEngine:
         self.paged = self.kv_page_tokens > 0
         self.paged_model = None
         self.page_pool = None
+        self._chunks_total = 0
+        self._pages_shared = 0
+        self._pages_private = 0
         if self.paged:
             cfg = getattr(model, "cfg", None)
             if cfg is None or not hasattr(cfg, "kv_page_tokens"):
@@ -272,9 +302,6 @@ class ContinuousBatchingEngine:
             self.page_pool = PagedBlockPool(pool_pages)
             self._btabs = np.zeros((self.n_slots, self.max_blocks),
                                    np.int32)
-            self._chunks_total = 0
-            self._pages_shared = 0
-            self._pages_private = 0
 
         self._prefill, self._tail_step, self._tail_block = \
             _build_cached_decode(model, self.top_k, self.top_p)
@@ -299,62 +326,72 @@ class ContinuousBatchingEngine:
         from ..llm.quantization import dequantize_params, weight_dtype
         wdtype = weight_dtype(model)
 
-        @jax.jit
-        def batched_step(params, caches, toks, poss, keys, temps):
+        horizon = self.horizon
+
+        def carried_tick(state, kv, step):
+            """What the four tick programs share: ``horizon`` scanned steps
+            of every lane from the slot state the last program left on the
+            device, and the state the next program takes.  ``step(kv, toks,
+            poss, keys)`` gives ``(next tokens, kv, carry keys, experts'
+            counters or None)``.  A lane is live while it has steps
+            ``left``; only a live lane's token, position and key advance
+            (a prefilling slot's admission key must not move with the
+            splits its lane rides along for), and the count runs down here
+            as it does on the host, so a budget's end uploads nothing."""
+            live = state["left"] > 0
+
+            def body(carry, _):
+                kv, toks, poss, keys = carry
+                nxt, kv, keys, counts = step(kv, toks, poss, keys)
+                return (kv, nxt, poss + 1, keys), (nxt, counts)
+
+            (kv, toks, poss, keys), (hist, counts) = jax.lax.scan(
+                body, (kv, state["toks"], state["poss"], state["keys"]),
+                None, length=horizon)
+            state = dict(
+                state, toks=jnp.where(live, toks, state["toks"]),
+                poss=jnp.where(live, poss, state["poss"]),
+                keys=jnp.where(live[:, None], keys, state["keys"]),
+                left=jnp.maximum(state["left"] - horizon, 0))
+            # hist: (horizon, n_slots) → host iterates per-slot rows
+            return _with_counters(hist.T, counts), kv, state
+
+        def dense_tick(params, lora_slots, caches, state):
             # int8-quantized trees dequantize inside the trace (stays int8
             # in HBM; per-matmul dequant fuses) — no-op for plain trees
             params = dequantize_params(params, wdtype)
 
-            def one(cache, tok, pos, key, temp):
-                logits, mut = model.apply(
-                    {"params": params, "cache": cache}, tok[None, None],
-                    decode=True, start_pos=pos, mutable=["cache"])
-                key, sub = jax.random.split(key)
-                nxt = _sample_live(logits[0, 0], sub, temp, self.top_k,
-                                   self.top_p)
-                return nxt, mut["cache"], key
-
-            def body(carry, _):
-                caches, toks, poss, keys = carry
-                toks, caches, keys = jax.vmap(one)(
-                    caches, toks, poss, keys, temps)
-                return (caches, toks, poss + 1, keys), toks
-
-            (caches, toks, poss, keys), hist = jax.lax.scan(
-                body, (caches, toks, poss, keys), None, length=self.horizon)
-            # hist: (horizon, n_slots) → host iterates per-slot rows
-            return hist.T, caches, keys
-
-        @jax.jit
-        def batched_step_mt(params, bank, caches, toks, poss, keys, temps,
-                            aids):
-            params = dequantize_params(params, wdtype)
-            # gather(bank, slot_adapter_ids) — one batched gather per lora
-            # leaf; the vmapped apply then runs the adapter matmuls
-            # slot-batched against the shared base (grouped einsums after
-            # vmap batching).  bank + aids are traced arguments: any
-            # request→adapter assignment reuses this one program.
-            lora_slots = jax.tree_util.tree_map(lambda b: b[aids], bank)
-
             def one(cache, tok, pos, key, temp, lora):
+                variables = {"params": params, "cache": cache}
+                if lora is not None:
+                    variables["lora"] = lora
                 logits, mut = model.apply(
-                    {"params": params, "lora": lora, "cache": cache},
-                    tok[None, None], decode=True, start_pos=pos,
+                    variables, tok[None, None], decode=True, start_pos=pos,
                     mutable=["cache"])
                 key, sub = jax.random.split(key)
                 nxt = _sample_live(logits[0, 0], sub, temp, self.top_k,
                                    self.top_p)
                 return nxt, mut["cache"], key
 
-            def body(carry, _):
-                caches, toks, poss, keys = carry
-                toks, caches, keys = jax.vmap(one)(
-                    caches, toks, poss, keys, temps, lora_slots)
-                return (caches, toks, poss + 1, keys), toks
+            def step(caches, toks, poss, keys):
+                return jax.vmap(one)(caches, toks, poss, keys,
+                                     state["temps"], lora_slots) + (None,)
 
-            (caches, toks, poss, keys), hist = jax.lax.scan(
-                body, (caches, toks, poss, keys), None, length=self.horizon)
-            return hist.T, caches, keys
+            return carried_tick(state, caches, step)
+
+        @partial(jax.jit, donate_argnums=(2,))
+        def batched_step(params, caches, state):
+            return dense_tick(params, None, caches, state)
+
+        @partial(jax.jit, donate_argnums=(3,))
+        def batched_step_mt(params, bank, caches, state):
+            # gather(bank, slot_adapter_ids) — one batched gather per lora
+            # leaf; the vmapped apply then runs the adapter matmuls
+            # slot-batched against the shared base (grouped einsums after
+            # vmap batching).  bank + aids are traced arguments: any
+            # request→adapter assignment reuses this one program.
+            return dense_tick(params, jax.tree_util.tree_map(
+                lambda b: b[state["aids"]], bank), caches, state)
 
         self._step = batched_step if self.registry is None \
             else batched_step_mt
@@ -362,88 +399,94 @@ class ContinuousBatchingEngine:
         if self.paged:
             from ..llm.moe import COUNTERS
             pm = self.paged_model
+            C = self.prefill_chunk
+            # only the TPU's compiler knows the options
+            options = (PAGED_TPU_COMPILER_OPTIONS
+                       if jax.default_backend() == "tpu" else None)
 
-            @partial(jax.jit, donate_argnums=(1,))
-            def paged_step(params, pool, btabs, toks, poss, keys, temps):
+            def paged_tick(params, lora_slots, pool, state):
                 # ONE batched apply against the shared pool — no vmap:
                 # every slot addresses its own pages via the traced block
                 # tables, per-slot depths ride the (b,) start_pos vector.
                 # The per-slot key splits replay the dense engine's
                 # sequence exactly (split[0]=carry, split[1]=sample).
+                # A lane that is not live (free, prefilling, finished)
+                # sees an all-trash table, so its burn write lands in
+                # garbage and never in a page another slot is reading.
                 params = dequantize_params(params, wdtype)
+                btabs = jnp.where((state["left"] > 0)[:, None],
+                                  state["btabs"], 0)
 
-                def body(carry, _):
-                    pool, toks, poss, keys = carry
+                def step(pool, toks, poss, keys):
+                    variables = {"params": params, "cache": pool}
+                    if lora_slots is not None:
+                        variables["lora"] = lora_slots
                     logits, mut = pm.apply(
-                        {"params": params, "cache": pool}, toks[:, None],
-                        decode=True, start_pos=poss, block_tables=btabs,
+                        variables, toks[:, None], decode=True,
+                        start_pos=poss, block_tables=btabs,
                         mutable=["cache", COUNTERS])
                     split = jax.vmap(jax.random.split)(keys)
-                    keys2, subs = split[:, 0], split[:, 1]
                     nxt = jax.vmap(
                         lambda lg, sub, temp: _sample_live(
                             lg, sub, temp, self.top_k, self.top_p)
-                    )(logits[:, 0], subs, temps)
-                    return (mut["cache"], nxt, poss + 1, keys2), \
-                        (nxt, _moe_counters(mut))
+                    )(logits[:, 0], split[:, 1], state["temps"])
+                    return nxt, mut["cache"], split[:, 0], _moe_counters(mut)
 
-                (pool, toks, poss, keys), (hist, counts) = jax.lax.scan(
-                    body, (pool, toks, poss, keys), None,
-                    length=self.horizon)
-                return _with_counters(hist.T, counts), pool, keys
+                return carried_tick(state, pool, step)
 
-            @partial(jax.jit, donate_argnums=(2,))
-            def paged_step_mt(params, bank, pool, btabs, toks, poss, keys,
-                              temps, aids):
+            @partial(jax.jit, donate_argnums=(1, 2),
+                     compiler_options=options)
+            def paged_step(params, pool, state):
+                return paged_tick(params, None, pool, state)
+
+            @partial(jax.jit, donate_argnums=(2, 3),
+                     compiler_options=options)
+            def paged_step_mt(params, bank, pool, state):
+                return paged_tick(params, jax.tree_util.tree_map(
+                    lambda b: b[state["aids"]], bank), pool, state)
+
+            @partial(jax.jit, donate_argnums=(2, 3),
+                     compiler_options=options)
+            def paged_chunk(params, lora, pool, state, chunk, acc):
+                # one fixed-shape (1, C) prefill chunk for one slot.
+                # ``chunk`` is everything the host knows of it, one
+                # upload: the C token ids, then ``start idx slot pos
+                # left`` and the sample key's words.  The sample index is
+                # TRACED so intermediate chunks (token discarded) and the
+                # final chunk (token at n-1-chunk_start) ride one compiled
+                # program; the slot's block table and temperature are its
+                # row of the carried state.  A final chunk (``left`` >= 0)
+                # hands the slot over on the device: the sampled token,
+                # the position ``pos`` and the steps ``left`` go into its
+                # row, so the slot joins the next tick with no read-back.
+                # ``acc`` is the last chunk's result for the same request
+                # (None for a model without sparse layers): the experts'
+                # counters add up behind the token from chunk to chunk,
+                # and the final chunk's one read-back brings the request's
                 params = dequantize_params(params, wdtype)
-                lora_slots = jax.tree_util.tree_map(
-                    lambda b: b[aids], bank)
-
-                def body(carry, _):
-                    pool, toks, poss, keys = carry
-                    logits, mut = pm.apply(
-                        {"params": params, "lora": lora_slots,
-                         "cache": pool}, toks[:, None],
-                        decode=True, start_pos=poss, block_tables=btabs,
-                        mutable=["cache", COUNTERS])
-                    split = jax.vmap(jax.random.split)(keys)
-                    keys2, subs = split[:, 0], split[:, 1]
-                    nxt = jax.vmap(
-                        lambda lg, sub, temp: _sample_live(
-                            lg, sub, temp, self.top_k, self.top_p)
-                    )(logits[:, 0], subs, temps)
-                    return (mut["cache"], nxt, poss + 1, keys2), \
-                        (nxt, _moe_counters(mut))
-
-                (pool, toks, poss, keys), (hist, counts) = jax.lax.scan(
-                    body, (pool, toks, poss, keys), None,
-                    length=self.horizon)
-                return _with_counters(hist.T, counts), pool, keys
-
-            @partial(jax.jit, donate_argnums=(2,))
-            def paged_chunk(params, lora, pool, chunk, btab, start, idx,
-                            key, temp, acc):
-                # one fixed-shape (1, C) prefill chunk for one slot; the
-                # sample index is TRACED so intermediate chunks (token
-                # discarded) and the final chunk (token at n-1-chunk_start)
-                # ride one compiled program.  ``acc`` is the last chunk's
-                # result for the same request (None for a model without
-                # sparse layers): the experts' counters add up behind the
-                # token from chunk to chunk, and the final chunk's one
-                # read-back brings the request's
-                params = dequantize_params(params, wdtype)
+                start, idx, slot, pos, left = (chunk[C + j] for j in range(5))
+                key = jax.lax.bitcast_convert_type(chunk[C + 5:], jnp.uint32)
                 variables = {"params": params, "cache": pool}
                 if lora is not None:
                     variables["lora"] = lora
                 logits, mut = pm.apply(
-                    variables, chunk, decode=True, start_pos=start,
-                    block_tables=btab, mutable=["cache", COUNTERS])
-                tok = _sample_live(logits[0, idx], key, temp, self.top_k,
-                                   self.top_p)
+                    variables, chunk[None, :C], decode=True,
+                    start_pos=start[None],
+                    block_tables=state["btabs"][slot][None],
+                    mutable=["cache", COUNTERS])
+                tok = _sample_live(logits[0, idx], key, state["temps"][slot],
+                                   self.top_k, self.top_p)
+                def handed(vec, new):
+                    return vec.at[slot].set(
+                        jnp.where(left >= 0, new, vec[slot]))
+
+                state = dict(state, toks=handed(state["toks"], tok),
+                             poss=handed(state["poss"], pos),
+                             left=handed(state["left"], left))
                 counts = _moe_counters(mut)
                 if counts is not None:      # added to the request's so far
                     counts = jnp.stack([acc[1:], counts])
-                return _with_counters(tok, counts), mut["cache"]
+                return _with_counters(tok, counts), mut["cache"], state
 
             self._step = paged_step if self.registry is None \
                 else paged_step_mt
@@ -455,6 +498,57 @@ class ContinuousBatchingEngine:
                 lambda all_c, c: all_c.at[slot].set(c), caches, cache)
 
         self._insert = insert_cache
+
+        # the slot state the tick program carries from launch to launch
+        # (docs/SERVING.md, "The loop"): one row a slot, on the device.
+        # The host writes a row only where an admission or a late finish
+        # changed it, all of an iteration's changes in one staged array
+        # ``[block table | tok pos left temp aid | key words | op]``.
+        key_words = int(np.asarray(jax.random.PRNGKey(0)).size)
+        blocks = self.max_blocks if self.paged else 0
+        self._row_blocks = blocks
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def slot_rows(state, rows):
+            op = rows[:, -1]
+            put = op == _ROW_PUT
+            cols = rows[:, blocks:]
+            new = {"toks": cols[:, 0], "poss": cols[:, 1],
+                   "left": cols[:, 2],
+                   "temps": jax.lax.bitcast_convert_type(
+                       cols[:, 3], jnp.float32),
+                   "aids": cols[:, 4],
+                   "keys": jax.lax.bitcast_convert_type(
+                       cols[:, 5:5 + key_words], jnp.uint32),
+                   "btabs": rows[:, :blocks]}
+            out = {name: jnp.where(put.reshape((-1,) + (1,) * (old.ndim - 1)),
+                                   new[name], old)
+                   for name, old in state.items()}
+            out["left"] = jnp.where(op == _ROW_MASK, 0, out["left"])
+            return out
+
+        self._slot_rows = slot_rows
+        n = self.n_slots
+        self._dev = {"toks": jnp.zeros(n, jnp.int32),
+                     "poss": jnp.zeros(n, jnp.int32),
+                     "left": jnp.zeros(n, jnp.int32),
+                     "temps": jnp.zeros(n, jnp.float32),
+                     "keys": jnp.zeros((n, key_words), jnp.uint32)}
+        if self.registry is not None:
+            self._dev["aids"] = jnp.zeros(n, jnp.int32)
+        if self.paged:
+            self._dev["btabs"] = jnp.zeros((n, blocks), jnp.int32)
+        self._rows = np.zeros((n, blocks + 6 + key_words), np.int32)
+        self._rows_staged = False
+        # the dispatch whose results the host has not read yet, as
+        # ``[tokens, [(slot, queue)] of its lanes, first tokens]``, and the
+        # first tokens of this pass's final chunks ``(slot, queue, token)``:
+        # they ride the next dispatch's record
+        self._unread: Optional[list] = None
+        self._firsts: List[tuple] = []
+        self._ticks_ahead = 0
+        self._lanes_burned = 0
+        self._flushes = 0
 
         dummy_lora = (self.registry.lora_for_row(0)
                       if self.registry is not None else None)
@@ -488,6 +582,8 @@ class ContinuousBatchingEngine:
                 cfg.sparse_layer(i) for i in range(cfg.n_layers))
             self._chunk_acc0 = jnp.zeros((4,), jnp.int32) \
                 if self._moe_layers else None
+            self._chunk_words = self.prefill_chunk + 5 + key_words
+            self._host_device = jax.devices("cpu")[0]
         else:
             # materialize the stacked cache template from one dummy
             # prefill (MT engines pass the zero bank row — a lora_rank>0
@@ -501,12 +597,6 @@ class ContinuousBatchingEngine:
                 cache0)
 
         self._slots = [_Slot() for _ in range(self.n_slots)]
-        self._toks = np.zeros(self.n_slots, np.int32)
-        self._poss = np.zeros(self.n_slots, np.int32)
-        self._temps = np.zeros(self.n_slots, np.float32)
-        self._aids = np.zeros(self.n_slots, np.int32)
-        self._keys = np.stack(
-            [np.asarray(jax.random.PRNGKey(i)) for i in range(self.n_slots)])
         self._waiting: "queue.Queue[dict]" = queue.Queue()
         # requests pulled off _waiting but not admittable yet (adapter
         # page-in in flight, page pool dry) — engine-thread-confined,
@@ -700,46 +790,30 @@ class ContinuousBatchingEngine:
         AOT-lower them without serving a request.  ``decode_step`` is the
         per-tick batched decode ``_dispatch`` launches; ``insert_cache``
         is admission's donated slot write."""
-        toks = jnp.asarray(self._toks)
-        poss = jnp.asarray(self._poss)
-        keys = jnp.asarray(self._keys)
-        temps = jnp.asarray(self._temps)
+        bank = () if self.registry is None else (self.registry.bank,)
+        state_arg = 2 + len(bank)
         if self.paged:
             # paged memory plane: the decode step donates the page pool
-            # (argnum after params[/bank]) and the chunk program is the
-            # third compiled citizen — both pinned so a page-geometry
-            # change shows up as a contract diff, not a silent regression
-            btabs = jnp.asarray(self._btabs)
-            if self.registry is not None:
-                step_args = (self.raw_params, self.registry.bank,
-                             self._pool, btabs, toks, poss, keys, temps,
-                             jnp.asarray(self._aids))
-                step_donate = (2,)
-            else:
-                step_args = (self.raw_params, self._pool, btabs, toks,
-                             poss, keys, temps)
-                step_donate = (1,)
+            # and the carried slot state (the two arguments after
+            # params[/bank]) and the chunk program is the third compiled
+            # citizen — both pinned so a page-geometry change shows up as
+            # a contract diff, not a silent regression
             lora = (self.registry.lora_for_row(0)
                     if self.registry is not None else None)
-            chunk_args = (self.raw_params, lora, self._pool,
-                          jnp.zeros((1, self.prefill_chunk), jnp.int32),
-                          jnp.zeros((1, self.max_blocks), jnp.int32),
-                          jnp.zeros((1,), jnp.int32), jnp.int32(0),
-                          jax.random.PRNGKey(0), jnp.float32(0.0),
-                          self._chunk_acc0)
+            chunk = jnp.zeros((self._chunk_words,), jnp.int32)
             return [
-                ("decode_step", self._step, step_args, step_donate),
-                ("prefill_chunk", self._chunk, chunk_args, (2,)),
+                ("decode_step", self._step,
+                 (self.raw_params, *bank, self._pool, self._dev),
+                 (state_arg - 1, state_arg)),
+                ("prefill_chunk", self._chunk,
+                 (self.raw_params, lora, self._pool, self._dev, chunk,
+                  self._chunk_acc0), (2, 3)),
             ]
-        if self.registry is not None:
-            step_args = (self.raw_params, self.registry.bank, self._caches,
-                         toks, poss, keys, temps, jnp.asarray(self._aids))
-        else:
-            step_args = (self.raw_params, self._caches, toks, poss, keys,
-                         temps)
         cache0 = jax.tree_util.tree_map(lambda c: c[0], self._caches)
         return [
-            ("decode_step", self._step, step_args, ()),
+            ("decode_step", self._step,
+             (self.raw_params, *bank, self._caches, self._dev),
+             (state_arg,)),
             ("insert_cache", self._insert,
              (self._caches, cache0, jnp.int32(0)), (0,)),
         ]
@@ -751,10 +825,48 @@ class ContinuousBatchingEngine:
                 return i
         return None
 
+    # the device-carried slot state and the rows staged for it are
+    # engine-thread-confined like the rest of the decode state (see _admit)
+    def _stage_row(self, slot: int, key, temp: float, aid: int,
+                   tok: int = 0, pos: int = 0, left: int = 0) -> None:
+        """Stage ``slot``'s whole row for the next ``_sync_rows``: what an
+        admission knows (a paged one leaves token, position and steps to
+        its final chunk, which writes them on the device)."""
+        row = self._rows[slot]
+        if self.paged:
+            row[:self._row_blocks] = self._btabs[slot]
+        row[self._row_blocks:-1] = (
+            tok, pos, left, np.float32(temp).view(np.int32), aid,
+            *np.asarray(key).view(np.int32))
+        row[-1] = _ROW_PUT
+        self._rows_staged = True  # fedrace: disable=unguarded-shared-write
+
+    def _mask_lane(self, slot: int) -> None:
+        """End ``slot``'s lane on the device where the device cannot know
+        (eos, an abort): a lane that ran out of budget or buffer has
+        counted itself down."""
+        self._rows[slot, -1] = _ROW_MASK
+        self._rows_staged = True  # fedrace: disable=unguarded-shared-write
+
+    def _sync_rows(self) -> None:
+        """Write the staged rows into the carried state: one upload and one
+        small program, and nothing at all in a pass that staged none."""
+        if not self._rows_staged:
+            return
+        # the array goes to the runtime for good (a CPU client may alias
+        # it); staging goes on in a new one
+        rows, self._rows = self._rows, np.zeros_like(self._rows)  # fedrace: disable=unguarded-shared-write
+        self._rows_staged = False  # fedrace: disable=unguarded-shared-write
+        # fedrace: disable-next-line=unguarded-shared-write
+        self._dev = self._slot_rows(self._dev, jax.device_put(rows))
+
     def _finish(self, i: int, aborted: bool = False):
         s = self._slots[i]
         if not aborted and s.t_admit is not None:
             self._observe_finish(i, s)
+        if s.steps:
+            s.steps = 0
+            self._mask_lane(i)
         s.t_admit = None
         s.live = False
         s.prefilling = False
@@ -905,10 +1017,10 @@ class ContinuousBatchingEngine:
             # is set once in the ctor and never rebound
             # fedrace: disable-next-line=unguarded-shared-write
             self.prefix_cache.insert(ids, cache, self.raw_params, atok)
-        # decode-state arrays (_caches/_aids/_temps/_keys, and _toks/_poss
-        # in _dispatch) are engine-thread-confined: written only between
-        # dispatches on the engine thread, never touched by submit()/HTTP
-        # threads, so they need no lock despite living next to shared state
+        # the decode state (_caches, the carried slot state _dev and the
+        # rows staged for it) is engine-thread-confined: written only on
+        # the engine thread, never touched by submit()/HTTP threads, so it
+        # needs no lock despite living next to shared state
         # fedrace: disable-next-line=unguarded-shared-write
         self._caches = self._insert(self._caches, cache, jnp.int32(slot))
         s = self._slots[slot]
@@ -931,11 +1043,12 @@ class ContinuousBatchingEngine:
         s.request = req.get("request")
         s.drafts_proposed = 0
         s.drafts_accepted = 0
-        self._aids[slot] = row  # fedrace: disable=unguarded-shared-write
-        self._temps[slot] = req["temperature"]  # fedrace: disable=unguarded-shared-write
-        self._keys[slot] = np.asarray(key)  # fedrace: disable=unguarded-shared-write
         if not self._emit(slot, tok_host):
             self._finish(slot)
+            return
+        s.steps = min(s.remaining, self.buf_len - n)
+        self._stage_row(slot, key, req["temperature"], row, tok=tok_host,
+                        pos=n, left=s.steps)
 
     # -- paged admission ---------------------------------------------------
     def _reserve_pages(self, req: dict, slot: int) -> None:
@@ -986,10 +1099,13 @@ class ContinuousBatchingEngine:
         ids = req["prompt_ids"]
         n = len(ids)
         full, need_blocks = req.pop("_kv")
-        key = jax.random.PRNGKey(req["seed"])
         # same split sequence as the dense prefill path: sub samples the
-        # first token (on the final chunk), key carries into decode
-        key, sub = jax.random.split(key)
+        # first token (on the final chunk), key carries into decode.  On
+        # the host's own device: the same integers, and no wait behind the
+        # tick the accelerator is running
+        with jax.default_device(self._host_device):
+            key, sub = jax.random.split(jax.random.PRNGKey(req["seed"]))
+            key, sub = np.asarray(key), np.asarray(sub)
         s = self._slots[slot]
         s.prefilling = True
         s.live = False
@@ -999,6 +1115,7 @@ class ContinuousBatchingEngine:
         s.eos_id = req["eos_id"]
         s.cur_tok = 0
         s.adapter_row = req.get("adapter_row", 0)
+        s.steps = 0
         s.pf_ids = ids
         s.pf_n = n
         s.pf_next = full * self.kv_page_tokens
@@ -1017,9 +1134,7 @@ class ContinuousBatchingEngine:
         s.request = req.get("request")
         s.drafts_proposed = 0
         s.drafts_accepted = 0
-        self._aids[slot] = s.adapter_row  # fedrace: disable=unguarded-shared-write
-        self._temps[slot] = req["temperature"]  # fedrace: disable=unguarded-shared-write
-        self._keys[slot] = np.asarray(key)  # fedrace: disable=unguarded-shared-write
+        self._stage_row(slot, key, req["temperature"], s.adapter_row)
 
     def _prefill_tick(self) -> None:
         """Run up to ``prefill_lanes`` fixed-shape prefill chunks, one per
@@ -1039,15 +1154,16 @@ class ContinuousBatchingEngine:
             final = cs + C >= n
             with tracer.span("serve.chunk", cat="engine", slot=i,
                              request=s.request, start=cs,
-                             tokens=min(C, n - cs), final=int(final)) as span:
-                self._prefill_chunk(tracer, span, i, s, cs, final)
+                             tokens=min(C, n - cs), final=int(final)):
+                self._prefill_chunk(tracer, i, s, cs, final)
 
-    def _prefill_chunk(self, tracer, span, i: int, s: "_Slot", cs: int,
+    def _prefill_chunk(self, tracer, i: int, s: "_Slot", cs: int,
                        final: bool) -> None:
-        """One chunk of slot ``i``'s prompt from position ``cs``; the
-        final one flips the slot live and emits its first token (and reads,
-        behind it, what the experts computed over the request's chunks:
-        ``span`` gets it as ``expert_pairs``)."""
+        """One chunk of slot ``i``'s prompt from position ``cs``.  The
+        final one flips the slot live: its sampled token goes into the
+        slot's row on the device, so the slot joins the tick of this same
+        pass, and the host reads the token (and, behind it, what the
+        experts computed over the request's chunks) with that tick's."""
         C = self.prefill_chunk
         n = s.pf_n
         with tracer.span("serve.chunk.gather", cat="engine",
@@ -1055,20 +1171,26 @@ class ContinuousBatchingEngine:
             lora = (self.registry.lora_for_row(s.adapter_row)
                     if self.registry is not None else None)
         with tracer.span("serve.chunk.dispatch", cat="engine"):
-            chunk = np.zeros((1, C), np.int32)
+            self._sync_rows()       # the chunk reads its slot's row
+            if final:
+                # what _emit will say of the first token, eos apart: the
+                # steps that budget and buffer end leave the ticks
+                s.steps = min(s.remaining - 1, self.buf_len - n) \
+                    if s.remaining > 0 else 0
+            chunk = np.zeros(self._chunk_words, np.int32)
             seg = s.pf_ids[cs:cs + C]
-            chunk[0, :len(seg)] = seg
+            chunk[:len(seg)] = seg
             # sample index is traced: intermediate chunks discard token 0,
             # the final chunk samples at the prompt's last position
-            idx = max(n - 1 - cs, 0) if final else 0
+            chunk[C:C + 5] = (cs, max(n - 1 - cs, 0) if final else 0, i, n,
+                              s.steps if final else -1)
+            chunk[C + 5:] = s.pf_sub.view(np.int32)
             # the page pool is engine-thread-confined like the other
             # decode state (see _admit); step_programs reads it at rest
             # fedrace: disable-next-line=unguarded-shared-write
-            tok, self._pool = self._chunk(
-                self.raw_params, lora, self._pool, jnp.asarray(chunk),
-                jnp.asarray(self._btabs[i][None]),
-                jnp.asarray([cs], jnp.int32), jnp.int32(idx), s.pf_sub,
-                jnp.float32(self._temps[i]), s.pf_acc)
+            tok, self._pool, self._dev = self._chunk(
+                self.raw_params, lora, self._pool, self._dev,
+                jax.device_put(chunk), s.pf_acc)
         with self._stats_lock:
             self._chunks_total += 1
         if not final:
@@ -1076,14 +1198,8 @@ class ContinuousBatchingEngine:
             if s.pf_acc is not None:
                 s.pf_acc = tok
             return
-        with tracer.span("serve.chunk.readback", cat="engine"):
-            out = np.asarray(tok).reshape(-1)
-        tok_host = int(out[0])
+        self._firsts.append((i, s.q, tok))
         s.pf_acc = None
-        if out.size > 1:
-            with self._stats_lock:
-                self._expert_pairs += int(out[1])
-            span.set(expert_pairs=int(out[1]))
         s.prefilling = False
         s.live = True
         s.pos = n
@@ -1097,8 +1213,6 @@ class ContinuousBatchingEngine:
                     self.raw_params, s.pf_atok)
         s.pf_ids = None
         s.pf_sub = None
-        if not self._emit(i, tok_host):
-            self._finish(i)
 
     def _admit_one(self, req: dict, slot: int, tracer) -> bool:
         """Admission front door for both engines: cache-mode adapter pin
@@ -1157,7 +1271,13 @@ class ContinuousBatchingEngine:
         """Host-side memory-plane stats (bench + tests): pool occupancy,
         chunk counts, prefix page-sharing, adapter cache counters."""
         with self._stats_lock:
-            out: Dict[str, Any] = {"ticks": self._ticks}
+            # ticks launched while the one before was still unread, lanes
+            # that ran for a slot eos had already ended, and times the
+            # loop read back with nothing to launch (drain, stop, swap)
+            out: Dict[str, Any] = {"ticks": self._ticks,
+                                   "ticks_ahead": self._ticks_ahead,
+                                   "lanes_burned": self._lanes_burned,
+                                   "flushes": self._flushes}
             chunks = self._chunks_total
             shared, private = self._pages_shared, self._pages_private
             pairs, hit = self._expert_pairs, self._experts_hit
@@ -1203,15 +1323,25 @@ class ContinuousBatchingEngine:
                 "prefilling": sum(s.prefilling for s in self._slots),
                 "queued": self._waiting.qsize() + len(self._parked)}
 
+    def _busy(self) -> bool:
+        """A request holds a slot, or a dispatch is still unread (its
+        lanes may all belong to requests eos has ended since)."""
+        return (self._unread is not None or bool(self._firsts)
+                or any(s.live or s.prefilling for s in self._slots))
+
     def _run(self):
         try:
             self._run_loop()
         except Exception:  # noqa: BLE001 — a dead engine must not hang HTTP
-            import logging
             logging.getLogger(__name__).exception(
                 "continuous-batching engine crashed; failing open")
             with self._cond:  # excludes concurrent submit() puts
                 self._stopped = True
+                try:        # what the device had produced still goes out
+                    self._flush()
+                except Exception:  # noqa: BLE001 — the device may be the cause
+                    logging.getLogger(__name__).exception(
+                        "the outstanding read-back was lost")
                 for i, s in enumerate(self._slots):
                     if s.live:
                         self._finish(i, aborted=True)
@@ -1223,12 +1353,12 @@ class ContinuousBatchingEngine:
             with self._cond:
                 while (not self._stopped and self._waiting.empty()
                        and self._pending_params is None
-                       and not any(s.live or s.prefilling
-                                   for s in self._slots)
+                       and not self._busy()
                        and not self._parked_actionable()):
                     with get_tracer().span("serve.wait", cat="engine"):
                         self._cond.wait(timeout=0.5)
                 if self._stopped:
+                    self._flush()   # every token already produced goes out
                     for i, s in enumerate(self._slots):
                         if s.live or s.prefilling:
                             self._finish(i, aborted=True)
@@ -1237,12 +1367,13 @@ class ContinuousBatchingEngine:
                     return
                 # apply a staged weight swap once in-flight slots drain
                 # (prefilling counts — its KV is half-written under the
-                # old weights); the prefix cache clears atomically with it
+                # old weights — and so does an unread dispatch: the pass
+                # that finds nothing to launch has flushed it by then);
+                # the prefix cache clears atomically with it
                 # (its old entries are keyed by the old params identity
                 # anyway — clearing frees the old tree + stale KV eagerly)
                 swap_pending = self._pending_params is not None
-                if swap_pending and not any(s.live or s.prefilling
-                                            for s in self._slots):
+                if swap_pending and not self._busy():
                     # raw_params is swapped only here on the engine thread
                     # (update_params merely STAGES via _pending_params under
                     # _cond); all other raw_params uses are engine-thread
@@ -1272,71 +1403,86 @@ class ContinuousBatchingEngine:
             self._iters += 1
             with tracer.span("serve.iter", cat="engine",
                              **self._iter_args(tracer)):
-                if retry_parked:
-                    retry, self._parked = self._parked, []
-                    for j, req in enumerate(retry):
-                        slot = self._free_slot()
-                        if slot is None:
-                            self._parked.extend(retry[j:])
-                            break
-                        self._admit_one(req, slot, tracer)
-                while not swap_pending and not self._waiting.empty():
-                    slot = self._free_slot()
-                    if slot is None:
-                        break
-                    req = self._waiting.get()
-                    self._admit_one(req, slot, tracer)
-                if tracer.enabled:
-                    tracer.counter("serve.queue_depth",
-                                   self._waiting.qsize() + len(self._parked))
+                worked = self._iterate(tracer, swap_pending, retry_parked)
+            if worked and tracer.enabled:
+                self._gauges(tracer)
 
-                if self.paged:
-                    self._prefill_tick()
-                live = [i for i, s in enumerate(self._slots) if s.live]
-                if live:
-                    self._dispatch(live)
-                    with self._stats_lock:
-                        self._ticks += 1
-                elif not any(s.prefilling for s in self._slots):
-                    continue
-            if tracer.enabled:
-                now = time.monotonic()
-                rolled = None
-                with self._stats_lock:
-                    t0, ntok = self._tok_window
-                    if now - t0 >= 0.5:
-                        rolled = (ntok, self.serve_stats["tokens"])
-                        self._tok_window = [now, 0]
-                if rolled is not None:   # counter emits outside _stats_lock
-                    tracer.counter("serve.tokens_per_s",
-                                   rolled[0] / (now - t0))
-                    tracer.counter("serve.tokens_total", rolled[1])
-                if self.paged:
-                    with self._stats_lock:
-                        shared = self._pages_shared
-                        tot = shared + self._pages_private
-                        chunks = self._chunks_total
-                    tracer.counter("serve.kv_pages_free",
-                                   self.page_pool.pages_free)
-                    tracer.counter("serve.kv_page_hit_rate",
-                                   shared / tot if tot else 0.0)
-                    tracer.counter("serve.prefill_chunks", chunks)
-                    tracer.counter("serve.kv_bytes_per_token",
-                                   self._kv_bytes_per_token)
-                    if self._moe_layers:
-                        tracer.counter("serve.expert_load_max",
-                                       self._expert_load_max)
-                if self._store_mode:
-                    st = self.registry.stats
-                    tracer.counter("serve.adapter_cache_hits",
-                                   st["cache_hits"])
-                    tracer.counter("serve.adapter_cache_misses",
-                                   st["cache_misses"])
-                    tracer.counter("serve.adapter_cache_evictions",
-                                   st["cache_evictions"])
-                    tot = st["cache_hits"] + st["cache_misses"]
-                    tracer.counter("serve.adapter_miss_rate",
-                                   st["cache_misses"] / tot if tot else 0.0)
+    def _iterate(self, tracer, swap_pending: bool,
+                 retry_parked: bool) -> bool:
+        """One pass of the loop outside ``_cond``: admissions, prefill
+        chunks, then a tick or the flush of the outstanding one.  False
+        when there was nothing to launch, read or prefill."""
+        if retry_parked:
+            retry, self._parked = self._parked, []
+            for j, req in enumerate(retry):
+                slot = self._free_slot()
+                if slot is None:
+                    self._parked.extend(retry[j:])
+                    break
+                self._admit_one(req, slot, tracer)
+        while not swap_pending and not self._waiting.empty():
+            slot = self._free_slot()
+            if slot is None:
+                break
+            req = self._waiting.get()
+            self._admit_one(req, slot, tracer)
+        if tracer.enabled:
+            tracer.counter("serve.queue_depth",
+                           self._waiting.qsize() + len(self._parked))
+
+        if self.paged:
+            self._prefill_tick()
+        # who is in the next tick is decided without the last one's
+        # tokens: a slot whose budget or buffer ends with a tick already
+        # launched has no steps left and waits, live, for the read-back
+        # that finishes it
+        live = [i for i, s in enumerate(self._slots)
+                if s.live and s.steps > 0]
+        if live:
+            self._dispatch(live)
+            with self._stats_lock:
+                self._ticks += 1
+        elif self._unread is not None or self._firsts:
+            self._flush()
+        else:
+            return any(s.prefilling for s in self._slots)
+        return True
+
+    def _gauges(self, tracer) -> None:
+        """The serving gauges, once a pass, when tracing."""
+        now = time.monotonic()
+        rolled = None
+        with self._stats_lock:
+            t0, ntok = self._tok_window
+            if now - t0 >= 0.5:
+                rolled = (ntok, self.serve_stats["tokens"])
+                self._tok_window = [now, 0]
+        if rolled is not None:   # counter emits outside _stats_lock
+            tracer.counter("serve.tokens_per_s", rolled[0] / (now - t0))
+            tracer.counter("serve.tokens_total", rolled[1])
+        if self.paged:
+            with self._stats_lock:
+                shared = self._pages_shared
+                tot = shared + self._pages_private
+                chunks = self._chunks_total
+            tracer.counter("serve.kv_pages_free", self.page_pool.pages_free)
+            tracer.counter("serve.kv_page_hit_rate",
+                           shared / tot if tot else 0.0)
+            tracer.counter("serve.prefill_chunks", chunks)
+            tracer.counter("serve.kv_bytes_per_token",
+                           self._kv_bytes_per_token)
+            if self._moe_layers:
+                tracer.counter("serve.expert_load_max",
+                               self._expert_load_max)
+        if self._store_mode:
+            st = self.registry.stats
+            tracer.counter("serve.adapter_cache_hits", st["cache_hits"])
+            tracer.counter("serve.adapter_cache_misses", st["cache_misses"])
+            tracer.counter("serve.adapter_cache_evictions",
+                           st["cache_evictions"])
+            tot = st["cache_hits"] + st["cache_misses"]
+            tracer.counter("serve.adapter_miss_rate",
+                           st["cache_misses"] / tot if tot else 0.0)
 
     def _tick_span(self, tracer, live, tracing: bool):
         """The ``serve.tick`` span both engines' ``_dispatch`` open;
@@ -1353,39 +1499,19 @@ class ContinuousBatchingEngine:
         return sum(self._slots[i].out_tokens for i in live)
 
     def _dispatch(self, live):
-        """One device tick for the live slots (overridden by the
-        speculative engine): horizon-scanned batched decode + emission."""
+        """One device tick for the slots with a lane in it (overridden by
+        the speculative engine), launched before the tick before it is
+        read: ``stage`` and ``dispatch`` put tick k on the device's queue,
+        then ``readback``, ``emit`` and ``free`` serve dispatch k-1 (its
+        tokens and the first tokens of its pass's final chunks) while the
+        device runs k.  The slot state stays on the device from program to
+        program; a pass that changed no slot uploads nothing."""
         tracer = get_tracer()
         tracing = tracer.enabled
+        ahead = int(self._unread is not None)
         with self._tick_span(tracer, live, tracing) as tick:
-            before = self._delivered(live) if tracing else 0
             with tracer.span("serve.tick.stage", cat="engine"):
-                for i in live:
-                    # engine-thread-confined decode state (see _admit)
-                    self._toks[i] = self._slots[i].cur_tok  # fedrace: disable=unguarded-shared-write
-                    self._poss[i] = self._slots[i].pos  # fedrace: disable=unguarded-shared-write
-                if self.paged:
-                    # block tables ride as TRACED data — page moves,
-                    # admissions and evictions between ticks never
-                    # recompile.  Non-live slots must see all-trash tables
-                    # so their burn writes land in garbage: freed rows are
-                    # already zeroed, but PREFILLING slots have real
-                    # (possibly shared-prefix) pages wired — mask their
-                    # rows here or the burn write at their stale position
-                    # would scribble into a page another slot is reading
-                    bt = self._btabs
-                    prefilling = [i for i, s in enumerate(self._slots)
-                                  if s.prefilling]
-                    if prefilling:
-                        bt = bt.copy()
-                        bt[prefilling] = 0
-                    state = (jnp.asarray(bt),)
-                else:
-                    state = ()
-                state += (jnp.asarray(self._toks), jnp.asarray(self._poss),
-                          jnp.asarray(self._keys), jnp.asarray(self._temps))
-                if self.registry is not None:
-                    state += (jnp.asarray(self._aids),)
+                self._sync_rows()
             with tracer.span("serve.tick.dispatch", cat="engine"):
                 kv = self._pool if self.paged else self._caches
                 if self.registry is not None:
@@ -1395,57 +1521,120 @@ class ContinuousBatchingEngine:
                     # launch (the dispatch itself is async and fast;
                     # registration is the rare path)
                     with self.registry.lock:
-                        toks, kv, keys = self._step(
-                            self.raw_params, self.registry.bank, kv, *state)
+                        toks, kv, self._dev = self._step(
+                            self.raw_params, self.registry.bank, kv,
+                            self._dev)
                 else:
-                    toks, kv, keys = self._step(self.raw_params, kv, *state)
+                    toks, kv, self._dev = self._step(
+                        self.raw_params, kv, self._dev)
                 if self.paged:
                     self._pool = kv
                 else:
                     self._caches = kv
-            with tracer.span("serve.tick.readback", cat="engine"):
-                # (n_slots, horizon) tokens; behind them the experts'
-                # counters where the model has sparse layers
-                flat = np.asarray(toks).reshape(-1)
-                n_tok = self.n_slots * self.horizon
-                toks_host = flat[:n_tok].reshape(self.n_slots, self.horizon)
-                counters = flat[n_tok:]
-                # copy carry keys back for LIVE slots only: a prefilling
-                # slot's admission key must not advance with the burn
-                # splits its lane rode along for (its first real sample
-                # comes later)
-                keys_host = np.asarray(keys)
-            if counters.size:
-                # counted before a token goes out, so that a caller who has
-                # its last token finds the tick that made it in kv_stats()
-                pairs, hit, most = (int(c) for c in counters)
-                with self._stats_lock:
-                    self._expert_pairs += pairs
-                    self._experts_hit += hit
-                    self._moe_layers_ticked += self._moe_layers * self.horizon
-                self._expert_load_max = most  # fedrace: disable=unguarded-shared-write
-                if tracing:
-                    tick.set(expert_pairs=pairs, experts_hit=hit,
-                             expert_load_max=most)
-            with tracer.span("serve.tick.emit", cat="engine") as emit:
-                finished = 0
+                lanes = []
                 for i in live:
-                    self._keys[i] = keys_host[i]  # fedrace: disable=unguarded-shared-write
-                for i in live:
-                    for j in range(self.horizon):
-                        self._slots[i].pos += 1
-                        if not self._emit(i, int(toks_host[i, j])):
-                            self._finish(i)
-                            finished += 1
-                            break
-                emit.set(finished=finished)
-            with tracer.span("serve.tick.free", cat="engine"):
-                # the tick's device arrays go here, by name, and not at
-                # the return: the first one freed after its program has
-                # run blocks for milliseconds on the TPU runtime
-                del state, toks, keys
-            if tracing:
-                tick.set(tokens=self._delivered(live) - before)
+                    s = self._slots[i]
+                    s.steps = max(s.steps - self.horizon, 0)
+                    lanes.append((i, s.q))
+                rec, self._unread = self._unread, [toks, lanes, self._firsts]
+                self._firsts = []
+                del toks
+            with self._stats_lock:
+                self._ticks_ahead += ahead
+            tick.set(ahead=ahead)
+            self._collect(tracer, tick, rec)
+
+    def _flush(self) -> None:
+        """Read back what is outstanding when there is nothing to launch
+        over it: the last dispatch of a drain (before the loop waits, a
+        staged swap is applied, or the engine stops) and the first tokens
+        of final chunks that no tick followed."""
+        rec, self._unread = self._unread, None
+        if self._firsts:
+            rec = rec or [None, [], []]
+            rec[2] = rec[2] + self._firsts
+            self._firsts = []
+        if rec is None:
+            return
+        with self._stats_lock:    # before a caller has its last token
+            self._flushes += 1
+        tracer = get_tracer()
+        with tracer.span("serve.flush", cat="engine") as span:
+            self._collect(tracer, span, rec)
+
+    def _collect(self, tracer, span, rec: Optional[list]) -> None:
+        """The ``readback``, ``emit`` and ``free`` phases over one
+        dispatch's record (None: nothing was outstanding), under the tick
+        or the flush that is open as ``span``.  Delivery is ``_emit``'s
+        for every token; a lane whose request eos ended after the launch
+        ran for nothing and is counted as burned."""
+        toks, lanes, firsts = rec or (None, (), ())
+        with tracer.span("serve.tick.readback", cat="engine"):
+            # one transfer: (n_slots, horizon) tokens with, behind them,
+            # the experts' counters where the model has sparse layers; and
+            # the final chunks' first tokens, which ran before the tick
+            got, first_got = jax.device_get(
+                (toks, [t for _, _, t in firsts])) if rec else (None, [])
+        flat = got.reshape(-1) if got is not None else ()
+        first_got = [out.reshape(-1) for out in first_got]
+        counters = flat[self.n_slots * self.horizon:]
+        # a request's first token brings what the experts computed over
+        # its prompt's chunks
+        prefill_pairs = sum(int(out[1]) for out in first_got if out.size > 1)
+        pairs = hit = ticked = 0
+        if len(counters):
+            pairs, hit, most = (int(c) for c in counters)
+            ticked = self._moe_layers * self.horizon
+            self._expert_load_max = most  # fedrace: disable=unguarded-shared-write
+        if ticked or prefill_pairs:
+            # counted before a token goes out, so that a caller who has
+            # its last token finds the tick that made it in kv_stats()
+            with self._stats_lock:
+                self._expert_pairs += pairs + prefill_pairs
+                self._experts_hit += hit
+                self._moe_layers_ticked += ticked
+        with tracer.span("serve.tick.emit", cat="engine") as emit:
+            finished = tokens = burned = 0
+            for (i, q, _), out in zip(firsts, first_got):
+                if self._slots[i].q is q and not self._emit(i, int(out[0])):
+                    self._finish(i)
+                    finished += 1
+            for i, q in lanes:
+                s = self._slots[i]
+                if s.q is not q:
+                    burned += 1
+                    continue
+                had = s.out_tokens
+                for tok in flat[i * self.horizon:(i + 1) * self.horizon]:
+                    s.pos += 1
+                    if not self._emit(i, int(tok)):
+                        self._finish(i)
+                        finished += 1
+                        break
+                tokens += s.out_tokens - had
+            emit.set(finished=finished)
+        if burned:
+            with self._stats_lock:
+                self._lanes_burned += burned
+        with tracer.span("serve.tick.free", cat="engine"):
+            # the record's device arrays go here, by name, and not at the
+            # return: the first one freed after its program has run blocks
+            # for milliseconds on the TPU runtime
+            if rec:
+                rec.clear()
+            del toks, firsts
+        if tracer.enabled:
+            # ``tokens`` are the ticks' own: a request's first is counted
+            # apart, with its prompt's pairs where the model has experts
+            args = {"tokens": tokens, "burned": burned}
+            if ticked:
+                args.update(expert_pairs=pairs, experts_hit=hit,
+                            expert_load_max=most)
+            if first_got:
+                args["first_tokens"] = len(first_got)
+                if first_got[0].size > 1:
+                    args["prefill_expert_pairs"] = prefill_pairs
+            span.set(**args)
 
 
 class SpeculativeBatchingEngine(ContinuousBatchingEngine):
@@ -1500,6 +1689,11 @@ class SpeculativeBatchingEngine(ContinuousBatchingEngine):
         self._pending_draft = None
         self._hist: Dict[int, List[int]] = {}
         self._fds = np.zeros(int(slots), np.int32)
+        # this engine stages every tick from the host: how far a slot
+        # advances is the acceptance count it reads back, so it cannot
+        # launch ahead and carries no slot state on the device
+        self._toks = np.zeros(int(slots), np.int32)
+        self._poss = np.zeros(int(slots), np.int32)
         super().__init__(model, params, slots=slots, buf_len=buf_len,
                          top_k=0, horizon=1,
                          prefix_cache_slots=prefix_cache_slots,

@@ -178,15 +178,21 @@ def _pool_sized_instructions(hlo: str, pool_elems: int):
 
 def _engine_programs(eng, one_chip):
     """The engine's step programs compiled for the described chip from
-    abstract arguments, and the shapes of its pools."""
+    abstract arguments under the options the engine gives the chip's compiler
+    (this process's own backend is the CPU, so the jits carry none), and the
+    shapes of what they donate: the pools, then the slot state the tick
+    carries from launch to launch."""
+    from fedml_tpu.serving.batching import PAGED_TPU_COMPILER_OPTIONS
     try:
-        pools = jax.tree_util.tree_leaves(eng._pool)
+        pools = jax.tree_util.tree_leaves(eng._pool) \
+            + jax.tree_util.tree_leaves(eng._dev)
         out = {}
         for name, fn, args, _ in eng.step_programs():
             described = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                                sharding=one_chip), args)
-            out[name] = fn.lower(*described).compile()
+            out[name] = fn.lower(*described).compile(
+                compiler_options=PAGED_TPU_COMPILER_OPTIONS)
         return out, [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools]
     finally:
         eng.stop()
@@ -231,9 +237,17 @@ def test_paged_program_never_moves_a_whole_pool(request, model, program):
     module a pool is the result of a parameter, of the scatter that updates
     it (one per pool, alone in its fusion) and of the plumbing that names it,
     and of nothing that reads or writes all of it: no copy, transpose,
-    relayout or prefetch.  Every pool argument is aliased to its output."""
-    compiled, pools = request.getfixturevalue(
+    relayout or prefetch.  Every donated argument is aliased to its output:
+    the pools, and the slot state's vectors (tokens, positions, steps left,
+    keys, temperatures, adapter rows, block tables) that the tick and the
+    final chunk update where they lie.  Weights and banks are prefetched
+    whole: a slice is a start, a done and a trace event of its own, and
+    four a prefetch doubled the programs' operations."""
+    compiled, donated = request.getfixturevalue(
         {"dense": "paged_programs", "latent": "latent_programs"}[model])
+    pools = [p for p in donated if p.ndim >= 3]
+    state = [p for p in donated if p.ndim < 3]
+    assert len(state) == 7 and all(p.shape[0] == state[0].shape[0] for p in state)
     compiled = compiled[program]
     hlo = compiled.as_text()
     assert len({p.shape for p in pools}) == 1
@@ -254,6 +268,8 @@ def test_paged_program_never_moves_a_whole_pool(request, model, program):
     assert len(fusions) == len(pools), [f[:200] for f in fusions]
     # donated and aliased: the update writes the argument's own buffer
     aliases = re.search(r"input_output_alias=\{(.*?) \}, ", hlo).group(1)
-    assert aliases.count("-alias)") == len(pools), aliases
+    assert aliases.count("-alias)") == len(pools) + len(state), aliases
     pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    # a prefetch is one start and one done: no weight or bank comes in slices
+    assert "slice-start" not in hlo and "copy-start" in hlo

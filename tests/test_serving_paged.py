@@ -147,6 +147,60 @@ def test_paged_matches_dense_multi_token_horizon(paged_setup):
         paged.stop()
 
 
+# ------------------------------------------------ a finish learned late
+#
+# The engine launches tick k+1 before it reads tick k (ISSUE 32).  Budget and
+# buffer end are counts the host has; only eos is learned from the tokens:
+# the slot's lane runs once more for nothing, and its pages go back to the
+# pool with that lane's write queued before whatever uses them next.
+
+#: a slot for each of four requests and pages for three, or three slots
+EOS_CASES = {"paged": dict(slots=4, kv_page_tokens=PTOK, kv_pool_pages=13,
+                           prefill_chunk_tokens=16),
+             "paged_horizon3": dict(slots=4, kv_page_tokens=PTOK, horizon=3,
+                                    kv_pool_pages=13, prefill_chunk_tokens=16),
+             "dense": dict(slots=3)}
+
+
+@pytest.mark.parametrize("case", sorted(EOS_CASES))
+def test_eos_mid_stream_matches_generate_and_frees_its_pages(paged_setup, case):
+    """One slot ends by eos mid-stream while its neighbours go on: every
+    request's tokens equal ``generate()``'s, the lane that ran on is
+    counted, all pages are free after the drain, and the request that was
+    admitted into the freed pages (the pool holds no others for it) reads
+    bit-equal."""
+    from fedml_tpu.serving.templates.openai_compat import generate
+    _, model, params = paged_setup
+
+    def ref(prompt, n, temp=0.0, seed=0, eos=None):
+        return generate(None, params, prompt, max_new_tokens=n,
+                        temperature=temp, seed=seed, buf_len=BUF, eos_id=eos,
+                        model=model)
+
+    first = [5, 17, 42, 8, 3]
+    eos = ref(first, 12)[5]
+    # 3 + 5 + 3 of the 12 usable pages: the fourth request's 2 pages are
+    # there only once the first has ended, by eos, and given its 3 back
+    reqs = [(first, 12, 0.0, 0, eos), (list(range(1, 21)), 14, 0.8, 3, None),
+            ([7, 9, 2], 14, 0.0, 0, None), ([60, 2, 9, 9, 31, 4], 10, 0.7, 5, None)]
+    want = [ref(*r) for r in reqs]
+    assert 0 < len(want[0]) <= 5 and [len(w) for w in want[1:]] == [14, 14, 10]
+    eng = ContinuousBatchingEngine(model, params, buf_len=BUF,
+                                   **EOS_CASES[case])
+    try:
+        with eng._cond:         # all four before the loop's next pass
+            qs = [eng.submit(p, max_new_tokens=n, temperature=t, seed=s, eos_id=e)
+                  for p, n, t, s, e in reqs]
+        assert [_drain(q) for q in qs] == want
+        kv = eng.kv_stats()
+        assert kv["lanes_burned"] >= 1 and kv["ticks_ahead"] > 0
+        if eng.paged:
+            assert kv["pool"]["exhausted"] >= 1      # the fourth had to wait
+            assert kv["pages_free"] == kv["pool_pages"] - 1
+    finally:
+        eng.stop()
+
+
 # ------------------------------------------------ where a write lands
 #
 # The pool is (pool_pages, page_tokens, h_kv, d): ``pool[page, offset]`` is

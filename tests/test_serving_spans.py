@@ -1,7 +1,10 @@
 """The engine thread's live span tree (ISSUE 27): one ``serve.iter`` per loop
 pass with ``serve.admit`` / ``serve.chunk`` / ``serve.tick`` under it, on the
 tracer's per-thread stack; args are host ints; off means no event and no
-transfer."""
+transfer.  Since ISSUE 32 a tick is launched before the one before it is
+read: its ``readback`` / ``emit`` / ``free`` serve the previous dispatch, a
+``serve.flush`` reads the last one of a drain, and a steady tick uploads
+nothing."""
 
 import dataclasses
 import os
@@ -56,8 +59,12 @@ def paged_engine(mt_model):
     return eng
 
 
-def serve_all(eng):
-    qs = [eng.submit(p, max_new_tokens=n, adapter=a) for p, n, a in REQUESTS]
+def serve_all(eng, requests=REQUESTS, **kw):
+    # under the engine's condition (re-entrant): the loop sees all of them
+    # at once, so what it does with them does not depend on the threads
+    with eng._cond:
+        qs = [eng.submit(p, max_new_tokens=n, adapter=a, **kw)
+              for p, n, a in requests]
     return [[t for t in iter(q.get, None)] for q in qs]
 
 
@@ -70,12 +77,13 @@ def traced(mt_model):
         eng = paged_engine(mt_model)
         try:
             outputs = serve_all(eng)
+            stats = eng.kv_stats()
         finally:
             eng.stop()
         events = tracer.export_chrome()["traceEvents"]
     finally:
         obs.configure(enabled=False, reset=True)
-    return {"events": events, "outputs": outputs,
+    return {"events": events, "outputs": outputs, "stats": stats,
             "spans": spans_of([e for e in events if e["ph"] in "BE"])}
 
 
@@ -88,18 +96,21 @@ def test_every_iteration_leaves_a_tree_that_nests(traced):
         sorted(s["args"]["iter"] for s in iters)
     assert all({"live", "prefilling", "queued"} <= set(s["args"]) for s in iters)
     engine_tid = iters[0]["tid"]
+    # the three phases that serve a dispatch already made also run under
+    # the flush that reads the last one of a drain
+    served = ("serve.tick", "serve.flush")
     want_parent = {"serve.admit": "serve.iter", "serve.chunk": "serve.iter",
-                   "serve.tick": "serve.iter", "serve.chunk.gather": "serve.chunk",
+                   "serve.tick": "serve.iter", "serve.flush": "serve.iter",
+                   "serve.chunk.gather": "serve.chunk",
                    "serve.chunk.dispatch": "serve.chunk",
-                   "serve.chunk.readback": "serve.chunk",
                    "serve.tick.stage": "serve.tick", "serve.tick.dispatch": "serve.tick",
-                   "serve.tick.readback": "serve.tick", "serve.tick.emit": "serve.tick",
-                   "serve.tick.free": "serve.tick"}
+                   "serve.tick.readback": served, "serve.tick.emit": served,
+                   "serve.tick.free": served}
     seen = set()
     for s in spans:
         if s["name"] in want_parent:
             parent = by_id[s["parent"]]
-            assert parent["name"] == want_parent[s["name"]], s
+            assert parent["name"] in want_parent[s["name"]], s
             assert parent["t0"] <= s["t0"] and s["t1"] <= parent["t1"], s
             assert s["tid"] == engine_tid
             seen.add(s["name"])
@@ -111,6 +122,11 @@ def test_every_iteration_leaves_a_tree_that_nests(traced):
             "serve.tick.stage", "serve.tick.dispatch", "serve.tick.readback",
             "serve.tick.emit", "serve.tick.free"]
         assert all(a["t1"] <= b["t0"] for a, b in zip(kids, kids[1:]))
+    for flush in named(spans, "serve.flush"):
+        assert [k["name"] for k in children(spans, flush)] == [
+            "serve.tick.readback", "serve.tick.emit", "serve.tick.free"]
+    # no chunk waits for its token any more
+    assert not named(spans, "serve.chunk.readback")
     # every arg is a host number or a string, never a device value
     for s in spans:
         assert all(isinstance(v, (int, float, str)) for v in s["args"].values()), s
@@ -120,9 +136,14 @@ def test_tick_tokens_are_what_the_callers_received(traced):
     received = sum(len(o) for o in traced["outputs"])
     assert [len(o) for o in traced["outputs"]] == [n for _, n, _ in REQUESTS]
     ticks = named(traced["spans"], "serve.tick")
-    # the first token of a request comes from its final chunk, the rest from ticks
-    assert sum(t["args"]["tokens"] for t in ticks) == received - len(REQUESTS)
-    assert all(0 <= t["args"]["tokens"] <= t["args"]["live"] <= 3 for t in ticks)
+    # a tick delivers what the dispatch before it produced, the flush what
+    # the last one did; the first token of a request comes from its final
+    # chunk and is counted apart
+    served = ticks + named(traced["spans"], "serve.flush")
+    assert sum(t["args"]["tokens"] for t in served) == received - len(REQUESTS)
+    assert sum(t["args"].get("first_tokens", 0) for t in served) == len(REQUESTS)
+    assert all(0 <= t["args"]["tokens"] <= 3 and 1 <= t["args"]["live"] <= 3
+               for t in ticks)
     assert all(t["args"]["live_kv_tokens"] > 0 for t in ticks)
     finished = sum(s["args"]["finished"] for s in named(traced["spans"], "serve.tick.emit"))
     assert 0 < finished <= len(REQUESTS)
@@ -140,11 +161,6 @@ def test_chunks_of_a_request_carry_its_id_and_cover_its_prompt(traced):
         assert [c["args"]["start"] for c in mine] == list(range(0, len(prompt), CHUNK))
         assert [c["args"]["final"] for c in mine] == [0] * (len(mine) - 1) + [1]
         assert len({c["args"]["slot"] for c in mine}) == 1
-    # a final chunk reads its token back, the others do not
-    by_id = {s["id"]: s for s in spans}
-    assert all(by_id[s["parent"]]["args"]["final"] == 1
-               for s in named(spans, "serve.chunk.readback"))
-    assert len(named(spans, "serve.chunk.readback")) == len(REQUESTS)
     gathers = named(spans, "serve.chunk.gather")
     assert len(gathers) == len(chunks) and all("adapter_row" in g["args"] for g in gathers)
 
@@ -247,6 +263,123 @@ def test_off_means_no_event_and_no_other_transfer(mt_model):
     assert (on.device_puts, on.device_gets) == (base.device_puts, base.device_gets)
 
 
+# -- one tick ahead (ISSUE 32) ------------------------------------------------
+
+#: long enough that nothing finishes while a test looks at the stream
+LONG = [([5, 17, 42], 40, "a0"), ([7, 9], 40, None), ([3, 1, 4, 1, 5], 40, "a1")]
+
+
+def take(q, n, timeout=120.0):
+    return [q.get(timeout=timeout) for _ in range(n)]
+
+
+def test_ahead_is_zero_only_with_nothing_outstanding(mt_model):
+    """A tick is launched over an unread one except the first after an idle
+    wait or a flush; ``kv_stats()`` counts the same."""
+    tracer = obs.configure(enabled=True, reset=True, jax_hooks=False)
+    try:
+        eng = paged_engine(mt_model)
+        try:
+            serve_all(eng)
+            assert eng.generate([5, 17], max_new_tokens=4) != []   # after an idle wait
+            stats = eng.kv_stats()
+        finally:
+            eng.stop()
+        spans = spans_of([e for e in tracer.events() if e["ph"] in "BE"])
+    finally:
+        obs.configure(enabled=False, reset=True)
+    ticks = named(spans, "serve.tick")
+    flushes = named(spans, "serve.flush")
+    order = sorted(ticks + flushes, key=lambda s: s["t0"])
+    for before, at in zip([None] + order, order):
+        if at["name"] == "serve.tick":
+            # outstanding: a tick that no flush has read since
+            assert at["args"]["ahead"] == int(
+                before is not None and before["name"] == "serve.tick"), at
+    ahead = sum(t["args"]["ahead"] for t in ticks)
+    assert 0 < ahead < len(ticks) and len(flushes) >= 2
+    assert stats["ticks"] == len(ticks) and stats["ticks_ahead"] == ahead
+    assert stats["flushes"] == len(flushes) and stats["lanes_burned"] == 0
+    assert all(t["args"]["burned"] == 0 for t in order)
+    # the last dispatch of a drain is read by a flush, never left behind
+    assert order[-1]["name"] == "serve.flush"
+
+
+def test_steady_tick_uploads_nothing_and_reads_back_once(mt_model):
+    """With tracing off, a tick in which no slot changed state (no admission,
+    no finish) makes no host-to-device transfer and one device-to-host
+    transfer: the slot state stays on the device."""
+    from fedml_tpu.analysis.runtime import JaxRuntimeAudit
+    assert not obs.get_tracer().enabled
+    eng = paged_engine(mt_model)
+    try:
+        with eng._cond:
+            qs = [eng.submit(p, max_new_tokens=n, adapter=a) for p, n, a in LONG]
+        for q in qs:                      # all admitted, prefilled and ticking
+            take(q, 5)
+        # explicit transfers are counted; an implicit upload (a numpy or
+        # Python value handed to a program) fails the engine's thread
+        jax.config.update("jax_transfer_guard_host_to_device", "disallow")
+        try:
+            with JaxRuntimeAudit() as audit:
+                t0 = eng.kv_stats()["ticks"]
+                for q in qs:
+                    take(q, 12)
+                t1 = eng.kv_stats()["ticks"]
+        finally:
+            jax.config.update("jax_transfer_guard_host_to_device", "allow")
+        assert t1 - t0 >= 6
+        assert audit.device_puts == 0
+        # a tick either side of the two counts may fall inside the audit
+        assert 1 <= audit.device_gets <= t1 - t0 + 2
+        assert audit.compilations == 0
+        for q in qs:
+            assert len([t for t in iter(q.get, None)]) == 40 - 17
+    finally:
+        eng.stop()
+
+
+def test_swap_and_stop_mid_stream_deliver_what_was_produced(mt_model):
+    """A weight swap staged mid-stream lands after every request has all of
+    its tokens, with no read-back outstanding; ``stop()`` mid-stream delivers
+    every token a launched tick produced before it ends the streams."""
+    model, params, _ = mt_model
+    eng = paged_engine(mt_model)
+    try:
+        want = serve_all(eng, LONG)
+        with eng._cond:
+            qs = [eng.submit(p, max_new_tokens=n, adapter=a) for p, n, a in LONG]
+        heads = [take(q, 3) for q in qs]
+        eng.update_params(jax.tree_util.tree_map(lambda x: x * 1.01, params))
+        assert eng._unread is None and not eng._firsts
+        got = [h + [t for t in iter(q.get, None)] for h, q in zip(heads, qs)]
+        assert got == want                   # one weight version end to end
+        assert serve_all(eng, LONG) != want  # the swap landed
+    finally:
+        eng.stop()
+
+    tracer = obs.configure(enabled=True, reset=True, jax_hooks=False)
+    try:
+        eng = paged_engine(mt_model)
+        try:
+            with eng._cond:
+                qs = [eng.submit(p, max_new_tokens=n, adapter=a) for p, n, a in LONG]
+            heads = [take(q, 3) for q in qs]
+        finally:
+            eng.stop()
+        assert eng._unread is None and not eng._firsts
+        got = [h + [t for t in iter(q.get, None)] for h, q in zip(heads, qs)]
+        spans = spans_of([e for e in tracer.events() if e["ph"] in "BE"])
+    finally:
+        obs.configure(enabled=False, reset=True)
+    assert all(g == w[:len(g)] and len(g) < len(w) for g, w in zip(got, want))
+    # no budget ended: every lane of every launched tick made a token, and
+    # every one of them reached its caller
+    ticks = named(spans, "serve.tick")
+    assert sum(map(len, got)) == sum(t["args"]["live"] for t in ticks) + len(LONG)
+    assert named(spans, "serve.flush")
+
+
 # -- a model with sparse layers: what the expert layers did ------------------
 
 @pytest.fixture(scope="module")
@@ -272,8 +405,7 @@ def traced_sparse():
                                        kv_page_tokens=PTOK,
                                        prefill_chunk_tokens=CHUNK)
         try:
-            outputs = [[t for t in iter(q.get, None)] for q in [
-                eng.submit(p, max_new_tokens=n) for p, n, _ in REQUESTS]]
+            outputs = serve_all(eng, [(p, n, None) for p, n, _ in REQUESTS])
             stats = eng.kv_stats()
         finally:
             eng.stop()
@@ -287,9 +419,15 @@ def traced_sparse():
 def test_sparse_ticks_say_what_the_experts_did(traced_sparse):
     assert [len(o) for o in traced_sparse["outputs"]] == [n for _, n, _ in REQUESTS]
     ticks = named(traced_sparse["spans"], "serve.tick")
-    assert ticks and all({"expert_pairs", "experts_hit", "expert_load_max"}
-                         <= set(t["args"]) for t in ticks)
-    for t in ticks:
+    served = ticks + named(traced_sparse["spans"], "serve.flush")
+    # the counters ride the tokens: a dispatch's are on the span that read
+    # it back, the tick launched over it or the flush
+    read = [t for t in served if "expert_pairs" in t["args"]]
+    assert ticks and len(read) == len(ticks)
+    assert all(("expert_pairs" in t["args"]) == bool(t["args"]["ahead"]) for t in ticks)
+    assert all({"expert_pairs", "experts_hit", "expert_load_max"}
+               <= set(t["args"]) for t in read)
+    for t in read:
         a = t["args"]
         # three lanes, two sparse layers, four experts a token, eight held
         assert 0 <= a["expert_pairs"] <= 3 * 2 * 4
@@ -298,16 +436,19 @@ def test_sparse_ticks_say_what_the_experts_did(traced_sparse):
         assert all(isinstance(a[k], int) for k in ("expert_pairs", "experts_hit", "expert_load_max"))
     stats = traced_sparse["stats"]
     assert stats["moe_layers_ticked"] == 2 * len(ticks)
-    assert stats["experts_hit"] == sum(t["args"]["experts_hit"] for t in ticks)
+    assert stats["experts_hit"] == sum(t["args"]["experts_hit"] for t in read)
+    # what the experts did over a prompt comes behind the request's first
+    # token, on the span that read it; no chunk waits for it
     chunks = named(traced_sparse["spans"], "serve.chunk")
-    finals = [c for c in chunks if c["args"]["final"]]
-    assert len(finals) == len(REQUESTS)
-    assert all("expert_pairs" in c["args"] for c in finals)
-    assert all("expert_pairs" not in c["args"] for c in chunks if not c["args"]["final"])
+    assert sum(c["args"]["final"] for c in chunks) == len(REQUESTS)
+    assert not any("expert_pairs" in c["args"] for c in chunks)
+    firsts = [t for t in served if "first_tokens" in t["args"]]
+    assert sum(t["args"]["first_tokens"] for t in firsts) == len(REQUESTS)
     # a prompt of three chunks computes 48 rows in two sparse layers
-    assert all(0 <= c["args"]["expert_pairs"] <= 48 * 2 * 4 for c in finals)
-    assert stats["expert_pairs"] == sum(t["args"]["expert_pairs"] for t in ticks) \
-        + sum(c["args"]["expert_pairs"] for c in finals) > 0
+    assert all(0 <= t["args"]["prefill_expert_pairs"]
+               <= t["args"]["first_tokens"] * 48 * 2 * 4 for t in firsts)
+    assert stats["expert_pairs"] == sum(t["args"]["expert_pairs"] for t in read) \
+        + sum(t["args"]["prefill_expert_pairs"] for t in firsts) > 0
 
 
 def test_sparse_gauges_and_bytes_a_token(traced_sparse):

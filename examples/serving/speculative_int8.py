@@ -42,13 +42,11 @@ if __name__ == "__main__":
           f"disagree; a distilled draft pushes this toward 1.0 and cuts "
           f"target forwards ~k-fold, output unchanged)")
 
-    # batch_slots + draft_model => speculative continuous batching: greedy
-    # requests share a slot pool AND advance up to spec_k+1 tokens per
-    # device dispatch (buf_len shrinks so max_seq_len covers the
-    # buf_len + spec_k + 1 block slack)
+    # draft_model on a server without batch_slots: greedy requests go
+    # through speculative_generate one at a time (buf_len shrinks so
+    # max_seq_len covers the buf_len + k + 1 block slack)
     srv = OpenAICompatServer(None, qtree, buf_len=120, model=target,
-                             draft_model=draft, draft_params=dparams,
-                             batch_slots=2, spec_k=4)
+                             draft_model=draft, draft_params=dparams)
     port = srv.start()
     conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
     t0 = time.time()
@@ -56,6 +54,6 @@ if __name__ == "__main__":
         {"prompt": "once upon a time", "max_tokens": 32}),
         {"Content-Type": "application/json"})
     r = json.loads(conn.getresponse().read())
-    print(f"HTTP completion via speculative batching engine "
+    print(f"HTTP completion via speculative decode "
           f"({time.time() - t0:.2f}s): {len(r['choices'][0]['text'])} chars")
     srv.stop()

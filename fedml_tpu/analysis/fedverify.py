@@ -1153,62 +1153,6 @@ def build_mesh3d_block8() -> ProgramReport:
                        block=8, **_PIPE_OVER)
 
 
-def _serving_engine():
-    import jax
-    import jax.numpy as jnp
-    from ..llm.model import LlamaConfig, LlamaLM
-    from ..serving.batching import ContinuousBatchingEngine
-    cfg = LlamaConfig(vocab_size=97, dim=32, n_layers=2, n_heads=4,
-                      n_kv_heads=2, ffn_dim=64, max_seq_len=48,
-                      dtype=jnp.float32, attn_impl="blockwise")
-    model = LlamaLM(cfg)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 8), jnp.int32))
-    eng = ContinuousBatchingEngine(model, variables["params"], slots=4,
-                                   buf_len=48)
-    return eng
-
-
-def _serving_estimate(eng) -> float:
-    import jax
-    from ..core.memory_estimate import estimate_serving_memory
-    from ..core import tree as tree_util
-    cache_bytes = sum(l.nbytes for l in
-                      jax.tree_util.tree_leaves(eng._caches))
-    n_params = tree_util.num_params(eng.raw_params)
-    return estimate_serving_memory(
-        n_params=n_params, param_bytes=4, n_slots=eng.n_slots,
-        cache_bytes=cache_bytes, vocab_size=97,
-        horizon=eng.horizon)["total"]
-
-
-def _build_serving(which: str) -> ProgramReport:
-    eng = _serving_engine()
-    try:
-        est = _serving_estimate(eng)
-        progs = {n: (fn, args, donate)
-                 for n, fn, args, donate in eng.step_programs()}
-        fn, args, donate = progs[which]
-        return lower_program(f"serving_{which}", fn, args, donate,
-                             mesh_shape=(1, 1), estimate_bytes=est)
-    finally:
-        eng.stop()
-
-
-@registry.register("serving_decode_step", "serving", "step")
-def build_serving_step() -> ProgramReport:
-    """The continuous-batching engine's batched decode step (vmapped
-    KV-cache decode over all slots, horizon-scanned)."""
-    return _build_serving("decode_step")
-
-
-@registry.register("serving_insert_cache", "serving", "step", quick=True)
-def build_serving_insert() -> ProgramReport:
-    """The engine's donated cache-insert (admission writes one slot's KV
-    into the stacked cache in place)."""
-    return _build_serving("insert_cache")
-
-
 def _serving_paged_engine():
     import jax
     import jax.numpy as jnp

@@ -467,6 +467,22 @@ def client_health_stats(old_params: Pytree, client_params: Pytree,
             "loss_delta": loss - mean_loss, "weight": w}
 
 
+def cohort_robust_z(vals, weights, floor: float):
+    """In-trace twin of ``obs.health.robust_z`` over the rows with weight:
+    ``(x - median) / max(1.4826 * MAD, floor)``, 0 on pad rows.  The async
+    engine standardises a generation's reference-relative lanes here, at
+    dispatch, where the whole generation is one cohort: an apply's buffer
+    mixes rows of several generations, each measured against its own
+    generation's mean direction and mean loss, and a z-score over that
+    mixture reads the smaller group as outliers."""
+    real = jnp.asarray(weights, jnp.float32) > 0
+    vals = jnp.asarray(vals, jnp.float32)
+    med = jnp.nanmedian(jnp.where(real, vals, jnp.nan))
+    mad = jnp.nanmedian(jnp.where(real, jnp.abs(vals - med), jnp.nan))
+    z = (vals - med) / jnp.maximum(1.4826 * mad, floor)
+    return jnp.where(real, z, 0.0)
+
+
 def cohort_mean_delta(old_params: Pytree, client_params: Pytree,
                       weights) -> Pytree:
     """Weighted cohort-mean update direction ``Σ w_i Δ_i / Σ w_i`` — the

@@ -299,32 +299,6 @@ def estimate_round_footprint(lo: MeshStateLayout, *,
     }
 
 
-def estimate_serving_memory(*, n_params: float, n_slots: int,
-                            cache_bytes: float, vocab_size: int,
-                            horizon: int = 1, param_bytes: int = 4,
-                            bank_bytes: float = 0.0,
-                            safety: float = 1.25) -> Dict[str, float]:
-    """Per-chip HBM upper bound for the continuous-batching engine's
-    batched decode step (fedverify's serving HBM-fit contract): the
-    weights, the stacked KV caches (``cache_bytes`` — exact, from the
-    engine's materialized cache template), the adapter bank, and a
-    working set of one cache copy (the functionalized in-place update)
-    plus per-slot logits across the decode horizon."""
-    params = float(n_params) * param_bytes
-    logits = float(n_slots) * vocab_size * 4.0 * max(1, int(horizon))
-    work = float(cache_bytes) + logits + params * 0.25
-    total = (params + float(cache_bytes) + float(bank_bytes)
-             + work) * safety
-    return {
-        "params": params,
-        "kv_caches": float(cache_bytes),
-        "adapter_bank": float(bank_bytes),
-        "step_work": work,
-        "total": total,
-        "total_gib": total / GIB,
-    }
-
-
 def estimate_paged_serving_memory(*, n_params: float, n_slots: int,
                                   pool_bytes: float,
                                   block_table_bytes: float,
@@ -332,18 +306,16 @@ def estimate_paged_serving_memory(*, n_params: float, n_slots: int,
                                   horizon: int = 1, param_bytes: int = 4,
                                   bank_bytes: float = 0.0,
                                   safety: float = 1.25) -> Dict[str, float]:
-    """Per-chip HBM upper bound for the PAGED engine's decode step
-    (fedverify's ``serving_paged_*`` HBM-fit contracts; docs/SERVING.md
-    memory plane).  Differs from :func:`estimate_serving_memory` in what
-    the cache plane costs: the page pool (``pool_bytes`` — exact, from
-    the engine's materialized per-layer pools) is DONATED into the step,
-    so the working set prices no cache copy — only the per-layer gather
-    window the paged attention materializes transiently
-    (``window_bytes``: ``n_slots x kv_heads x max_blocks*page_tokens x
-    head_dim`` K+V for ~2 live layers), plus block tables and logits.
-    Comparing ``total`` against the dense estimate at the same slot
-    count is the bench's equal-HBM slot-capacity argument
-    (``bench.py --serve-paged``)."""
+    """Per-chip HBM upper bound for the continuous-batching engine's
+    decode step (fedverify's ``serving_paged_*`` HBM-fit contracts;
+    docs/SERVING.md memory plane): the weights, the page pool
+    (``pool_bytes`` — exact, from the engine's materialized per-layer
+    pools), the adapter bank, block tables and per-slot logits across the
+    decode horizon.  The pool is DONATED into the step, so the working
+    set prices no cache copy — only the per-layer gather window the paged
+    attention materializes transiently (``window_bytes``: ``n_slots x
+    kv_heads x max_blocks*page_tokens x head_dim`` K+V for ~2 live
+    layers)."""
     params = float(n_params) * param_bytes
     logits = float(n_slots) * vocab_size * 4.0 * max(1, int(horizon))
     work = float(window_bytes) + logits + params * 0.25

@@ -146,7 +146,8 @@ class HealthMonitor:
 
         ``client_ids`` are the sampled REGISTERED ids (host ints — the
         driver's own sampling, never a device readback); ``stats`` maps
-        :data:`HEALTH_STAT_FIELDS` (+ optional ``staleness``) to
+        :data:`HEALTH_STAT_FIELDS` (+ optional ``staleness``, and the
+        async engine's per-generation ``z_cosine`` / ``z_loss_delta``) to
         sequences at least ``len(client_ids)`` long (mesh engines pad the
         cohort axis — pad rows carry weight 0 and are dropped here).
         Returns the per-round verdict dict (also traced as the
@@ -185,8 +186,13 @@ class HealthMonitor:
         log_norm = [math.log(max(norm[i], 1e-12)) for i in range(n)]
 
         z_norm = _scatter_z(log_norm, rows, cfg.norm_floor)
-        z_cos = _scatter_z(cos, rows, cfg.cosine_floor)
-        z_loss = _scatter_z(loss_d, rows, cfg.loss_floor)
+        # the async engine brings cosine and loss_delta standardised within
+        # the generation they were measured against (its buffer mixes
+        # generations); a sync round is one cohort and is standardised here
+        z_cos = (col("z_cosine") if "z_cosine" in stats
+                 else _scatter_z(cos, rows, cfg.cosine_floor))
+        z_loss = (col("z_loss_delta") if "z_loss_delta" in stats
+                  else _scatter_z(loss_d, rows, cfg.loss_floor))
         # direction evidence gate: once training converges a BENIGN
         # client's update is near-zero noise and its cosine to the cohort
         # mean is arbitrary — only a client pushing with at least

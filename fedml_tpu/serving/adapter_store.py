@@ -8,8 +8,8 @@ sparse hash-paged host table (with optional LRU ``.npz`` spill past
 ``max_resident_pages``) that scaled per-client training state past HBM in
 the fedstore work — and the registry pages rows in on cache miss.
 Registered-adapter count is now bounded by host RAM / disk, not HBM
-(10k+ adapters through one engine at flat HBM, ``bench.py
---serve-paged``).
+(the bank's bytes stay flat from 32 to 10k registered adapters:
+``tests/test_serving_paged.py``).
 
 Thread-safety: the name→row-id map and the underlying store carry their
 own locks; ``put``/``get`` may be called from HTTP registration threads

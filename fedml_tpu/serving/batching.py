@@ -11,10 +11,12 @@ batching" — requests join/leave the batch at token granularity, so short
 requests aren't held hostage by long ones and the MXU sees batch-B matmuls
 instead of B sequential batch-1 passes).
 
-Engine states are static-shaped throughout (slot count, buffer length), so
-exactly two programs compile: the per-slot prefill and the batched step.
-Per-slot KV caches live stacked on a leading slot axis and are inserted at
-admission with a donated ``.at[slot].set``.
+Engine states are static-shaped throughout (slot count, window, pool), so
+exactly two programs compile: the batched tick and the prefill chunk.  The
+KV cache is ONE page pool per layer (:mod:`fedml_tpu.serving.paged_kv`,
+docs/SERVING.md "Memory plane"): every slot addresses its own pages through
+a block table carried as traced data, admission reserves pages on the host,
+and a prompt enters in fixed-size chunks that share the tick with decode.
 
 Multi-tenant LoRA (``adapter_slots``/``adapter_registry``, see
 :mod:`fedml_tpu.serving.adapters` and docs/SERVING.md): N adapters live
@@ -56,16 +58,16 @@ from ..obs import get_tracer
 from ..obs.histogram import ServeHistograms
 from .adapters import AdapterMissError, AdapterRegistry
 from .paged_kv import PagedBlockPool, PagedPrefixCache, PageExhaustedError
-from .templates.openai_compat import (TAIL_BLOCK, PrefixCache,
-                                      _build_cached_decode,
-                                      _replay_tail, _sample_live)
+from .templates.openai_compat import _sample_live
 
 
 class PagedKVUnsupportedError(ValueError):
-    """Raised at engine construction for the paged-KV × speculative
-    combo: the draft/target verify blocks assume contiguous per-slot
-    caches and would silently corrupt positions against a page pool.
-    Use the dense speculative engine, or the paged non-speculative one."""
+    """What the page pool cannot serve: a model that carries no
+    ``LlamaConfig`` for the engine to rebuild with the pool's geometry
+    (raised at engine construction), and a speculative draft (raised by
+    the server for ``draft_model`` with ``batch_slots``: the draft/target
+    verify blocks write multi-token windows into contiguous per-request
+    caches, :mod:`fedml_tpu.serving.speculative`)."""
 
 
 class _UnservableError(Exception):
@@ -128,8 +130,8 @@ class _Slot:
                  # device counts the same down (``left``); ``pos`` and
                  # ``remaining`` follow delivery, a dispatch behind
                  "steps",
-                 # paged-KV prefill state machine (free → prefilling →
-                 # live): prompt ids + replay cursor for the chunked
+                 # prefill state machine (free → prefilling → live):
+                 # prompt ids + replay cursor for the chunked
                  # prefill lanes, the admission-split sample key, and the
                  # slot's block-table reservation size
                  "prefilling", "pf_ids", "pf_next", "pf_n", "pf_sub",
@@ -138,7 +140,7 @@ class _Slot:
                  # clocks, engine-thread-confined like the decode state)
                  "t_submit", "t_admit", "t_prefill_end", "t_first",
                  "prompt_tokens", "out_tokens", "adapter_label",
-                 "traceparent", "drafts_proposed", "drafts_accepted",
+                 "traceparent",
                  # the running integer submit() gave the request: every
                  # span of one request carries it
                  "request")
@@ -168,8 +170,6 @@ class _Slot:
         self.out_tokens = 0
         self.adapter_label = "base"
         self.traceparent: Optional[str] = None
-        self.drafts_proposed = 0
-        self.drafts_accepted = 0
         self.request: Optional[int] = None
 
 
@@ -180,16 +180,27 @@ class ContinuousBatchingEngine:
     def __init__(self, model, params, slots: int = 4, buf_len: int = 256,
                  top_k: int = 0, top_p: float = 1.0, horizon: int = 1,
                  prefix_cache_slots: int = 0,
-                 prefix_max_tail: int = TAIL_BLOCK,
                  adapter_registry: Optional[AdapterRegistry] = None,
                  adapter_slots: int = 0,
                  metrics_port: Optional[int] = None,
                  hist_labels: int = 8,
                  slo_rules: Optional[List[Dict[str, Any]]] = None,
-                 kv_page_tokens: int = 0, kv_pool_pages: int = 0,
+                 kv_page_tokens: int = 16, kv_pool_pages: int = 0,
                  prefill_chunk_tokens: int = 0, prefill_lanes: int = 1,
                  adapter_cache_slots: int = 0,
                  adapter_store_dir: Optional[str] = None):
+        # refused before anything is started or allocated
+        self.kv_page_tokens = int(kv_page_tokens)
+        if self.kv_page_tokens <= 0:
+            raise ValueError(
+                f"kv_page_tokens={kv_page_tokens}: the page size of the "
+                "engine's KV cache, in tokens, is positive (default 16)")
+        cfg = getattr(model, "cfg", None)
+        if cfg is None or not hasattr(cfg, "kv_page_tokens"):
+            raise PagedKVUnsupportedError(
+                "paged KV needs a LlamaLM-style model carrying a "
+                "LlamaConfig (engine rebuilds it with the pool "
+                "geometry)")
         self.model = model
         # fedslo (docs/OBSERVABILITY.md): per-request lifecycle histograms
         # (TTFT / e2e / queue wait / phase times / decode rate) with
@@ -261,92 +272,101 @@ class ContinuousBatchingEngine:
         # next admission).
         self.horizon = max(1, int(horizon))
 
-        # paged KV (serving/paged_kv.py, docs/SERVING.md memory plane):
-        # kv_page_tokens>0 replaces the per-slot stacked caches with ONE
-        # page pool per layer + a per-slot block table carried as traced
-        # data.  Admission reserves ceil(min(n+max_new, buf_len)/P)
+        # the KV cache (serving/paged_kv.py, docs/SERVING.md memory plane):
+        # ONE page pool per layer + a per-slot block table carried as
+        # traced data.  Admission reserves ceil(min(n+max_new, buf_len)/P)
         # pages host-side (parking the request when the pool is dry);
         # prefill runs in fixed prefill_chunk_tokens chunks on a per-tick
         # lane budget so long prompts stop head-of-line-blocking decode.
-        self.kv_page_tokens = int(kv_page_tokens)
-        self.paged = self.kv_page_tokens > 0
-        self.paged_model = None
-        self.page_pool = None
+        ptok = self.kv_page_tokens
         self._chunks_total = 0
         self._pages_shared = 0
         self._pages_private = 0
-        if self.paged:
-            cfg = getattr(model, "cfg", None)
-            if cfg is None or not hasattr(cfg, "kv_page_tokens"):
-                raise PagedKVUnsupportedError(
-                    "paged KV needs a LlamaLM-style model carrying a "
-                    "LlamaConfig (engine rebuilds it with the pool "
-                    "geometry)")
-            ptok = self.kv_page_tokens
-            self.prefill_chunk = int(prefill_chunk_tokens) or \
-                min(64, self.buf_len)
-            self.prefill_lanes = max(1, int(prefill_lanes))
-            # per-slot block-table width: the window covers buf_len plus
-            # the worst chunk-padding / horizon-burn overhang, so every
-            # out-of-reservation write lands on a real (trash) table
-            # entry instead of index-clamping into a live page
-            overhang = max(self.prefill_chunk, self.horizon)
-            self.max_blocks = math.ceil((self.buf_len + overhang) / ptok)
-            # pages a single slot may ever RESERVE (positions < buf_len)
-            self.blocks_cap = math.ceil(self.buf_len / ptok)
-            pool_pages = int(kv_pool_pages) or \
-                (1 + self.n_slots * self.blocks_cap)
-            self.kv_pool_pages = pool_pages
-            self.paged_model = type(model)(dataclasses.replace(
-                cfg, kv_page_tokens=ptok, kv_pool_pages=pool_pages))
-            self.page_pool = PagedBlockPool(pool_pages)
-            self._btabs = np.zeros((self.n_slots, self.max_blocks),
-                                   np.int32)
+        self.prefill_chunk = int(prefill_chunk_tokens) or \
+            min(64, self.buf_len)
+        self.prefill_lanes = max(1, int(prefill_lanes))
+        # per-slot block-table width: the window covers buf_len plus
+        # the worst chunk-padding / horizon-burn overhang, so every
+        # out-of-reservation write lands on a real (trash) table
+        # entry instead of index-clamping into a live page
+        overhang = max(self.prefill_chunk, self.horizon)
+        self.max_blocks = math.ceil((self.buf_len + overhang) / ptok)
+        # pages a single slot may ever RESERVE (positions < buf_len)
+        self.blocks_cap = math.ceil(self.buf_len / ptok)
+        # 0: a page for every position of every slot, and the trash page —
+        # no admitted request ever parks for pages
+        pool_pages = int(kv_pool_pages) or \
+            (1 + self.n_slots * self.blocks_cap)
+        self.kv_pool_pages = pool_pages
+        self.paged_model = type(model)(dataclasses.replace(
+            cfg, kv_page_tokens=ptok, kv_pool_pages=pool_pages))
+        self.page_pool = PagedBlockPool(pool_pages)
+        self._btabs = np.zeros((self.n_slots, self.max_blocks), np.int32)
 
-        self._prefill, self._tail_step, self._tail_block = \
-            _build_cached_decode(model, self.top_k, self.top_p)
-        # prefix_cache_slots > 0: admission reuses prefill KV for shared
-        # prompt prefixes (templates/openai_compat.PrefixCache — LRU,
-        # longest-common-prefix, params-identity invalidation); only the
-        # engine thread touches it during _admit, but the cache carries
-        # its own lock anyway.  Paged engines share *pages* instead of
-        # copying KV: PagedPrefixCache lends refcounted full pages into
-        # the new slot's block table, and the chunk replay starts past
-        # the shared span, so lent pages stay read-only under sharers.
+        # prefix_cache_slots > 0: admission shares *pages* for shared
+        # prompt prefixes: PagedPrefixCache (LRU, longest common prefix in
+        # whole pages, params-identity invalidation) lends refcounted full
+        # pages into the new slot's block table, and the chunk replay
+        # starts past the shared span, so lent pages stay read-only under
+        # sharers.  Only the engine thread touches it during admission, but
+        # the cache carries its own lock anyway.
         self.prefix_cache = None
         if prefix_cache_slots:
-            if self.paged:
-                self.prefix_cache = PagedPrefixCache(
-                    prefix_cache_slots, self.kv_page_tokens,
-                    self.page_pool)
-            else:
-                self.prefix_cache = PrefixCache(prefix_cache_slots,
-                                                max_tail=int(prefix_max_tail))
+            self.prefix_cache = PagedPrefixCache(
+                prefix_cache_slots, ptok, self.page_pool)
 
         from ..llm.quantization import dequantize_params, weight_dtype
         wdtype = weight_dtype(model)
 
+        from ..llm.moe import COUNTERS
+        pm = self.paged_model
+        C = self.prefill_chunk
         horizon = self.horizon
+        # only the TPU's compiler knows the options
+        options = (PAGED_TPU_COMPILER_OPTIONS
+                   if jax.default_backend() == "tpu" else None)
 
-        def carried_tick(state, kv, step):
-            """What the four tick programs share: ``horizon`` scanned steps
-            of every lane from the slot state the last program left on the
-            device, and the state the next program takes.  ``step(kv, toks,
-            poss, keys)`` gives ``(next tokens, kv, carry keys, experts'
-            counters or None)``.  A lane is live while it has steps
-            ``left``; only a live lane's token, position and key advance
-            (a prefilling slot's admission key must not move with the
-            splits its lane rides along for), and the count runs down here
-            as it does on the host, so a budget's end uploads nothing."""
+        def paged_tick(params, lora_slots, pool, state):
+            """``horizon`` scanned steps of every lane from the slot state
+            the last program left on the device, and the state the next
+            program takes.  Each step is ONE batched apply against the
+            shared pool — no vmap: every slot addresses its own pages via
+            the traced block tables, per-slot depths ride the (b,)
+            start_pos vector, and the per-slot key splits follow the
+            single-request path's sequence exactly (split[0]=carry,
+            split[1]=sample).  A lane is live while it has steps ``left``;
+            only a live lane's token, position and key advance (a
+            prefilling slot's admission key must not move with the splits
+            its lane rides along for), and the count runs down here as it
+            does on the host, so a budget's end uploads nothing.  A lane
+            that is not live (free, prefilling, finished) sees an all-trash
+            table, so its burn write lands in garbage and never in a page
+            another slot is reading."""
+            # int8-quantized trees dequantize inside the trace (stays int8
+            # in HBM; per-matmul dequant fuses) — no-op for plain trees
+            params = dequantize_params(params, wdtype)
             live = state["left"] > 0
+            btabs = jnp.where(live[:, None], state["btabs"], 0)
 
             def body(carry, _):
-                kv, toks, poss, keys = carry
-                nxt, kv, keys, counts = step(kv, toks, poss, keys)
-                return (kv, nxt, poss + 1, keys), (nxt, counts)
+                pool, toks, poss, keys = carry
+                variables = {"params": params, "cache": pool}
+                if lora_slots is not None:
+                    variables["lora"] = lora_slots
+                logits, mut = pm.apply(
+                    variables, toks[:, None], decode=True,
+                    start_pos=poss, block_tables=btabs,
+                    mutable=["cache", COUNTERS])
+                split = jax.vmap(jax.random.split)(keys)
+                nxt = jax.vmap(
+                    lambda lg, sub, temp: _sample_live(
+                        lg, sub, temp, self.top_k, self.top_p)
+                )(logits[:, 0], split[:, 1], state["temps"])
+                return ((mut["cache"], nxt, poss + 1, split[:, 0]),
+                        (nxt, _moe_counters(mut)))
 
-            (kv, toks, poss, keys), (hist, counts) = jax.lax.scan(
-                body, (kv, state["toks"], state["poss"], state["keys"]),
+            (pool, toks, poss, keys), (hist, counts) = jax.lax.scan(
+                body, (pool, state["toks"], state["poss"], state["keys"]),
                 None, length=horizon)
             state = dict(
                 state, toks=jnp.where(live, toks, state["toks"]),
@@ -354,150 +374,65 @@ class ContinuousBatchingEngine:
                 keys=jnp.where(live[:, None], keys, state["keys"]),
                 left=jnp.maximum(state["left"] - horizon, 0))
             # hist: (horizon, n_slots) → host iterates per-slot rows
-            return _with_counters(hist.T, counts), kv, state
+            return _with_counters(hist.T, counts), pool, state
 
-        def dense_tick(params, lora_slots, caches, state):
-            # int8-quantized trees dequantize inside the trace (stays int8
-            # in HBM; per-matmul dequant fuses) — no-op for plain trees
+        @partial(jax.jit, donate_argnums=(1, 2),
+                 compiler_options=options)
+        def paged_step(params, pool, state):
+            return paged_tick(params, None, pool, state)
+
+        @partial(jax.jit, donate_argnums=(2, 3),
+                 compiler_options=options)
+        def paged_step_mt(params, bank, pool, state):
+            return paged_tick(params, jax.tree_util.tree_map(
+                lambda b: b[state["aids"]], bank), pool, state)
+
+        @partial(jax.jit, donate_argnums=(2, 3),
+                 compiler_options=options)
+        def paged_chunk(params, lora, pool, state, chunk, acc):
+            # one fixed-shape (1, C) prefill chunk for one slot.
+            # ``chunk`` is everything the host knows of it, one
+            # upload: the C token ids, then ``start idx slot pos
+            # left`` and the sample key's words.  The sample index is
+            # TRACED so intermediate chunks (token discarded) and the
+            # final chunk (token at n-1-chunk_start) ride one compiled
+            # program; the slot's block table and temperature are its
+            # row of the carried state.  A final chunk (``left`` >= 0)
+            # hands the slot over on the device: the sampled token,
+            # the position ``pos`` and the steps ``left`` go into its
+            # row, so the slot joins the next tick with no read-back.
+            # ``acc`` is the last chunk's result for the same request
+            # (None for a model without sparse layers): the experts'
+            # counters add up behind the token from chunk to chunk,
+            # and the final chunk's one read-back brings the request's
             params = dequantize_params(params, wdtype)
+            start, idx, slot, pos, left = (chunk[C + j] for j in range(5))
+            key = jax.lax.bitcast_convert_type(chunk[C + 5:], jnp.uint32)
+            variables = {"params": params, "cache": pool}
+            if lora is not None:
+                variables["lora"] = lora
+            logits, mut = pm.apply(
+                variables, chunk[None, :C], decode=True,
+                start_pos=start[None],
+                block_tables=state["btabs"][slot][None],
+                mutable=["cache", COUNTERS])
+            tok = _sample_live(logits[0, idx], key, state["temps"][slot],
+                               self.top_k, self.top_p)
+            def handed(vec, new):
+                return vec.at[slot].set(
+                    jnp.where(left >= 0, new, vec[slot]))
 
-            def one(cache, tok, pos, key, temp, lora):
-                variables = {"params": params, "cache": cache}
-                if lora is not None:
-                    variables["lora"] = lora
-                logits, mut = model.apply(
-                    variables, tok[None, None], decode=True, start_pos=pos,
-                    mutable=["cache"])
-                key, sub = jax.random.split(key)
-                nxt = _sample_live(logits[0, 0], sub, temp, self.top_k,
-                                   self.top_p)
-                return nxt, mut["cache"], key
+            state = dict(state, toks=handed(state["toks"], tok),
+                         poss=handed(state["poss"], pos),
+                         left=handed(state["left"], left))
+            counts = _moe_counters(mut)
+            if counts is not None:      # added to the request's so far
+                counts = jnp.stack([acc[1:], counts])
+            return _with_counters(tok, counts), mut["cache"], state
 
-            def step(caches, toks, poss, keys):
-                return jax.vmap(one)(caches, toks, poss, keys,
-                                     state["temps"], lora_slots) + (None,)
-
-            return carried_tick(state, caches, step)
-
-        @partial(jax.jit, donate_argnums=(2,))
-        def batched_step(params, caches, state):
-            return dense_tick(params, None, caches, state)
-
-        @partial(jax.jit, donate_argnums=(3,))
-        def batched_step_mt(params, bank, caches, state):
-            # gather(bank, slot_adapter_ids) — one batched gather per lora
-            # leaf; the vmapped apply then runs the adapter matmuls
-            # slot-batched against the shared base (grouped einsums after
-            # vmap batching).  bank + aids are traced arguments: any
-            # request→adapter assignment reuses this one program.
-            return dense_tick(params, jax.tree_util.tree_map(
-                lambda b: b[state["aids"]], bank), caches, state)
-
-        self._step = batched_step if self.registry is None \
-            else batched_step_mt
-
-        if self.paged:
-            from ..llm.moe import COUNTERS
-            pm = self.paged_model
-            C = self.prefill_chunk
-            # only the TPU's compiler knows the options
-            options = (PAGED_TPU_COMPILER_OPTIONS
-                       if jax.default_backend() == "tpu" else None)
-
-            def paged_tick(params, lora_slots, pool, state):
-                # ONE batched apply against the shared pool — no vmap:
-                # every slot addresses its own pages via the traced block
-                # tables, per-slot depths ride the (b,) start_pos vector.
-                # The per-slot key splits replay the dense engine's
-                # sequence exactly (split[0]=carry, split[1]=sample).
-                # A lane that is not live (free, prefilling, finished)
-                # sees an all-trash table, so its burn write lands in
-                # garbage and never in a page another slot is reading.
-                params = dequantize_params(params, wdtype)
-                btabs = jnp.where((state["left"] > 0)[:, None],
-                                  state["btabs"], 0)
-
-                def step(pool, toks, poss, keys):
-                    variables = {"params": params, "cache": pool}
-                    if lora_slots is not None:
-                        variables["lora"] = lora_slots
-                    logits, mut = pm.apply(
-                        variables, toks[:, None], decode=True,
-                        start_pos=poss, block_tables=btabs,
-                        mutable=["cache", COUNTERS])
-                    split = jax.vmap(jax.random.split)(keys)
-                    nxt = jax.vmap(
-                        lambda lg, sub, temp: _sample_live(
-                            lg, sub, temp, self.top_k, self.top_p)
-                    )(logits[:, 0], split[:, 1], state["temps"])
-                    return nxt, mut["cache"], split[:, 0], _moe_counters(mut)
-
-                return carried_tick(state, pool, step)
-
-            @partial(jax.jit, donate_argnums=(1, 2),
-                     compiler_options=options)
-            def paged_step(params, pool, state):
-                return paged_tick(params, None, pool, state)
-
-            @partial(jax.jit, donate_argnums=(2, 3),
-                     compiler_options=options)
-            def paged_step_mt(params, bank, pool, state):
-                return paged_tick(params, jax.tree_util.tree_map(
-                    lambda b: b[state["aids"]], bank), pool, state)
-
-            @partial(jax.jit, donate_argnums=(2, 3),
-                     compiler_options=options)
-            def paged_chunk(params, lora, pool, state, chunk, acc):
-                # one fixed-shape (1, C) prefill chunk for one slot.
-                # ``chunk`` is everything the host knows of it, one
-                # upload: the C token ids, then ``start idx slot pos
-                # left`` and the sample key's words.  The sample index is
-                # TRACED so intermediate chunks (token discarded) and the
-                # final chunk (token at n-1-chunk_start) ride one compiled
-                # program; the slot's block table and temperature are its
-                # row of the carried state.  A final chunk (``left`` >= 0)
-                # hands the slot over on the device: the sampled token,
-                # the position ``pos`` and the steps ``left`` go into its
-                # row, so the slot joins the next tick with no read-back.
-                # ``acc`` is the last chunk's result for the same request
-                # (None for a model without sparse layers): the experts'
-                # counters add up behind the token from chunk to chunk,
-                # and the final chunk's one read-back brings the request's
-                params = dequantize_params(params, wdtype)
-                start, idx, slot, pos, left = (chunk[C + j] for j in range(5))
-                key = jax.lax.bitcast_convert_type(chunk[C + 5:], jnp.uint32)
-                variables = {"params": params, "cache": pool}
-                if lora is not None:
-                    variables["lora"] = lora
-                logits, mut = pm.apply(
-                    variables, chunk[None, :C], decode=True,
-                    start_pos=start[None],
-                    block_tables=state["btabs"][slot][None],
-                    mutable=["cache", COUNTERS])
-                tok = _sample_live(logits[0, idx], key, state["temps"][slot],
-                                   self.top_k, self.top_p)
-                def handed(vec, new):
-                    return vec.at[slot].set(
-                        jnp.where(left >= 0, new, vec[slot]))
-
-                state = dict(state, toks=handed(state["toks"], tok),
-                             poss=handed(state["poss"], pos),
-                             left=handed(state["left"], left))
-                counts = _moe_counters(mut)
-                if counts is not None:      # added to the request's so far
-                    counts = jnp.stack([acc[1:], counts])
-                return _with_counters(tok, counts), mut["cache"], state
-
-            self._step = paged_step if self.registry is None \
-                else paged_step_mt
-            self._chunk = paged_chunk
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def insert_cache(caches, cache, slot):
-            return jax.tree_util.tree_map(
-                lambda all_c, c: all_c.at[slot].set(c), caches, cache)
-
-        self._insert = insert_cache
+        self._step = paged_step if self.registry is None \
+            else paged_step_mt
+        self._chunk = paged_chunk
 
         # the slot state the tick program carries from launch to launch
         # (docs/SERVING.md, "The loop"): one row a slot, on the device.
@@ -505,8 +440,7 @@ class ContinuousBatchingEngine:
         # changed it, all of an iteration's changes in one staged array
         # ``[block table | tok pos left temp aid | key words | op]``.
         key_words = int(np.asarray(jax.random.PRNGKey(0)).size)
-        blocks = self.max_blocks if self.paged else 0
-        self._row_blocks = blocks
+        blocks = self.max_blocks
 
         @partial(jax.jit, donate_argnums=(0,))
         def slot_rows(state, rows):
@@ -533,11 +467,10 @@ class ContinuousBatchingEngine:
                      "poss": jnp.zeros(n, jnp.int32),
                      "left": jnp.zeros(n, jnp.int32),
                      "temps": jnp.zeros(n, jnp.float32),
-                     "keys": jnp.zeros((n, key_words), jnp.uint32)}
+                     "keys": jnp.zeros((n, key_words), jnp.uint32),
+                     "btabs": jnp.zeros((n, blocks), jnp.int32)}
         if self.registry is not None:
             self._dev["aids"] = jnp.zeros(n, jnp.int32)
-        if self.paged:
-            self._dev["btabs"] = jnp.zeros((n, blocks), jnp.int32)
         self._rows = np.zeros((n, blocks + 6 + key_words), np.int32)
         self._rows_staged = False
         # the dispatch whose results the host has not read yet, as
@@ -552,49 +485,35 @@ class ContinuousBatchingEngine:
 
         dummy_lora = (self.registry.lora_for_row(0)
                       if self.registry is not None else None)
-        if self.paged:
-            # materialize the page pool from the chunk program's shape —
-            # eval_shape only, nothing dense ever allocates
-            self._caches = None
-            chunk0 = jnp.zeros((1, self.prefill_chunk), jnp.int32)
-            btab0 = jnp.zeros((1, self.max_blocks), jnp.int32)
+        # materialize the page pool from the chunk program's shape
+        # (eval_shape only)
+        chunk0 = jnp.zeros((1, self.prefill_chunk), jnp.int32)
+        btab0 = jnp.zeros((1, self.max_blocks), jnp.int32)
 
-            def _shape_probe(p):
-                variables = {"params": p}
-                if dummy_lora is not None:
-                    variables["lora"] = dummy_lora
-                return self.paged_model.apply(
-                    variables, chunk0, decode=True,
-                    start_pos=jnp.zeros((1,), jnp.int32),
-                    block_tables=btab0, mutable=["cache"])
+        def _shape_probe(p):
+            variables = {"params": dequantize_params(p, wdtype)}
+            if dummy_lora is not None:
+                variables["lora"] = dummy_lora
+            return self.paged_model.apply(
+                variables, chunk0, decode=True,
+                start_pos=jnp.zeros((1,), jnp.int32),
+                block_tables=btab0, mutable=["cache"])
 
-            _, shapes = jax.eval_shape(_shape_probe, self.raw_params)
-            self._pool = jax.tree_util.tree_map(
-                lambda s: jnp.zeros(s.shape, s.dtype), shapes["cache"])
-            # what one cached token costs over all layers, whatever a page
-            # holds (K and V rows of every kv head; one latent row)
-            self._kv_bytes_per_token = sum(
-                p.nbytes for p in jax.tree_util.tree_leaves(self._pool)
-            ) // (pool_pages * ptok)
-            # sparse layers: the chunk program's first accumulator
-            cfg = self.paged_model.cfg
-            self._moe_layers = sum(
-                cfg.sparse_layer(i) for i in range(cfg.n_layers))
-            self._chunk_acc0 = jnp.zeros((4,), jnp.int32) \
-                if self._moe_layers else None
-            self._chunk_words = self.prefill_chunk + 5 + key_words
-            self._host_device = jax.devices("cpu")[0]
-        else:
-            # materialize the stacked cache template from one dummy
-            # prefill (MT engines pass the zero bank row — a lora_rank>0
-            # model can't apply without its "lora" collection)
-            dummy = jnp.zeros((1, self.buf_len), jnp.int32)
-            _, cache0 = self._prefill(self.raw_params, dummy_lora, dummy,
-                                      jnp.int32(1), jax.random.PRNGKey(0),
-                                      jnp.float32(0.0))
-            self._caches = jax.tree_util.tree_map(
-                lambda c: jnp.zeros((self.n_slots,) + c.shape, c.dtype),
-                cache0)
+        _, shapes = jax.eval_shape(_shape_probe, self.raw_params)
+        self._pool = jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), shapes["cache"])
+        # what one cached token costs over all layers, whatever a page
+        # holds (K and V rows of every kv head; one latent row)
+        self._kv_bytes_per_token = sum(
+            p.nbytes for p in jax.tree_util.tree_leaves(self._pool)
+        ) // (pool_pages * ptok)
+        # sparse layers: the chunk program's first accumulator
+        self._moe_layers = sum(
+            cfg.sparse_layer(i) for i in range(cfg.n_layers))
+        self._chunk_acc0 = jnp.zeros((4,), jnp.int32) \
+            if self._moe_layers else None
+        self._chunk_words = self.prefill_chunk + 5 + key_words
+        self._host_device = jax.devices("cpu")[0]
 
         self._slots = [_Slot() for _ in range(self.n_slots)]
         self._waiting: "queue.Queue[dict]" = queue.Queue()
@@ -616,7 +535,7 @@ class ContinuousBatchingEngine:
         # thread once live slots drain (admission pauses meanwhile)
         self._pending_params = None
         self._ticks = 0  # batched steps executed (observability)
-        # what the sparse layers did (paged engines; kv_stats): pairs the
+        # what the sparse layers did (kv_stats): pairs the
         # held experts computed in ticks and finished prefills; held
         # experts that got a token and sparse layers run, over the ticks
         self._expert_pairs = 0
@@ -761,10 +680,6 @@ class ContinuousBatchingEngine:
                         f"{timeout}s (in-flight requests still draining)")
                 self._cond.wait(timeout=min(0.5, remaining))
 
-    def _on_swap(self) -> None:
-        """Hook run (under ``_cond``) when the staged swap is applied —
-        the speculative subclass swaps its draft tree here."""
-
     def _on_adapter_fetched(self, name: str) -> None:
         """Fetch-worker callback (cache mode): wake the engine so parked
         adapter-miss requests retry immediately."""
@@ -788,34 +703,23 @@ class ContinuousBatchingEngine:
         compiled programs as ``(name, jitted_fn, args, donate_argnums)``
         on their resting buffer shapes, so the contract checker can
         AOT-lower them without serving a request.  ``decode_step`` is the
-        per-tick batched decode ``_dispatch`` launches; ``insert_cache``
-        is admission's donated slot write."""
+        per-tick batched decode ``_dispatch`` launches: it donates the
+        page pool and the carried slot state (the two arguments after
+        params[/bank]).  ``prefill_chunk`` is the other compiled citizen —
+        both pinned so a page-geometry change shows up as a contract
+        diff, not a silent regression."""
         bank = () if self.registry is None else (self.registry.bank,)
         state_arg = 2 + len(bank)
-        if self.paged:
-            # paged memory plane: the decode step donates the page pool
-            # and the carried slot state (the two arguments after
-            # params[/bank]) and the chunk program is the third compiled
-            # citizen — both pinned so a page-geometry change shows up as
-            # a contract diff, not a silent regression
-            lora = (self.registry.lora_for_row(0)
-                    if self.registry is not None else None)
-            chunk = jnp.zeros((self._chunk_words,), jnp.int32)
-            return [
-                ("decode_step", self._step,
-                 (self.raw_params, *bank, self._pool, self._dev),
-                 (state_arg - 1, state_arg)),
-                ("prefill_chunk", self._chunk,
-                 (self.raw_params, lora, self._pool, self._dev, chunk,
-                  self._chunk_acc0), (2, 3)),
-            ]
-        cache0 = jax.tree_util.tree_map(lambda c: c[0], self._caches)
+        lora = (self.registry.lora_for_row(0)
+                if self.registry is not None else None)
+        chunk = jnp.zeros((self._chunk_words,), jnp.int32)
         return [
             ("decode_step", self._step,
-             (self.raw_params, *bank, self._caches, self._dev),
-             (state_arg,)),
-            ("insert_cache", self._insert,
-             (self._caches, cache0, jnp.int32(0)), (0,)),
+             (self.raw_params, *bank, self._pool, self._dev),
+             (state_arg - 1, state_arg)),
+            ("prefill_chunk", self._chunk,
+             (self.raw_params, lora, self._pool, self._dev, chunk,
+              self._chunk_acc0), (2, 3)),
         ]
 
     # -- engine loop -------------------------------------------------------
@@ -825,17 +729,18 @@ class ContinuousBatchingEngine:
                 return i
         return None
 
-    # the device-carried slot state and the rows staged for it are
-    # engine-thread-confined like the rest of the decode state (see _admit)
+    # the decode state (the page pool _pool, the carried slot state _dev
+    # and the rows staged for it) is engine-thread-confined: written only
+    # on the engine thread, never touched by submit()/HTTP threads, so it
+    # needs no lock despite living next to shared state
     def _stage_row(self, slot: int, key, temp: float, aid: int,
                    tok: int = 0, pos: int = 0, left: int = 0) -> None:
         """Stage ``slot``'s whole row for the next ``_sync_rows``: what an
-        admission knows (a paged one leaves token, position and steps to
-        its final chunk, which writes them on the device)."""
+        admission knows (it leaves token, position and steps to the
+        request's final chunk, which writes them on the device)."""
         row = self._rows[slot]
-        if self.paged:
-            row[:self._row_blocks] = self._btabs[slot]
-        row[self._row_blocks:-1] = (
+        row[:self.max_blocks] = self._btabs[slot]
+        row[self.max_blocks:-1] = (
             tok, pos, left, np.float32(temp).view(np.int32), aid,
             *np.asarray(key).view(np.int32))
         row[-1] = _ROW_PUT
@@ -872,7 +777,7 @@ class ContinuousBatchingEngine:
         s.prefilling = False
         s.pf_ids = None
         s.pf_sub = None
-        if self.paged and s.n_blocks:
+        if s.n_blocks:
             # drop the slot's hold on its block-table pages (shared
             # prefix pages survive under the cache / other sharers)
             self.page_pool.release(
@@ -934,10 +839,7 @@ class ContinuousBatchingEngine:
             queue_s=round(queue_s, 6), prefill_s=round(prefill_s, 6),
             ttft_s=round(ttft_s, 6) if ttft_s is not None else None,
             decode_s=round(decode_s, 6), e2e_s=round(e2e_s, 6),
-            traceparent=s.traceparent,
-            drafts_proposed=s.drafts_proposed or None,
-            drafts_accepted=(s.drafts_accepted if s.drafts_proposed
-                             else None))
+            traceparent=s.traceparent)
         tracer.complete("serve.queue", queue_s, cat="serve", tid=lane,
                         end_s_ago=max(e2e_s - queue_s, 0.0), slot=i,
                         request=s.request)
@@ -963,94 +865,7 @@ class ContinuousBatchingEngine:
             self._tok_window[1] += 1
         return s.remaining > 0 and s.pos < self.buf_len
 
-    def _admit(self, req: dict, slot: int):
-        t_admit = time.monotonic()
-        ids = req["prompt_ids"]
-        n = len(ids)
-        buf = np.zeros((1, self.buf_len), np.int32)
-        buf[0, :n] = ids
-        key = jax.random.PRNGKey(req["seed"])
-        temp = jnp.float32(req["temperature"])
-        # multi-tenant: prefill against the request's gathered bank row
-        # (row 0 = the zero adapter for base traffic, so the lora arg is
-        # ALWAYS a tree on MT engines — one compiled prefill).  The prefix
-        # cache keys on the registration token, not the gathered tree
-        # (fresh identity per gather): KV computed under one adapter
-        # version can never serve another.
-        row = req.get("adapter_row", 0)
-        atok = req.get("adapter_token")
-        lora = (self.registry.lora_for_row(row)
-                if self.registry is not None else None)
-        hit_len, hit_cache = (self.prefix_cache.lookup(ids, self.raw_params,
-                                                       atok)
-                              if self.prefix_cache is not None and n > 0
-                              else (0, None))
-        # serve.prefill is the one LIVE phase span (nests under the
-        # caller's serve.admit); it closes on the int() below — the
-        # engine's pre-existing sync point, not a new one
-        with get_tracer().span("serve.prefill", cat="serve", slot=slot,
-                               prompt_tokens=n,
-                               cache_hit=int(hit_cache is not None)):
-            if hit_cache is not None:
-                # shared replay discipline (openai_compat._replay_tail):
-                # exact hits rewrite only the last position (idempotent);
-                # fitting multi-token tails replay as ONE tail_block
-                # dispatch
-                cache = hit_cache
-                start = min(hit_len, n - 1)
-                max_seq = getattr(getattr(self.model, "cfg", None),
-                                  "max_seq_len", self.buf_len)
-                tok, cache, key = _replay_tail(
-                    partial(self._tail_step, self.raw_params, lora),
-                    partial(self._tail_block, self.raw_params, lora),
-                    cache, jnp.asarray(buf), ids, start, n, max_seq, key,
-                    temp)
-            else:
-                key, sub = jax.random.split(key)
-                tok, cache = self._prefill(self.raw_params, lora,
-                                           jnp.asarray(buf), jnp.int32(n),
-                                           sub, temp)
-            tok_host = int(tok)
-        t_prefill_end = time.monotonic()
-        if self.prefix_cache is not None and n > 0:
-            # the cache object is internally locked; the reference itself
-            # is set once in the ctor and never rebound
-            # fedrace: disable-next-line=unguarded-shared-write
-            self.prefix_cache.insert(ids, cache, self.raw_params, atok)
-        # the decode state (_caches, the carried slot state _dev and the
-        # rows staged for it) is engine-thread-confined: written only on
-        # the engine thread, never touched by submit()/HTTP threads, so it
-        # needs no lock despite living next to shared state
-        # fedrace: disable-next-line=unguarded-shared-write
-        self._caches = self._insert(self._caches, cache, jnp.int32(slot))
-        s = self._slots[slot]
-        s.live = True
-        s.q = req["q"]
-        s.pos = n
-        s.remaining = req["max_new_tokens"]
-        s.eos_id = req["eos_id"]
-        s.adapter_row = row
-        # request-lifecycle telemetry (engine-thread-confined, read back
-        # by _observe_finish): host clocks + counts only
-        s.t_submit = req.get("t_submit", t_admit)
-        s.t_admit = t_admit
-        s.t_prefill_end = t_prefill_end
-        s.t_first = None
-        s.prompt_tokens = n
-        s.out_tokens = 0
-        s.adapter_label = req.get("adapter_label", "base")
-        s.traceparent = req.get("traceparent")
-        s.request = req.get("request")
-        s.drafts_proposed = 0
-        s.drafts_accepted = 0
-        if not self._emit(slot, tok_host):
-            self._finish(slot)
-            return
-        s.steps = min(s.remaining, self.buf_len - n)
-        self._stage_row(slot, key, req["temperature"], row, tok=tok_host,
-                        pos=n, left=s.steps)
-
-    # -- paged admission ---------------------------------------------------
+    # -- admission ---------------------------------------------------------
     def _reserve_pages(self, req: dict, slot: int) -> None:
         """Wire ``slot``'s block table: longest shareable prefix pages
         (incref'd) + fresh private pages for the rest of the request's
@@ -1090,7 +905,7 @@ class ContinuousBatchingEngine:
             self._pages_shared += full
             self._pages_private += priv
 
-    def _admit_paged(self, req: dict, slot: int) -> None:
+    def _admit(self, req: dict, slot: int) -> None:
         """Enter the prefilling state (free → prefilling): block table is
         already wired by ``_reserve_pages``; the chunk lanes in
         ``_prefill_tick`` replay the prompt from the shared-page boundary
@@ -1099,7 +914,7 @@ class ContinuousBatchingEngine:
         ids = req["prompt_ids"]
         n = len(ids)
         full, need_blocks = req.pop("_kv")
-        # same split sequence as the dense prefill path: sub samples the
+        # same split sequence as the single-request path: sub samples the
         # first token (on the final chunk), key carries into decode.  On
         # the host's own device: the same integers, and no wait behind the
         # tick the accelerator is running
@@ -1132,8 +947,6 @@ class ContinuousBatchingEngine:
         s.adapter_label = req.get("adapter_label", "base")
         s.traceparent = req.get("traceparent")
         s.request = req.get("request")
-        s.drafts_proposed = 0
-        s.drafts_accepted = 0
         self._stage_row(slot, key, req["temperature"], s.adapter_row)
 
     def _prefill_tick(self) -> None:
@@ -1186,7 +999,7 @@ class ContinuousBatchingEngine:
                               s.steps if final else -1)
             chunk[C + 5:] = s.pf_sub.view(np.int32)
             # the page pool is engine-thread-confined like the other
-            # decode state (see _admit); step_programs reads it at rest
+            # decode state (see _stage_row); step_programs reads it at rest
             # fedrace: disable-next-line=unguarded-shared-write
             tok, self._pool, self._dev = self._chunk(
                 self.raw_params, lora, self._pool, self._dev,
@@ -1207,6 +1020,9 @@ class ContinuousBatchingEngine:
         if self.prefix_cache is not None and n > 0:
             fullpages = n // self.kv_page_tokens
             if fullpages:
+                # the cache object is internally locked; the reference
+                # itself is set once in the ctor and never rebound
+                # fedrace: disable-next-line=unguarded-shared-write
                 self.prefix_cache.insert(
                     s.pf_ids,
                     [int(p) for p in self._btabs[i, :fullpages]],
@@ -1215,17 +1031,16 @@ class ContinuousBatchingEngine:
         s.pf_sub = None
 
     def _admit_one(self, req: dict, slot: int, tracer) -> bool:
-        """Admission front door for both engines: cache-mode adapter pin
-        (deferred from submit) + paged page reservation, then the real
-        admit.  Returns False when the request parked (adapter page-in in
-        flight / pool dry) or failed open — the slot stays free."""
+        """Admission front door: cache-mode adapter pin (deferred from
+        submit) + page reservation, then the real admit.  Returns False
+        when the request parked (adapter page-in in flight / pool dry) or
+        failed open — the slot stays free."""
         try:
             if (self._store_mode and req.get("adapter") is not None
                     and req.get("adapter_token") is None):
                 row, atok = self.registry.acquire(req["adapter"])
                 req["adapter_row"], req["adapter_token"] = row, atok
-            if self.paged:
-                self._reserve_pages(req, slot)
+            self._reserve_pages(req, slot)
         except AdapterMissError:
             req["_park_reason"] = "adapter"
             self._parked.append(req)
@@ -1249,10 +1064,7 @@ class ContinuousBatchingEngine:
         with tracer.span("serve.admit", cat="serve", slot=slot,
                          adapter_row=req.get("adapter_row", 0),
                          request=req.get("request")):
-            if self.paged:
-                self._admit_paged(req, slot)
-            else:
-                self._admit(req, slot)
+            self._admit(req, slot)
         with self._stats_lock:
             self.serve_stats["admits"] += 1
         return True
@@ -1282,19 +1094,18 @@ class ContinuousBatchingEngine:
             shared, private = self._pages_shared, self._pages_private
             pairs, hit = self._expert_pairs, self._experts_hit
             layers = self._moe_layers_ticked
-        if self.paged:
-            out["pool"] = dict(self.page_pool.stats)
-            out["pages_free"] = self.page_pool.pages_free
-            out["pool_pages"] = self.page_pool.n_pages
-            out["prefill_chunks"] = chunks
-            out["pages_shared"] = shared
-            out["pages_private"] = private
-            out["kv_bytes_per_token"] = self._kv_bytes_per_token
-            out["expert_pairs"] = pairs
-            out["experts_hit"] = hit
-            out["moe_layers_ticked"] = layers
-            if self.prefix_cache is not None:
-                out["prefix"] = dict(self.prefix_cache.stats)
+        out["pool"] = dict(self.page_pool.stats)
+        out["pages_free"] = self.page_pool.pages_free
+        out["pool_pages"] = self.page_pool.n_pages
+        out["prefill_chunks"] = chunks
+        out["pages_shared"] = shared
+        out["pages_private"] = private
+        out["kv_bytes_per_token"] = self._kv_bytes_per_token
+        out["expert_pairs"] = pairs
+        out["experts_hit"] = hit
+        out["moe_layers_ticked"] = layers
+        if self.prefix_cache is not None:
+            out["prefix"] = dict(self.prefix_cache.stats)
         if self.registry is not None:
             out["adapter"] = dict(self.registry.stats)
         return out
@@ -1383,7 +1194,6 @@ class ContinuousBatchingEngine:
                     self._pending_params = None
                     if self.prefix_cache is not None:
                         self.prefix_cache.clear()
-                    self._on_swap()
                     swap_pending = False
                     self._cond.notify_all()
                 retry_parked = bool(self._parked) and not swap_pending
@@ -1430,8 +1240,7 @@ class ContinuousBatchingEngine:
             tracer.counter("serve.queue_depth",
                            self._waiting.qsize() + len(self._parked))
 
-        if self.paged:
-            self._prefill_tick()
+        self._prefill_tick()
         # who is in the next tick is decided without the last one's
         # tokens: a slot whose budget or buffer ends with a tick already
         # launched has no steps left and waits, live, for the read-back
@@ -1460,20 +1269,19 @@ class ContinuousBatchingEngine:
         if rolled is not None:   # counter emits outside _stats_lock
             tracer.counter("serve.tokens_per_s", rolled[0] / (now - t0))
             tracer.counter("serve.tokens_total", rolled[1])
-        if self.paged:
-            with self._stats_lock:
-                shared = self._pages_shared
-                tot = shared + self._pages_private
-                chunks = self._chunks_total
-            tracer.counter("serve.kv_pages_free", self.page_pool.pages_free)
-            tracer.counter("serve.kv_page_hit_rate",
-                           shared / tot if tot else 0.0)
-            tracer.counter("serve.prefill_chunks", chunks)
-            tracer.counter("serve.kv_bytes_per_token",
-                           self._kv_bytes_per_token)
-            if self._moe_layers:
-                tracer.counter("serve.expert_load_max",
-                               self._expert_load_max)
+        with self._stats_lock:
+            shared = self._pages_shared
+            tot = shared + self._pages_private
+            chunks = self._chunks_total
+        tracer.counter("serve.kv_pages_free", self.page_pool.pages_free)
+        tracer.counter("serve.kv_page_hit_rate",
+                       shared / tot if tot else 0.0)
+        tracer.counter("serve.prefill_chunks", chunks)
+        tracer.counter("serve.kv_bytes_per_token",
+                       self._kv_bytes_per_token)
+        if self._moe_layers:
+            tracer.counter("serve.expert_load_max",
+                           self._expert_load_max)
         if self._store_mode:
             st = self.registry.stats
             tracer.counter("serve.adapter_cache_hits", st["cache_hits"])
@@ -1485,23 +1293,17 @@ class ContinuousBatchingEngine:
                            st["cache_misses"] / tot if tot else 0.0)
 
     def _tick_span(self, tracer, live, tracing: bool):
-        """The ``serve.tick`` span both engines' ``_dispatch`` open;
-        what needs a sum over the slots is summed only when tracing."""
+        """The ``serve.tick`` span ``_dispatch`` opens; what needs a sum
+        over the slots is summed only when tracing."""
         return tracer.span(
             "serve.tick", cat="engine", live=len(live),
             live_kv_tokens=(sum(self._slots[i].pos for i in live)
                             if tracing else None))
 
-    def _delivered(self, live) -> int:
-        """Tokens the ``live`` slots' requests have received so far (a
-        slot keeps its count until its next admission): the difference
-        over a tick is what the tick emitted."""
-        return sum(self._slots[i].out_tokens for i in live)
-
     def _dispatch(self, live):
-        """One device tick for the slots with a lane in it (overridden by
-        the speculative engine), launched before the tick before it is
-        read: ``stage`` and ``dispatch`` put tick k on the device's queue,
+        """One device tick for the slots with a lane in it, launched
+        before the tick before it is read: ``stage`` and ``dispatch`` put
+        tick k on the device's queue,
         then ``readback``, ``emit`` and ``free`` serve dispatch k-1 (its
         tokens and the first tokens of its pass's final chunks) while the
         device runs k.  The slot state stays on the device from program to
@@ -1513,7 +1315,6 @@ class ContinuousBatchingEngine:
             with tracer.span("serve.tick.stage", cat="engine"):
                 self._sync_rows()
             with tracer.span("serve.tick.dispatch", cat="engine"):
-                kv = self._pool if self.paged else self._caches
                 if self.registry is not None:
                     # snapshot + dispatch under the registry lock so a
                     # concurrent register()'s donated row write cannot
@@ -1521,16 +1322,12 @@ class ContinuousBatchingEngine:
                     # launch (the dispatch itself is async and fast;
                     # registration is the rare path)
                     with self.registry.lock:
-                        toks, kv, self._dev = self._step(
-                            self.raw_params, self.registry.bank, kv,
-                            self._dev)
+                        toks, self._pool, self._dev = self._step(
+                            self.raw_params, self.registry.bank,
+                            self._pool, self._dev)
                 else:
-                    toks, kv, self._dev = self._step(
-                        self.raw_params, kv, self._dev)
-                if self.paged:
-                    self._pool = kv
-                else:
-                    self._caches = kv
+                    toks, self._pool, self._dev = self._step(
+                        self.raw_params, self._pool, self._dev)
                 lanes = []
                 for i in live:
                     s = self._slots[i]
@@ -1635,228 +1432,3 @@ class ContinuousBatchingEngine:
                 if first_got[0].size > 1:
                     args["prefill_expert_pairs"] = prefill_pairs
             span.set(**args)
-
-
-class SpeculativeBatchingEngine(ContinuousBatchingEngine):
-    """Continuous batching × speculative decoding (greedy-only).
-
-    Every tick runs ONE fused device program: a vmapped draft
-    catch-up+propose block (k tokens per slot) followed by a vmapped
-    target verify block — so each live slot advances up to k+1 tokens
-    per dispatch at full acceptance, and the expensive model runs one
-    (k+1)-token forward per slot per tick regardless of acceptance.
-    Output is bit-identical to the non-speculative engine / single-request
-    ``generate`` (the draft only changes how many target forwards are
-    spent — see :mod:`fedml_tpu.serving.speculative`).
-
-    Cache-overrun discipline: verify/propose blocks write up to position
-    ``buf_len + k`` (positions past a rejection self-heal, per the
-    speculative module's argument), so both models must be built with
-    ``max_seq_len >= buf_len + k + 1`` — asserted at construction instead
-    of silently clamping writes (which would corrupt canonical K/V).
-    """
-
-    def __init__(self, model, params, draft_model, draft_params,
-                 slots: int = 4, buf_len: int = 256, k: int = 4,
-                 prefix_cache_slots: int = 0,
-                 prefix_max_tail: int = TAIL_BLOCK,
-                 hist_labels: int = 8,
-                 slo_rules: Optional[List[Dict[str, Any]]] = None):
-        self.k = int(k)
-        assert self.k >= 1
-        for m, name in ((model, "model"), (draft_model, "draft_model")):
-            if getattr(getattr(m, "cfg", None), "kv_page_tokens", 0):
-                raise PagedKVUnsupportedError(
-                    f"{name} is built with kv_page_tokens="
-                    f"{m.cfg.kv_page_tokens}: speculative decoding needs "
-                    "contiguous per-slot caches (the draft/target verify "
-                    "blocks write multi-token windows that would corrupt "
-                    "a shared page pool) — use ContinuousBatchingEngine "
-                    "for paged serving, or a dense model here")
-            msl = getattr(getattr(m, "cfg", None), "max_seq_len", None)
-            if msl is None:
-                raise ValueError(
-                    f"{name} has no cfg.max_seq_len — cannot prove the "
-                    "speculative block writes stay in-bounds (a clamped "
-                    "write would silently corrupt canonical K/V)")
-            if msl < buf_len + self.k + 1:
-                raise ValueError(
-                    f"{name}.cfg.max_seq_len={msl} < buf_len+k+1="
-                    f"{buf_len + self.k + 1}: speculative blocks would "
-                    "clamp their cache writes")
-        self.draft_model = draft_model
-        self.raw_draft = _unwrap_params(draft_params)
-        self._pending_draft = None
-        self._hist: Dict[int, List[int]] = {}
-        self._fds = np.zeros(int(slots), np.int32)
-        # this engine stages every tick from the host: how far a slot
-        # advances is the acceptance count it reads back, so it cannot
-        # launch ahead and carries no slot state on the device
-        self._toks = np.zeros(int(slots), np.int32)
-        self._poss = np.zeros(int(slots), np.int32)
-        super().__init__(model, params, slots=slots, buf_len=buf_len,
-                         top_k=0, horizon=1,
-                         prefix_cache_slots=prefix_cache_slots,
-                         prefix_max_tail=prefix_max_tail,
-                         hist_labels=hist_labels, slo_rules=slo_rules)
-
-        from ..llm.quantization import dequantize_params, weight_dtype
-        t_wdtype = weight_dtype(model)
-        d_wdtype = weight_dtype(draft_model)
-        k_ = self.k
-
-        self._d_prefill, _, _ = _build_cached_decode(draft_model, 0, 1.0)
-        dummy = jnp.zeros((1, self.buf_len), jnp.int32)
-        _, dcache0 = self._d_prefill(self.raw_draft, None, dummy,
-                                     jnp.int32(1),
-                                     jax.random.PRNGKey(0), jnp.float32(0.0))
-        self._d_caches = jax.tree_util.tree_map(
-            lambda c: jnp.zeros((self.n_slots,) + c.shape, c.dtype), dcache0)
-
-        from .speculative import propose_block, verify_greedy_block
-
-        @jax.jit
-        def spec_tick(draw, raw, d_caches, t_caches, sync_bufs, sync_lens,
-                      fds, curs, poss):
-            # one fused program per tick: vmapped draft propose (shared
-            # body: speculative.propose_block) + vmapped target verify
-            draw = dequantize_params(draw, d_wdtype)
-            raw = dequantize_params(raw, t_wdtype)
-            d_tokens, d_caches = jax.vmap(
-                lambda cache, sync, slen, fd: propose_block(
-                    draft_model, draw, cache, sync, slen, fd, k_)
-            )(d_caches, sync_bufs, sync_lens, fds)
-            blocks = jnp.concatenate([curs[:, None], d_tokens], axis=1)
-            greedy, t_caches = jax.vmap(
-                lambda cache, block, pos: verify_greedy_block(
-                    model, raw, cache, block, pos)
-            )(t_caches, blocks, poss)
-            return d_tokens, greedy, d_caches, t_caches
-
-        self._spec_tick = spec_tick
-        # observability: target forwards vs tokens out (acceptance rate)
-        self.stats = {"target_block_forwards": 0, "proposed": 0,
-                      "accepted": 0}
-
-    def update_params(self, params, draft_params=None, wait: bool = True,
-                      timeout: float = 60.0) -> None:
-        """Swap target (and optionally draft) weights after the in-flight
-        drain.  A stale draft only lowers the acceptance rate — greedy
-        verification against the target keeps outputs exact — so the
-        draft swap is optional."""
-        if draft_params is not None:
-            with self._cond:
-                self._pending_draft = _unwrap_params(draft_params)
-        super().update_params(params, wait=wait, timeout=timeout)
-
-    def _on_swap(self) -> None:
-        if self._pending_draft is not None:
-            self.raw_draft = self._pending_draft
-            self._pending_draft = None
-
-    def submit(self, prompt_ids, max_new_tokens: int = 64,
-               temperature: float = 0.0, seed: int = 0, eos_id=None,
-               adapter: Optional[str] = None,
-               traceparent: Optional[str] = None):
-        if float(temperature) != 0.0:
-            raise ValueError("SpeculativeBatchingEngine is greedy-only "
-                             "(temperature 0); use ContinuousBatchingEngine "
-                             "for sampled requests")
-        # single-tenant: the base class rejects non-None adapters (no
-        # registry), so the kwarg just rides through for signature parity
-        return super().submit(prompt_ids, max_new_tokens=max_new_tokens,
-                              temperature=0.0, seed=seed, eos_id=eos_id,
-                              adapter=adapter, traceparent=traceparent)
-
-    def _admit(self, req, slot):
-        self._hist[slot] = list(req["prompt_ids"])
-        super()._admit(req, slot)  # target prefill + first emitted token
-        ids = req["prompt_ids"]
-        n = len(ids)
-        buf = np.zeros((1, self.buf_len), np.int32)
-        buf[0, :n] = ids
-        _, dcache = self._d_prefill(self.raw_draft, None, jnp.asarray(buf),
-                                    jnp.int32(n), jax.random.PRNGKey(0),
-                                    jnp.float32(0.0))
-        self._d_caches = self._insert(self._d_caches, dcache,
-                                      jnp.int32(slot))
-        self._fds[slot] = n
-
-    def _emit(self, i: int, tok: int) -> bool:
-        s = self._slots[i]
-        before = s.remaining
-        cont = super()._emit(i, tok)
-        if s.remaining < before:  # token was actually delivered
-            self._hist[i].append(tok)
-        return cont
-
-    def _dispatch(self, live):
-        tracer = get_tracer()
-        tracing = tracer.enabled
-        with self._tick_span(tracer, live, tracing) as tick:
-            before = self._delivered(live) if tracing else 0
-            with tracer.span("serve.tick.stage", cat="engine"):
-                kp1 = self.k + 1
-                sync_bufs = np.zeros((self.n_slots, kp1), np.int32)
-                sync_lens = np.ones(self.n_slots, np.int32)
-                for i in live:
-                    s = self._slots[i]
-                    hist = self._hist[i]
-                    self._toks[i] = s.cur_tok
-                    self._poss[i] = s.pos
-                    sync = hist[self._fds[i]: s.pos + 1]
-                    assert 1 <= len(sync) <= kp1, (len(sync), self.k)
-                    sync_bufs[i, :len(sync)] = sync
-                    sync_lens[i] = len(sync)
-                state = (jnp.asarray(sync_bufs), jnp.asarray(sync_lens),
-                         jnp.asarray(self._fds), jnp.asarray(self._toks),
-                         jnp.asarray(self._poss))
-            with tracer.span("serve.tick.dispatch", cat="engine"):
-                d_tokens, greedy, self._d_caches, self._caches = \
-                    self._spec_tick(self.raw_draft, self.raw_params,
-                                    self._d_caches, self._caches, *state)
-            with tracer.span("serve.tick.readback", cat="engine"):
-                d_host = np.asarray(d_tokens)
-                g_host = np.asarray(greedy)
-            self.stats["target_block_forwards"] += len(live)
-            with tracer.span("serve.tick.emit", cat="engine") as emit:
-                emit.set(finished=self._accept(live, d_host, g_host))
-            with tracer.span("serve.tick.free", cat="engine"):
-                del state, d_tokens, greedy      # see the base engine's tick
-            if tracing:
-                tick.set(tokens=self._delivered(live) - before)
-
-    def _accept(self, live, d_host, g_host) -> int:
-        """Emit each live slot's accepted draft tokens and the target's
-        own next one; returns how many slots finished."""
-        finished = 0
-        for i in live:
-            s = self._slots[i]
-            self._fds[i] = s.pos + 1  # draft confirmed through old cur
-            for j in range(self.k):
-                # count only proposals actually examined — eos/budget can
-                # truncate the acceptance loop mid-block, and charging the
-                # full k would understate real draft acceptance
-                self.stats["proposed"] += 1
-                s.drafts_proposed += 1
-                dj, gj = int(d_host[i, j]), int(g_host[i, j])
-                s.pos += 1
-                if dj != gj:
-                    # first disagreement: the target's own token replaces it
-                    if not self._emit(i, gj):
-                        self._finish(i)
-                        finished += 1
-                    break
-                self.stats["accepted"] += 1
-                s.drafts_accepted += 1
-                if not self._emit(i, dj):
-                    self._finish(i)
-                    finished += 1
-                    break
-            else:
-                # every proposal accepted: the target's continuation token
-                s.pos += 1
-                if not self._emit(i, int(g_host[i, self.k])):
-                    self._finish(i)
-                    finished += 1
-        return finished

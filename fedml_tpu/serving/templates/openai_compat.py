@@ -182,9 +182,8 @@ TAIL_BLOCK = 32
 def _replay_tail(step_fn, tail_fn, cache, buf_j, ids, start, n, max_seq,
                  key, temp):
     """Replay prompt positions ``start..n-1`` onto a cached KV state —
-    the ONE shared implementation of the prefix-hit replay discipline
-    (generate() and the batching engine's admission both use it, so the
-    correctness guards cannot diverge).  Multi-token tails that fit the
+    :func:`generate`'s prefix-hit replay discipline.  Multi-token tails
+    that fit the
     fixed block AND the context window replay as one tail_block dispatch;
     everything else (exact hits, tails longer than TAIL_BLOCK under a
     custom admission bound, the window's very end) takes the bounded
@@ -451,13 +450,14 @@ class OpenAICompatServer:
                  model_name: str = "fedml-tpu-llm", host: str = "127.0.0.1",
                  port: int = 0, buf_len: int = 256, model=None,
                  batch_slots: int = 0, draft_model=None, draft_params=None,
-                 decode_horizon: int = 1, spec_k: int = 4,
+                 decode_horizon: int = 1,
                  prefix_cache_slots: int = 0,
                  prefix_max_tail: int = TAIL_BLOCK,
                  adapters=None, adapter_slots: int = 0,
                  metrics_port: Optional[int] = None,
                  slo_rules: Optional[List[dict]] = None,
-                 kv_page_tokens: int = 0, kv_pool_pages: int = 0,
+                 kv_page_tokens: Optional[int] = None,
+                 kv_pool_pages: int = 0,
                  prefill_chunk_tokens: int = 0, prefill_lanes: int = 1,
                  adapter_cache_slots: int = 0,
                  adapter_store_dir: Optional[str] = None):
@@ -475,8 +475,9 @@ class OpenAICompatServer:
         round-trips; streaming granularity coarsens to H tokens.
 
         Memory-plane knobs (engine mode only; docs/SERVING.md):
-        ``kv_page_tokens`` > 0 switches the engine to the paged KV cache
-        (``kv_pool_pages`` sizes the pool, 0 = auto) with chunked prefill
+        ``kv_page_tokens`` is the page size of the engine's KV page pool
+        (None = the engine's default; ``kv_pool_pages`` sizes the pool,
+        0 = auto), a prompt enters in chunks
         (``prefill_chunk_tokens``/``prefill_lanes``);
         ``adapter_cache_slots`` > 0 demotes the adapter bank to an N-row
         cache over a host/disk store (``adapter_store_dir`` spills cold
@@ -497,8 +498,8 @@ class OpenAICompatServer:
         self.slo_rules = slo_rules
         self.buf_len = buf_len
         self.model = model
-        # speculative decode (requires model + a draft; greedy requests
-        # only — sampled requests fall back to the plain paths)
+        # speculative decode (requires model + a draft, and no engine;
+        # greedy requests only — sampled requests take the plain paths)
         self.draft_model = draft_model
         self.draft_params = draft_params
         if draft_model is not None and model is None:
@@ -508,14 +509,10 @@ class OpenAICompatServer:
             raise ValueError("draft_model requires draft_params")
         # prefix_cache_slots > 0 (requires ``model``): reuse prefill KV
         # for shared prompt prefixes.  Non-engine path: one PrefixCache
-        # consulted by generate(); engine path: the engine builds its own
-        # and consults it at admission (self.prefix_cache aliases it
-        # below so stats stay reachable either way — but the sampled
-        # fall-through around a greedy-only engine does NOT use it:
-        # update_params() swaps the engine only after its in-flight
-        # drain, so MID-SWAP the engine's tree and self.params diverge
-        # and sharing one cache would ping-pong invalidation between the
-        # two identities; separate caches keep each path self-consistent).
+        # consulted by generate(); engine path: the engine shares pages
+        # through a cache of its own, consulted at admission
+        # (self.prefix_cache aliases it below so stats stay reachable
+        # either way; the fall-through around the engine uses none).
         self.prefix_cache = None
         if prefix_cache_slots and model is None:
             raise ValueError("prefix_cache_slots requires `model` "
@@ -542,18 +539,23 @@ class OpenAICompatServer:
         self.adapters = None
         self._zero_lora = None
         self.registry = None
-        # paged-KV / adapter-cache knobs are engine-mode only (the memory
-        # plane they reshape IS the engine's) — reject up front instead of
-        # silently serving dense
-        if (kv_page_tokens or adapter_cache_slots) and not batch_slots:
+        # page-size / adapter-cache knobs are engine-mode only (the memory
+        # plane they shape IS the engine's) — reject up front instead of
+        # silently ignoring them
+        if (kv_page_tokens is not None or adapter_cache_slots) \
+                and not batch_slots:
             raise ValueError(
-                "kv_page_tokens / adapter_cache_slots reshape the "
+                "kv_page_tokens / adapter_cache_slots shape the "
                 "batching engine's memory plane — set batch_slots too")
-        if kv_page_tokens and draft_model is not None:
+        if batch_slots and draft_model is not None:
             from ..batching import PagedKVUnsupportedError
             raise PagedKVUnsupportedError(
-                "kv_page_tokens with draft_model: the speculative engine "
-                "needs contiguous per-slot caches — drop one of the two")
+                "draft_model with batch_slots: the batching engine's KV "
+                "cache is a page pool, and the speculative verify blocks "
+                "write multi-token windows into contiguous per-request "
+                "caches — speculation serves single requests "
+                "(serving/speculative.py::speculative_generate: build the "
+                "server without batch_slots)")
         if adapter_cache_slots and adapter_slots:
             raise ValueError(
                 "adapter_cache_slots and adapter_slots are mutually "
@@ -565,11 +567,6 @@ class OpenAICompatServer:
             if getattr(getattr(model, "cfg", None), "lora_rank", 0) <= 0:
                 raise ValueError("adapters require a lora_rank>0 model "
                                  "config (LoRADense layers)")
-            if batch_slots and draft_model is not None:
-                raise ValueError(
-                    "adapters and the speculative batching engine are "
-                    "incompatible (it is single-tenant greedy) — drop "
-                    "draft_model or batch_slots")
             if batch_slots and not adapter_cache_slots:
                 from ..adapters import AdapterRegistry
                 cap = int(adapter_slots) or len(adapters or {}) + 8
@@ -593,55 +590,33 @@ class OpenAICompatServer:
                 self._zero_lora = jax.tree_util.tree_map(
                     lambda s: jnp.zeros(s.shape, s.dtype), shapes)
         self._engine = None
-        self._engine_greedy_only = False
         if batch_slots:
             if model is None:
                 raise ValueError(
                     "batch_slots requires `model` (a flax module supporting "
                     "decode=True) — the batching engine is KV-cache based")
-            if draft_model is not None:
-                # flagship serving config: speculative continuous batching
-                # for greedy traffic; sampled requests fall through to the
-                # single-request cached path below.  Requires
-                # cfg.max_seq_len >= buf_len + spec_k + 1 (block slack).
-                if int(decode_horizon) > 1:
-                    raise ValueError(
-                        "decode_horizon and draft_model are mutually "
-                        "exclusive: the speculative engine advances up to "
-                        "spec_k+1 tokens per dispatch already")
-                from ..batching import SpeculativeBatchingEngine
-                self._engine = SpeculativeBatchingEngine(
-                    model, params, draft_model, draft_params,
-                    slots=int(batch_slots), buf_len=buf_len,
-                    k=int(spec_k),
-                    prefix_cache_slots=int(prefix_cache_slots),
-                    prefix_max_tail=int(prefix_max_tail),
-                    slo_rules=slo_rules)
-                self.prefix_cache = self._engine.prefix_cache
-                self._engine_greedy_only = True
-            else:
-                from ..batching import ContinuousBatchingEngine
-                self._engine = ContinuousBatchingEngine(
-                    model, params, slots=int(batch_slots), buf_len=buf_len,
-                    horizon=int(decode_horizon),
-                    prefix_cache_slots=int(prefix_cache_slots),
-                    prefix_max_tail=int(prefix_max_tail),
-                    adapter_registry=self.registry,
-                    slo_rules=slo_rules,
-                    kv_page_tokens=int(kv_page_tokens),
-                    kv_pool_pages=int(kv_pool_pages),
-                    prefill_chunk_tokens=int(prefill_chunk_tokens),
-                    prefill_lanes=int(prefill_lanes),
-                    adapter_cache_slots=int(adapter_cache_slots),
-                    adapter_store_dir=adapter_store_dir)
-                self.prefix_cache = self._engine.prefix_cache
-                if adapter_cache_slots:
-                    # the engine owns the store-backed registry; alias it
-                    # so add_adapter/evict_adapter and the fall-through
-                    # path route through the same cache
-                    self.registry = self._engine.registry
-                    for name, tree in (adapters or {}).items():
-                        self.registry.register(name, tree)
+            from ..batching import ContinuousBatchingEngine
+            page = ({} if kv_page_tokens is None
+                    else {"kv_page_tokens": int(kv_page_tokens)})
+            self._engine = ContinuousBatchingEngine(
+                model, params, slots=int(batch_slots), buf_len=buf_len,
+                horizon=int(decode_horizon),
+                prefix_cache_slots=int(prefix_cache_slots),
+                adapter_registry=self.registry,
+                slo_rules=slo_rules,
+                kv_pool_pages=int(kv_pool_pages),
+                prefill_chunk_tokens=int(prefill_chunk_tokens),
+                prefill_lanes=int(prefill_lanes),
+                adapter_cache_slots=int(adapter_cache_slots),
+                adapter_store_dir=adapter_store_dir, **page)
+            self.prefix_cache = self._engine.prefix_cache
+            if adapter_cache_slots:
+                # the engine owns the store-backed registry; alias it
+                # so add_adapter/evict_adapter and the fall-through
+                # path route through the same cache
+                self.registry = self._engine.registry
+                for name, tree in (adapters or {}).items():
+                    self.registry.register(name, tree)
         self._server: Optional[ThreadingHTTPServer] = None
 
     # -- request handling --------------------------------------------------
@@ -719,8 +694,7 @@ class OpenAICompatServer:
                           else req.get("top_p"))
         wants_filters = (temp != 0.0
                          and (req_top_k > 0 or req_top_p < 1.0))
-        if self._engine is not None and not wants_filters and not (
-                self._engine_greedy_only and temp != 0.0):
+        if self._engine is not None and not wants_filters:
             try:
                 q = self._engine.submit(
                     tok.encode(prompt),
@@ -965,8 +939,9 @@ class OpenAICompatServer:
         ``self.params`` and clears the prefix cache eagerly (its strong
         params ref would otherwise keep the old tree + stale KV resident
         until the next request).  ``draft_params`` also swaps the
-        speculative draft (optional: a stale draft only lowers acceptance
-        rate; greedy verification keeps outputs exact).  ``timeout``
+        speculative draft of a server built with ``draft_model``
+        (optional: a stale draft only lowers acceptance rate; greedy
+        verification keeps outputs exact).  ``timeout``
         bounds the engine drain — size it to the slowest legal request
         (roughly ``buf_len`` x per-dispatch latency); on ``TimeoutError``
         NOTHING has been mutated, so the caller can simply retry.
@@ -981,11 +956,7 @@ class OpenAICompatServer:
         # old version — assigning self.params before the engine landed
         # would split the sampled fall-through (new) from the engine (old)
         if self._engine is not None:
-            if hasattr(self._engine, "raw_draft"):
-                self._engine.update_params(params, draft_params=draft_params,
-                                           timeout=timeout)
-            else:
-                self._engine.update_params(params, timeout=timeout)
+            self._engine.update_params(params, timeout=timeout)
         # the engine drain above can block for seconds — only the final
         # pointer swap runs under _swap_lock, paired with the coherent
         # snapshot HTTP workers take at the top of _complete

@@ -180,6 +180,7 @@ class FedBuffAPI(FedAvgAPI):
         dev_x, dev_y = self._dev_x, self._dev_y
 
         health = self._health
+        floors = self.health_monitor.config if health else None
 
         def dispatch_fn(state, idx, mask, w, key, c_stacked):
             x = jnp.take(dev_x, idx, axis=0)
@@ -201,11 +202,19 @@ class FedBuffAPI(FedAvgAPI):
                 # delta (no post-apply params exist yet); rows land in
                 # the buffer like every other lane, staleness joins at
                 # apply from the buffer's tau lane
-                rows["__health"] = federated.client_health_stats(
+                h = federated.client_health_stats(
                     state.global_params, outs.params,
                     federated.cohort_mean_delta(state.global_params,
                                                 outs.params, w),
                     outs.loss, w)
+                # cosine and loss_delta are relative to THIS generation's
+                # mean, so they are standardised against this generation
+                # too; the monitor takes the z lanes as they come
+                h["z_cosine"] = federated.cohort_robust_z(
+                    h["cosine"], w, floors.cosine_floor)
+                h["z_loss_delta"] = federated.cohort_robust_z(
+                    h["loss_delta"], w, floors.loss_floor)
+                rows["__health"] = h
             return rows, outs.new_client_state
 
         return jax.jit(dispatch_fn)

@@ -358,15 +358,20 @@ def run_silo_federation(args, device, dataset, model):
 def _collect_quorum(ep, guard, round_idx, expected, quorum, deadline_s,
                     recv_timeout_s, tracer):
     """Collect SILO_PARTIAL uploads for ``round_idx`` until every live
-    expected silo arrived, or — once ``deadline_s`` has elapsed — until
-    at least ``quorum`` have.  Lease-dead ranks leave the expected set
+    expected silo arrived, or — once ``deadline_s`` has elapsed since the
+    round's FIRST partial arrived — until at least ``quorum`` have.  The
+    deadline bounds how far a straggler may trail the fastest silo, not
+    how long a round may take: what every silo pays alike (round 0
+    compiles the silo program on each rank) is not straggling, and from
+    the round's open it raced the compiler on a slow or loaded host.
+    Lease-dead ranks leave the expected set
     mid-wait (and re-enter next round if they heal).  Returns
     ``(got, live)``; raises ``RuntimeError`` when the quorum can never
     be met and ``TimeoutError`` when nothing arrives for
     ``recv_timeout_s``."""
     got = {}
     live = set(expected)
-    t_open = time.monotonic()
+    t_first = None      # arrival of the round's first partial
     last_arrival = time.monotonic()
     while True:
         if guard is not None:
@@ -380,7 +385,7 @@ def _collect_quorum(ep, guard, round_idx, expected, quorum, deadline_s,
         if not waiting:
             break
         if deadline_s > 0 and len(got) >= quorum \
-                and time.monotonic() - t_open >= deadline_s:
+                and time.monotonic() - t_first >= deadline_s:
             log.warning(
                 "round %d: quorum close at deadline with %d/%d silos "
                 "(missing %s)", round_idx, len(got), len(expected),
@@ -405,6 +410,8 @@ def _collect_quorum(ep, guard, round_idx, expected, quorum, deadline_s,
             tracer.counter("comm.stale_partials", 1.0)
             continue
         got.setdefault(int(msg.get("silo")), msg)
+        if t_first is None:
+            t_first = last_arrival
     return got, live
 
 

@@ -149,39 +149,6 @@ def test_bench_serve_mt_quick(monkeypatch):
     assert load["tokens_per_s"] > 0
 
 
-def test_bench_serve_paged_quick(monkeypatch):
-    """FEDML_SERVE_PAGED_QUICK smoke (fedkv, docs/SERVING.md): bench.py
-    --serve-paged runs the paged memory plane green end-to-end — the
-    paged engine sustains >= 1.5x the dense engine's concurrently live
-    slots at EQUAL KV HBM, zero steady-state recompiles under page
-    churn, every page back on the free list after the burst drains, and
-    the adapter-scale sweep holding the bank's resident bytes flat
-    while hit rate and latency stay measured (the 10k-adapter scale and
-    the pinned curves come from the full-size BENCH_r16 run)."""
-    bench = _import_bench()
-    monkeypatch.setenv("FEDML_SERVE_PAGED_QUICK", "1")
-    out = bench.serve_paged_bench()
-    assert out["quick"] is True
-    assert out["paged_vs_dense_slots"] >= 1.5
-    assert out["peak_live_dense"] == out["dense_slots_equal_hbm"]
-    assert out["steady_state_recompiles"] == 0
-    assert out["pages_leaked"] == 0
-    assert out["dense_tok_s"] > 0 and out["paged_tok_s"] > 0
-    lat = out["latency_paged"]
-    assert lat["e2e_p99_ms"] >= lat["ttft_p50_ms"] > 0
-    assert out["kv_stats"]["prefill_chunks"] > 0
-    # flat-HBM pin: the bank never grows with the registered population
-    assert out["bank_hbm_flat_across_scales"] == 1
-    sweep = out["adapter_sweep"]
-    assert len(sweep) == 2
-    for row in sweep.values():
-        assert row["tok_s"] > 0
-        assert 0.0 <= row["hit_rate"] <= 1.0
-        assert row["bank_rows"] == 4
-    # the long tail at the larger scale must actually churn the cache
-    assert sweep[str(out["adapters_max_scale"])]["cache_evictions"] > 0
-
-
 def test_bench_serve_slo_quick(monkeypatch):
     """FEDML_SLO_QUICK smoke (fedslo, docs/OBSERVABILITY.md): bench.py
     --serve-slo runs the serving-SLO plane green end-to-end — telemetry
@@ -307,7 +274,6 @@ def test_bench_verify_quick(monkeypatch):
     assert out["violations"] == 0
     progs = out["programs"]
     assert set(progs) == {"sp_round", "mesh1d_scatter",
-                          "serving_insert_cache",
                           "serving_paged_prefill_chunk"}
     mesh = progs["mesh1d_scatter"]
     assert mesh["num_partitions"] == 8

@@ -345,17 +345,62 @@ def test_label_flip_detected_on_mesh_scatter():
 
 
 def test_label_flip_detected_on_fedbuff_with_staleness_lane():
+    """Twelve applies of a 32-row buffer fed by three generations in
+    flight: 384 arrivals over 64 clients, the sync tests' cohort.  (With 16
+    a generation, 192 arrivals, client 2 arrived four times, all by apply 6,
+    while a flip still scored at the flag line, 5.4 to 6.0 against 5.5, and
+    never again; on other seeds a flipped client never arrived at all.  A
+    client that does not arrive cannot be flagged: the population has to
+    carry the statement.)  Each row is standardised within the generation
+    it was measured against (``cohort_robust_z``): pooled over the buffer,
+    a minority generation's benign rows read as direction outliers."""
     api, flipped = _flipped_api(
         "fedbuff", rounds=12, federated_optimizer="fedbuff",
-        client_num_per_round=16, async_buffer_k=16,
+        client_num_per_round=32, async_buffer_k=32,
         async_latency_median_s=5.0, async_latency_sigma=1.2,
         async_inflight_gens=3, frequency_of_the_test=4)
     api.train()
-    precision, recall = _precision_recall(api.health_monitor.flagged(),
-                                          flipped)
-    assert precision >= 0.9 and recall >= 0.9
+    mon = api.health_monitor
+    assert min(mon._clients[c].obs for c in flipped) >= 3
+    precision, recall = _precision_recall(mon.flagged(), flipped)
+    assert precision >= 0.9 and recall >= 0.9, (mon.flagged(), flipped)
     # real staleness flowed through the buffer's tau lane into the gauges
     assert api.health_monitor.gauges()["health.staleness_p99"] >= 1.0
+
+
+@pytest.mark.parametrize("pad", [0, 3])
+def test_cohort_robust_z_is_the_monitors_robust_z(pad):
+    """The in-trace standardisation of a generation equals the monitor's
+    own ``robust_z`` over the real rows; pad rows read 0."""
+    import jax.numpy as jnp
+    from fedml_tpu.core import federated
+    from fedml_tpu.obs.health import robust_z
+    rng = np.random.default_rng(pad)
+    vals = rng.normal(0.5, 0.2, 11 + pad).astype(np.float32)
+    vals[4] = -0.6
+    w = np.r_[np.ones(11, np.float32), np.zeros(pad, np.float32)]
+    got = np.asarray(federated.cohort_robust_z(jnp.asarray(vals), w, 0.08))
+    np.testing.assert_allclose(got[:11], robust_z(vals[:11].tolist(), 0.08),
+                               rtol=1e-5, atol=1e-5)
+    assert not got[11:].any() and got[:11].argmin() == 4 and got[4] < -3
+
+
+def test_monitor_takes_a_generations_own_z_lanes():
+    """A buffer of two generations: five rows measured against a mean that
+    flipped members pulled (benign cosine -0.2) beside eleven of a clean
+    generation (0.75).  Pooled, the five are direction outliers; with the
+    lanes the engine standardised per generation nobody is."""
+    ids = list(range(16))
+    cos = [-0.2] * 5 + [0.75] * 11
+    base = {"update_norm": [1.0] * 16, "cosine": cos,
+            "loss_delta": [0.0] * 16, "weight": [1.0] * 16}
+    pooled, lanes = HealthMonitor(), HealthMonitor()
+    for r in range(3):
+        pooled.observe_round(r, ids, base)
+        lanes.observe_round(r, ids, dict(base, z_cosine=[0.0] * 16,
+                                         z_loss_delta=[0.0] * 16))
+    assert pooled.flagged() == [0, 1, 2, 3, 4]
+    assert lanes.flagged() == []
 
 
 def test_health_population_rejected_early():

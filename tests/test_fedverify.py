@@ -319,12 +319,24 @@ def test_gate_covers_every_program_family(verified):
     assert blk.census_bytes()["client"] > \
         3 * one.census_bytes()["client"]
     # single-partition programs carry no collectives
-    for name in ("sp_round", "population_p4", "serving_decode_step"):
+    for name in ("sp_round", "population_p4", "serving_paged_decode_step"):
         assert reports[name].collectives == [], name
-    # the serving insert really donates the stacked cache in place
-    ins = reports["serving_insert_cache"]
-    assert ins.donated_params and \
-        ins.donated_params <= ins.aliased_params
+    # the prefill chunk really donates the page pools and the carried slot
+    # state, and writes them in place
+    chunk = reports["serving_paged_prefill_chunk"]
+    assert len(chunk.donated_params) >= 4 and \
+        chunk.donated_params <= chunk.aliased_params
+
+
+def test_manifest_holds_exactly_the_two_serving_programs():
+    """The engine has one KV cache, so it has two compiled citizens: the
+    registry and the committed manifest name the tick and the prefill chunk
+    over the page pool, and no program of a per-slot cache."""
+    serving = ["serving_paged_decode_step", "serving_paged_prefill_chunk"]
+    assert [n for n in fv.PROGRAMS if n.startswith("serving")] == serving
+    pinned = fv.load_manifest()["programs"]
+    assert sorted(n for n in pinned if n.startswith("serving")) == serving
+    assert set(pinned) == set(fv.PROGRAMS)
 
 
 # -- 3. lowering-level mutants ----------------------------------------------

@@ -57,6 +57,35 @@ def assert_tree_close(a, b, atol=2e-5, rtol=1e-4, msg=""):
                                    atol=atol, rtol=rtol, err_msg=msg)
 
 
+def assert_adam_server_close(got, seq, server_lr, msg=""):
+    """``assert_tree_close`` for a FedOpt run, whose band is float32's.
+
+    The vmapped population and the sequential run merge the clients'
+    parameters in a different order: after round 0 they agree to the last
+    bit in most elements and to one ULP (2.4e-7 at |w| in [2, 4)) in the
+    rest.  The server's pseudo-gradient is a difference of such values, so
+    where it is small (1e-4) one ULP is a part in a thousand of it, and the
+    Adam step divides it by its own running size: a ULP of the merged
+    parameters moves the next round's by ``server_lr * ulp / sqrt(nu)``.
+    That term is added to the band element by element, from the sequential
+    run's own second moments; every element with a gradient worth the name
+    is still held to ``rtol=1e-4, atol=2e-5`` (measured here: the one
+    element of 1,960 outside the plain band is 0.2 of this one)."""
+    import optax
+    adam = next(x for x in jax.tree_util.tree_leaves(
+        seq.state.opt_state,
+        is_leaf=lambda n: isinstance(n, optax.ScaleByAdamState))
+        if isinstance(x, optax.ScaleByAdamState))
+    for x, y, nu in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(seq.state.global_params),
+                        jax.tree_util.tree_leaves(adam.nu)):
+        x, y = np.asarray(x), np.asarray(y)
+        band = 2e-5 + 1e-4 * np.abs(y) + \
+            server_lr * np.spacing(np.abs(y)) / (np.sqrt(np.asarray(nu)) + 1e-8)
+        worst = (np.abs(x - y) / band).max()
+        assert worst <= 1.0, (msg, float(worst))
+
+
 # -- 1. primitives ----------------------------------------------------------
 
 def test_broadcast_is_identity_placement():
@@ -235,6 +264,15 @@ POP_ALGS = [
 ]
 
 
+def _assert_member(alg, pop, member, seq):
+    got = fed.population_member(pop.state.global_params, member)
+    msg = f"{alg} member {member}"
+    if alg == "FedOpt":
+        assert_adam_server_close(got, seq, float(seq.args.server_lr), msg)
+    else:
+        assert_tree_close(got, seq.state.global_params, msg=msg)
+
+
 @pytest.mark.parametrize("alg,axes,member0_args", POP_ALGS,
                          ids=[a for a, _, _ in POP_ALGS])
 def test_population_members_match_sequential_runs(alg, axes, member0_args):
@@ -252,8 +290,7 @@ def test_population_members_match_sequential_runs(alg, axes, member0_args):
     seq = make_api(federated_optimizer=alg, **member0_args)
     for r in range(3):
         seq_metrics = seq.train_one_round(r)
-    assert_tree_close(fed.population_member(pop.state.global_params, 0),
-                      seq.state.global_params, msg=f"{alg} member 0")
+    _assert_member(alg, pop, 0, seq)
     np.testing.assert_allclose(losses[0],
                                float(seq_metrics["train_loss"]),
                                atol=2e-5, rtol=1e-4)
@@ -266,8 +303,7 @@ def test_population_members_match_sequential_runs(alg, axes, member0_args):
     seq1 = make_api(federated_optimizer=alg, **{static_name: values[1]})
     for r in range(3):
         seq1.train_one_round(r)
-    assert_tree_close(fed.population_member(pop.state.global_params, 1),
-                      seq1.state.global_params, msg=f"{alg} member 1")
+    _assert_member(alg, pop, 1, seq1)
 
 
 def test_population_seed_axis_gives_distinct_members():

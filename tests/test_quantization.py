@@ -123,7 +123,7 @@ def test_int8_kv_cache_decode_fidelity():
 
 def test_int8_kv_cache_through_batching_engine():
     """kv_cache_dtype="int8" must work through the continuous-batching
-    engine (stacked int8 cache + 3-D scale leaves in insert/step), with
+    engine (int8 page pools with a pool of scales beside each), with
     greedy output identical to the single-request cached generate on the
     same int8-KV model."""
     import jax
@@ -144,8 +144,9 @@ def test_int8_kv_cache_through_batching_engine():
     eng = ContinuousBatchingEngine(model, params, slots=2, buf_len=32,
                                    horizon=4)
     try:
-        leaves = jax.tree_util.tree_leaves(eng._caches)
-        assert any(l.dtype == jnp.int8 for l in leaves)
+        leaves = jax.tree_util.tree_leaves(eng._pool)
+        assert sum(l.dtype == jnp.int8 for l in leaves) == 2 * cfg.n_layers
+        assert len(leaves) == 4 * cfg.n_layers      # k, v and their scales
         for p in ([5, 17, 42], [7, 7, 7, 7]):
             got = eng.generate(p, max_new_tokens=8)
             want = generate(apply_fn, params, p, max_new_tokens=8,

@@ -1,13 +1,14 @@
-"""fedkv (ISSUE 20): the paged serving memory plane — per-layer KV page
+"""fedkv (ISSUE 20): the serving memory plane — per-layer KV page
 pools + block tables, chunked prefill, copy-on-write prefix page
 sharing, and the adapter bank demoted to an N-row cache over the
-fedstore tier.
+fedstore tier.  The page pool is the engine's one KV cache (ISSUE 33).
 
 The engine contracts pinned here:
 
-- paged output is BIT-IDENTICAL to the dense engine (greedy AND
-  sampled, single-stream AND concurrent, incl. multi-token horizons and
-  prompts long enough to exercise chunked prefill);
+- the engine's output is BIT-IDENTICAL to the single-request cached
+  decode, ``generate(model=...)`` over the model's contiguous cache
+  (greedy AND sampled, single-stream AND concurrent, incl. multi-token
+  horizons and prompts long enough to exercise chunked prefill);
 - prefix reuse shares PAGES (refcounts), never copies KV, and every
   page returns to the free list once its sharers drain;
 - page exhaustion parks requests (no deadlock, no corruption) and an
@@ -17,7 +18,8 @@ The engine contracts pinned here:
 - page churn + adapter miss -> evict -> page-in adds ZERO steady-state
   recompiles (block tables are traced data, free-list bookkeeping is
   host-side);
-- the speculative engine refuses paged models with a named error.
+- a page size of 0, a model without a ``LlamaConfig`` and a speculative
+  draft beside ``batch_slots`` are refused by name.
 """
 
 import dataclasses
@@ -36,10 +38,10 @@ from fedml_tpu.llm.model import LlamaConfig, LlamaLM
 from fedml_tpu.serving.adapters import AdapterMissError, AdapterRegistry
 from fedml_tpu.serving.adapter_store import AdapterStore
 from fedml_tpu.serving.batching import (ContinuousBatchingEngine,
-                                        PagedKVUnsupportedError,
-                                        SpeculativeBatchingEngine)
+                                        PagedKVUnsupportedError)
 from fedml_tpu.serving.paged_kv import (PagedBlockPool, PagedPrefixCache,
                                         PageExhaustedError)
+from fedml_tpu.serving.templates.openai_compat import generate
 from fedml_tpu.store.pager import AsyncRowFetcher
 
 BUF = 48
@@ -91,60 +93,137 @@ def _paged(model, params, slots=4, **kw):
                                     buf_len=BUF, **kw)
 
 
+def _ref(model, params, prompt, n, temp=0.0, seed=0, eos=None, lora=None,
+         buf_len=BUF):
+    """The reference of every "bit for bit" test here: the single-request
+    cached decode over the model's contiguous ``decode=True`` cache."""
+    return generate(None, params, prompt, max_new_tokens=n,
+                    temperature=temp, seed=seed, buf_len=buf_len, eos_id=eos,
+                    model=model, lora=lora)
+
+
 # ---------------------------------------------------------------- parity
 
-def test_paged_matches_dense_single_stream(paged_setup):
+def test_engine_matches_generate_single_stream(paged_setup):
     """Greedy + sampled single-stream parity, including a prompt long
     enough (40 tokens, chunk 16) that prefill takes three chunks."""
     _, model, params = paged_setup
-    dense = ContinuousBatchingEngine(model, params, slots=2, buf_len=BUF)
     paged = _paged(model, params, slots=2)
     prompts = [[5, 17, 42], [7], list(range(1, 41)), [60, 2, 9, 9]]
     try:
         for p in prompts:
             for temp, seed in ((0.0, 0), (0.9, 3)):
-                ref = dense.generate(p, max_new_tokens=8,
-                                     temperature=temp, seed=seed)
                 out = paged.generate(p, max_new_tokens=8,
                                      temperature=temp, seed=seed)
-                assert out == ref, (p, temp)
+                assert out == _ref(model, params, p, 8, temp, seed), (p, temp)
     finally:
-        dense.stop()
         paged.stop()
 
 
-def test_paged_matches_dense_concurrent_sampled(paged_setup):
+def test_engine_matches_generate_concurrent_sampled(paged_setup):
     """4 concurrent sampled streams (distinct seeds/temps) through the
-    paged engine equal the dense engine's — admission-time key splits
-    and per-slot block tables keep streams independent."""
+    engine equal each request's own ``generate()`` — admission-time key
+    splits and per-slot block tables keep streams independent."""
     _, model, params = paged_setup
-    dense = ContinuousBatchingEngine(model, params, slots=4, buf_len=BUF)
     paged = _paged(model, params, slots=4)
     reqs = [([5, 17, 42], 0.8, 1), ([7, 7], 0.0, 0),
             (list(range(2, 30)), 0.9, 5), ([60], 0.7, 9)]
     try:
-        def battery(eng):
-            qs = [eng.submit(p, max_new_tokens=10, temperature=t, seed=s)
-                  for p, t, s in reqs]
-            return [_drain(q) for q in qs]
-        assert battery(paged) == battery(dense)
+        qs = [paged.submit(p, max_new_tokens=10, temperature=t, seed=s)
+              for p, t, s in reqs]
+        assert [_drain(q) for q in qs] == \
+            [_ref(model, params, p, 10, t, s) for p, t, s in reqs]
     finally:
-        dense.stop()
         paged.stop()
 
 
-def test_paged_matches_dense_multi_token_horizon(paged_setup):
+def test_engine_matches_generate_multi_token_horizon(paged_setup):
     _, model, params = paged_setup
-    dense = ContinuousBatchingEngine(model, params, slots=2, buf_len=BUF,
-                                     horizon=4)
     paged = _paged(model, params, slots=2, horizon=4)
     try:
         for p in ([5, 17, 42], list(range(1, 20))):
             assert paged.generate(p, max_new_tokens=9) == \
-                dense.generate(p, max_new_tokens=9)
+                _ref(model, params, p, 9)
     finally:
-        dense.stop()
         paged.stop()
+
+
+# -------------------------------------------- the engine as it is built
+#
+# A default-constructed engine (slots and buf_len only) keeps its KV in the
+# page pool: 16-token pages, 64-token chunks, a page for every position of
+# every slot.  Prompt lengths sit on either side of a page's end and of a
+# chunk's end.
+
+DEFAULT_BUF = 128
+
+
+@pytest.fixture(scope="module")
+def default_engine():
+    cfg = LlamaConfig(vocab_size=97, dim=32, n_layers=2, n_heads=4,
+                      n_kv_heads=2, ffn_dim=64, max_seq_len=DEFAULT_BUF,
+                      dtype=jnp.float32, attn_impl="blockwise")
+    model = LlamaLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ContinuousBatchingEngine(model, params, slots=3,
+                                   buf_len=DEFAULT_BUF)
+    yield model, params, eng
+    eng.stop()
+
+
+def test_default_engine_is_paged(default_engine):
+    _, _, eng = default_engine
+    assert [name for name, *_ in eng.step_programs()] == \
+        ["decode_step", "prefill_chunk"]
+    assert (eng.kv_page_tokens, eng.prefill_chunk) == (16, 64)
+    # the capacity of a cache of buf_len a slot: nothing admitted parks
+    assert eng.kv_pool_pages == 1 + 3 * (DEFAULT_BUF // 16)
+    pool = jax.tree_util.tree_leaves(eng._pool)
+    assert {p.shape[:2] for p in pool} == {(eng.kv_pool_pages, 16)}
+
+
+@pytest.mark.parametrize("n_prompt", [5, 15, 16, 17, 63, 64, 65, 100])
+def test_default_engine_matches_generate(default_engine, n_prompt):
+    """Shorter than a page, across a page's end (16), across a chunk's end
+    (64) and two chunks deep: greedy and sampled streams equal the
+    single-request cached decode bit for bit."""
+    model, params, eng = default_engine
+    ids = [int(t) for t in
+           np.random.default_rng(n_prompt).integers(1, 97, n_prompt)]
+    for temp, seed in ((0.0, 0), (0.9, 3)):
+        assert eng.generate(ids, max_new_tokens=20, temperature=temp,
+                            seed=seed) == \
+            _ref(model, params, ids, 20, temp, seed, buf_len=DEFAULT_BUF)
+    kv = eng.kv_stats()
+    assert kv["pool"]["exhausted"] == 0
+    assert kv["pages_free"] == kv["pool_pages"] - 1
+
+
+@pytest.mark.parametrize("page", [0, -16])
+def test_page_size_must_be_positive(paged_setup, page):
+    _, model, params = paged_setup
+    with pytest.raises(ValueError, match="kv_page_tokens"):
+        ContinuousBatchingEngine(model, params, slots=2, buf_len=BUF,
+                                 kv_page_tokens=page)
+
+
+def test_model_without_llama_config_is_refused():
+    """The engine rebuilds the model with the pool's geometry: a module
+    that carries no ``LlamaConfig`` is refused at construction, before a
+    thread or a server is started."""
+    import flax.linen as nn
+
+    class Bare(nn.Module):
+        @nn.compact
+        def __call__(self, tokens):
+            return nn.Embed(97, 8)(tokens)
+
+    before = threading.active_count()
+    with pytest.raises(PagedKVUnsupportedError, match="LlamaConfig"):
+        ContinuousBatchingEngine(Bare(), {}, slots=2, buf_len=BUF,
+                                 metrics_port=0)
+    assert threading.active_count() == before
 
 
 # ------------------------------------------------ a finish learned late
@@ -155,11 +234,12 @@ def test_paged_matches_dense_multi_token_horizon(paged_setup):
 # pool with that lane's write queued before whatever uses them next.
 
 #: a slot for each of four requests and pages for three, or three slots
+#: and the default pool: a page for every position
 EOS_CASES = {"paged": dict(slots=4, kv_page_tokens=PTOK, kv_pool_pages=13,
                            prefill_chunk_tokens=16),
              "paged_horizon3": dict(slots=4, kv_page_tokens=PTOK, horizon=3,
                                     kv_pool_pages=13, prefill_chunk_tokens=16),
-             "dense": dict(slots=3)}
+             "three_slots": dict(slots=3)}
 
 
 @pytest.mark.parametrize("case", sorted(EOS_CASES))
@@ -169,13 +249,10 @@ def test_eos_mid_stream_matches_generate_and_frees_its_pages(paged_setup, case):
     counted, all pages are free after the drain, and the request that was
     admitted into the freed pages (the pool holds no others for it) reads
     bit-equal."""
-    from fedml_tpu.serving.templates.openai_compat import generate
     _, model, params = paged_setup
 
     def ref(prompt, n, temp=0.0, seed=0, eos=None):
-        return generate(None, params, prompt, max_new_tokens=n,
-                        temperature=temp, seed=seed, buf_len=BUF, eos_id=eos,
-                        model=model)
+        return _ref(model, params, prompt, n, temp, seed, eos)
 
     first = [5, 17, 42, 8, 3]
     eos = ref(first, 12)[5]
@@ -194,9 +271,10 @@ def test_eos_mid_stream_matches_generate_and_frees_its_pages(paged_setup, case):
         assert [_drain(q) for q in qs] == want
         kv = eng.kv_stats()
         assert kv["lanes_burned"] >= 1 and kv["ticks_ahead"] > 0
-        if eng.paged:
-            assert kv["pool"]["exhausted"] >= 1      # the fourth had to wait
-            assert kv["pages_free"] == kv["pool_pages"] - 1
+        # the fourth had to wait: for pages, or for one of three slots
+        assert (kv["pool"]["exhausted"] >= 1) == ("kv_pool_pages"
+                                                  in EOS_CASES[case])
+        assert kv["pages_free"] == kv["pool_pages"] - 1
     finally:
         eng.stop()
 
@@ -358,20 +436,17 @@ def _case_horizon4(cfg, model, params):
     """horizon=4: the pool is the scan's carry.  Ten tokens burn two lanes
     of the third dispatch past the reservation (into the trash page), the
     answers cross a page's end mid-horizon, and the streams are those of
-    the dense engine one token at a time."""
-    dense = ContinuousBatchingEngine(model, params, slots=2, buf_len=BUF)
+    the single-request decode one token at a time."""
     paged = _paged(model, params, slots=2, horizon=4)
     reqs = [([5, 17, 42, 8, 9], 0.0, 0), (list(range(1, 15)), 0.8, 3)]
     try:
-        def battery(eng):
-            qs = [eng.submit(p, max_new_tokens=10, temperature=t, seed=s)
-                  for p, t, s in reqs]
-            return [_drain(q) for q in qs]
-        assert battery(paged) == battery(dense)
+        qs = [paged.submit(p, max_new_tokens=10, temperature=t, seed=s)
+              for p, t, s in reqs]
+        assert [_drain(q) for q in qs] == \
+            [_ref(model, params, p, 10, t, s) for p, t, s in reqs]
         kv = paged.kv_stats()
         assert kv["pages_free"] == kv["pool_pages"] - 1
     finally:
-        dense.stop()
         paged.stop()
 
 
@@ -420,9 +495,8 @@ def test_all_pages_free_after_drain(paged_setup):
 def test_page_exhaustion_parks_and_completes(paged_setup):
     """A pool too small for all slots at once: late requests park on
     page exhaustion and complete as earlier slots free pages — every
-    stream still matches the dense engine."""
+    stream still matches its own ``generate()``."""
     _, model, params = paged_setup
-    dense = ContinuousBatchingEngine(model, params, slots=4, buf_len=BUF)
     # 4 slots want up to ceil((3+12)/8)=2 pages each; 5 usable pages
     # means at most 2 concurrent — the rest must park, not fail
     eng = _paged(model, params, slots=4, kv_pool_pages=6)
@@ -430,12 +504,11 @@ def test_page_exhaustion_parks_and_completes(paged_setup):
         prompts = [[i + 1, i + 2, i + 3] for i in range(6)]
         qs = [eng.submit(p, max_new_tokens=12) for p in prompts]
         outs = [_drain(q) for q in qs]
-        refs = [dense.generate(p, max_new_tokens=12) for p in prompts]
-        assert outs == refs
+        assert outs == [_ref(model, params, p, 12) for p in prompts]
         kv = eng.kv_stats()
+        assert kv["pool"]["exhausted"] >= 1
         assert kv["pages_free"] == kv["pool_pages"] - 1
     finally:
-        dense.stop()
         eng.stop()
 
 
@@ -462,7 +535,8 @@ def test_unservable_request_fails_open(paged_setup):
 
 def test_adapter_cache_mode_matches_bank_engine(mt_setup):
     """6 adapters through a 3-row cache over the store equal the plain
-    full-bank engine's outputs, with evictions actually happening."""
+    full-bank engine's outputs and the single-request decode under the
+    adapter's own tree, with evictions actually happening."""
     model, params, loras = mt_setup
     bank = ContinuousBatchingEngine(model, params, slots=2, buf_len=BUF,
                                     adapter_slots=8)
@@ -474,8 +548,9 @@ def test_adapter_cache_mode_matches_bank_engine(mt_setup):
         names = sorted(loras) + sorted(loras)  # revisit all -> refetches
         for i, n in enumerate(names):
             p = [3 + i, 11, 19]
-            assert cache.generate(p, max_new_tokens=5, adapter=n) == \
-                bank.generate(p, max_new_tokens=5, adapter=n), n
+            want = _ref(model, params, p, 5, lora=loras[n])
+            assert cache.generate(p, max_new_tokens=5, adapter=n) == want, n
+            assert bank.generate(p, max_new_tokens=5, adapter=n) == want, n
         st = cache.registry.stats
         assert st["cache_evictions"] > 0
         assert st["cache_misses"] >= len(loras)
@@ -525,27 +600,39 @@ def test_cache_mode_unknown_adapter_fails_at_submit(mt_setup):
         eng.stop()
 
 
+def _bank_bytes(eng):
+    return sum(np.asarray(x).nbytes
+               for x in jax.tree_util.tree_leaves(eng.registry.bank))
+
+
 def test_adapter_store_scales_names_flat_bank(mt_setup, tmp_path):
-    """Registered names scale far past the bank (here 64 names through 2
-    rows with a disk spill tier) while the resident bank bytes stay
-    constant — the ISSUE's 10k-scale curve is pinned in BENCH_r16."""
+    """Registered names scale far past the bank — 32, then 10,000 names
+    through 2 rows with a disk spill tier — while the resident bank bytes
+    stay constant, and a name from the far end of the store still serves
+    the stream of its own tree."""
     model, params, loras = mt_setup
     eng = _paged(model, params, slots=2, adapter_cache_slots=2,
                  adapter_store_dir=str(tmp_path))
     try:
         seed = jax.tree_util.tree_map(np.asarray, loras["a0"])
-        for i in range(64):
-            eng.registry.register(f"n{i}", jax.tree_util.tree_map(
-                lambda x: x * (1.0 + i / 64.0), seed))
-        bank0 = sum(np.asarray(x).nbytes for x in
-                    jax.tree_util.tree_leaves(eng.registry.bank))
-        assert len(eng.registry.store) == 64
-        for i in (0, 17, 63, 5):
-            assert len(eng.generate([2, 3, 5], max_new_tokens=3,
-                                    adapter=f"n{i}")) == 3
-        bank1 = sum(np.asarray(x).nbytes for x in
-                    jax.tree_util.tree_leaves(eng.registry.bank))
-        assert bank1 == bank0  # flat HBM: rows never grow with names
+
+        def tree(i):
+            return jax.tree_util.tree_map(
+                lambda x: x * np.float32(1.0 + (i % 64) / 64.0), seed)
+
+        bank0 = _bank_bytes(eng)
+        registered = 0
+        for names in (32, 10_000):
+            for i in range(registered, names):
+                eng.registry.register(f"n{i}", tree(i))
+            registered = names
+            assert len(eng.registry.store) == names
+            for i in (0, 17, names - 1, 5):
+                assert eng.generate([2, 3, 5], max_new_tokens=3,
+                                    adapter=f"n{i}") == \
+                    _ref(model, params, [2, 3, 5], 3, lora=tree(i)), i
+            assert _bank_bytes(eng) == bank0  # flat HBM at every scale
+        assert eng.registry.stats["cache_evictions"] > 0
     finally:
         eng.stop()
 
@@ -581,21 +668,52 @@ def test_zero_steady_state_recompiles_under_churn(mt_setup):
         eng.stop()
 
 
-def test_speculative_engine_rejects_paged_model(paged_setup):
-    """Satellite: speculative x paged KV is rejected EARLY with the
-    named error (draft verification replays positions the paged write
-    path does not support yet), not a shape error mid-flight."""
-    cfg, model, params = paged_setup
-    paged_cfg = dataclasses.replace(cfg, kv_page_tokens=PTOK,
-                                    kv_pool_pages=16)
-    paged_model = LlamaLM(paged_cfg)
-    draft = LlamaLM(cfg)
-    with pytest.raises(PagedKVUnsupportedError):
-        SpeculativeBatchingEngine(paged_model, params, draft, params,
-                                  slots=2, buf_len=32)
-    with pytest.raises(PagedKVUnsupportedError):
-        SpeculativeBatchingEngine(model, params, paged_model, params,
-                                  slots=2, buf_len=32)
+def test_mixed_run_with_parking_compiles_nothing_and_leaks_no_page(paged_setup):
+    """Twelve requests of mixed lengths, greedy and sampled, on four slots
+    over pages for about two of them: requests park for pages and for
+    slots, prompts take one to three chunks.  After the warm-up nothing
+    compiles, and after the drain every page is back on the free list."""
+    from fedml_tpu.analysis.runtime import JaxRuntimeAudit
+    _, model, params = paged_setup
+    eng = _paged(model, params, slots=4, kv_pool_pages=9)
+    rng = np.random.default_rng(0)
+    reqs = [([int(t) for t in rng.integers(1, 97, n)], 0.7 * (i % 2), i)
+            for i, n in enumerate([3, 20, 7, 33, 1, 16, 17, 9, 30, 2, 12, 24])]
+    try:
+        eng.generate([5, 17, 42], max_new_tokens=2)
+        eng.generate(list(range(1, 40)), max_new_tokens=2, temperature=0.8)
+        with JaxRuntimeAudit() as audit:
+            qs = [eng.submit(p, max_new_tokens=8, temperature=t, seed=s)
+                  for p, t, s in reqs]
+            outs = [_drain(q) for q in qs]
+        assert audit.compilations == 0
+        kv = eng.kv_stats()
+        assert kv["pool"]["exhausted"] >= 1
+        assert kv["pool_pages"] - 1 - kv["pages_free"] == 0     # leaked
+        assert kv["pool"]["reserved_pages"] == kv["pool"]["released_pages"]
+    finally:
+        eng.stop()
+    assert outs == [_ref(model, params, p, 8, t, s) for p, t, s in reqs]
+
+
+def test_server_refuses_a_draft_beside_batch_slots(paged_setup):
+    """Speculation writes multi-token verify blocks into contiguous
+    per-request caches: beside the engine's page pool it is refused at
+    construction by the named error, which says where speculation lives;
+    without ``batch_slots`` the same server is built."""
+    from fedml_tpu.serving.templates.openai_compat import OpenAICompatServer
+    _, model, params = paged_setup
+
+    def apply_fn(p, t):
+        return model.apply({"params": p}, t)
+
+    with pytest.raises(PagedKVUnsupportedError, match="speculative_generate"):
+        OpenAICompatServer(apply_fn, params, buf_len=BUF, model=model,
+                           batch_slots=2, draft_model=model,
+                           draft_params=params)
+    srv = OpenAICompatServer(apply_fn, params, buf_len=BUF, model=model,
+                             draft_model=model, draft_params=params)
+    assert srv._engine is None and srv.draft_model is model
 
 
 def test_server_knob_validation(paged_setup):
@@ -706,8 +824,7 @@ def test_async_row_fetcher():
 
 
 def test_estimate_paged_serving_memory():
-    from fedml_tpu.core.memory_estimate import (
-        estimate_paged_serving_memory, estimate_serving_memory)
+    from fedml_tpu.core.memory_estimate import estimate_paged_serving_memory
     est = estimate_paged_serving_memory(
         n_params=1e6, n_slots=8, pool_bytes=64 * 2**20,
         block_table_bytes=8 * 64 * 4, window_bytes=2 * 2**20,
@@ -722,10 +839,3 @@ def test_estimate_paged_serving_memory():
         est["params"] + est["kv_pool"] + est["block_tables"]
         + est["adapter_bank"] + est["step_work"]))
     assert est["total_gib"] == pytest.approx(est["total"] / 2**30)
-    # dense at the same slot count reserves full-length buffers per
-    # slot; at 8 slots of full-length cache vs the shared 64 MiB pool
-    # the paged estimate is strictly smaller
-    dense = estimate_serving_memory(
-        n_params=1e6, n_slots=8, cache_bytes=8 * 64 * 2**20,
-        vocab_size=97)
-    assert dense["total"] > est["total"]

@@ -570,130 +570,6 @@ def test_top_p_nucleus_sampling():
     assert len(full) >= 4, full  # unfiltered high-temp covers the support
 
 
-def test_speculative_batching_engine_parity_and_acceptance():
-    """SpeculativeBatchingEngine greedy output must be bit-identical to
-    single-request generate for an arbitrary draft; with the target as its
-    own draft (perfectly aligned) every proposal is accepted, so target
-    block-forwards ~= tokens/(k+1); sampled requests are rejected."""
-    import dataclasses
-    import jax
-    import jax.numpy as jnp
-    import pytest
-    from fedml_tpu.llm.model import LlamaConfig, LlamaLM
-    from fedml_tpu.serving.batching import SpeculativeBatchingEngine
-    from fedml_tpu.serving.templates.openai_compat import generate
-
-    k = 3
-    buf = 32
-    # max_seq_len must cover buf + k + 1 (speculative block slack)
-    cfg = LlamaConfig(vocab_size=97, dim=32, n_layers=2, n_heads=4,
-                      n_kv_heads=2, ffn_dim=64, max_seq_len=buf + k + 1,
-                      dtype=jnp.float32, attn_impl="blockwise")
-    model = LlamaLM(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
-    dcfg = dataclasses.replace(cfg, dim=16, n_layers=1, n_heads=2,
-                               n_kv_heads=2, ffn_dim=32)
-    draft = LlamaLM(dcfg)
-    dparams = draft.init(jax.random.PRNGKey(1),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    apply_fn = lambda p, t: model.apply({"params": p}, t)
-
-    # (a) parity with an unrelated random draft, 4 requests through 2 slots
-    eng = SpeculativeBatchingEngine(model, params, draft, dparams,
-                                    slots=2, buf_len=buf, k=k)
-    try:
-        with pytest.raises(ValueError):
-            eng.submit([1, 2], temperature=0.7)
-        prompts = [[5, 17, 42], [7, 7], [1, 2, 3, 4], [60]]
-        budgets = [10, 3, 13, 6]
-        ref0 = generate(apply_fn, params, prompts[0], max_new_tokens=10,
-                        buf_len=buf, model=model)
-        eoss = [ref0[4], None, None, None]  # eos fires mid-stream for req 0
-        queues = [eng.submit(p, max_new_tokens=b, eos_id=e)
-                  for p, b, e in zip(prompts, budgets, eoss)]
-        for p, b, e, q in zip(prompts, budgets, eoss, queues):
-            got = []
-            while True:
-                t = q.get(timeout=120)
-                if t is None:
-                    break
-                got.append(t)
-            want = generate(apply_fn, params, p, max_new_tokens=b,
-                            buf_len=buf, model=model, eos_id=e)
-            assert got == want, (p, got, want)
-    finally:
-        eng.stop()
-
-    # (b) aligned draft: full acceptance, ~tokens/(k+1) target forwards
-    eng = SpeculativeBatchingEngine(model, params, model, params,
-                                    slots=1, buf_len=buf, k=k)
-    try:
-        n_new = 12
-        out = eng.generate([5, 17, 42], max_new_tokens=n_new)
-        want = generate(apply_fn, params, [5, 17, 42],
-                        max_new_tokens=n_new, buf_len=buf, model=model)
-        assert out == want
-        assert eng.stats["accepted"] == eng.stats["proposed"], eng.stats
-        # prefill emits 1; each block tick then yields k+1 tokens
-        assert eng.stats["target_block_forwards"] <= -(-(n_new - 1) // (k + 1)) + 1, \
-            eng.stats
-    finally:
-        eng.stop()
-
-
-def test_server_speculative_batching_mode():
-    """batch_slots + draft_model => SpeculativeBatchingEngine: greedy HTTP
-    requests go through it (bit-equal to generate); sampled requests fall
-    back to the single-request cached path instead of erroring."""
-    import dataclasses
-    import json as _json
-    import jax
-    import jax.numpy as jnp
-    from fedml_tpu.llm.model import LlamaConfig, LlamaLM
-    from fedml_tpu.serving.batching import SpeculativeBatchingEngine
-    from fedml_tpu.serving.templates.openai_compat import (
-        ByteTokenizer, OpenAICompatServer, generate)
-
-    tok = ByteTokenizer()
-    k = 4
-    buf = 48
-    cfg = LlamaConfig(vocab_size=tok.vocab_size, dim=32, n_layers=1,
-                      n_heads=2, n_kv_heads=2, ffn_dim=64,
-                      max_seq_len=buf + k + 1, dtype=jnp.float32,
-                      attn_impl="blockwise")
-    model = LlamaLM(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
-    dcfg = dataclasses.replace(cfg, dim=16, n_heads=2, n_kv_heads=2,
-                               ffn_dim=32)
-    draft = LlamaLM(dcfg)
-    dparams = draft.init(jax.random.PRNGKey(1),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    apply_fn = lambda p, t: model.apply({"params": p}, t)
-
-    srv = OpenAICompatServer(apply_fn, params, tokenizer=tok, buf_len=buf,
-                             model=model, batch_slots=2,
-                             draft_model=draft, draft_params=dparams)
-    assert isinstance(srv._engine, SpeculativeBatchingEngine)
-    port = srv.start()
-    try:
-        st, body = _post(port, "/v1/completions",
-                         {"prompt": "hi", "max_tokens": 10})
-        text = _json.loads(body)["choices"][0]["text"]
-        want = generate(apply_fn, params, tok.encode("hi"),
-                        max_new_tokens=10, buf_len=buf, model=model,
-                        eos_id=tok.eos_id)
-        assert text == tok.decode(want)
-        # sampled request: must not error (engine is greedy-only)
-        st, body = _post(port, "/v1/completions",
-                         {"prompt": "hi", "max_tokens": 5,
-                          "temperature": 0.9, "seed": 3})
-        assert st == 200 and _json.loads(body)["choices"][0]["text"]
-    finally:
-        srv.stop()
-
-
 def test_prefix_cache_greedy_parity_and_reuse():
     """PrefixCache: greedy outputs must be BIT-IDENTICAL with and without
     the cache for (a) cold miss, (b) exact-prompt hit, (c) shared-prefix
@@ -912,15 +788,13 @@ def test_prefix_cache_invalidated_on_weight_swap():
 
 
 def test_prefix_cache_in_batching_engine():
-    """Engine admission with prefix_cache_slots: outputs bit-equal to an
-    uncached engine (greedy), cache hits recorded across requests sharing
-    a system prefix, and the speculative engine threads the knob through
-    (still parity with generate)."""
+    """Engine admission with prefix_cache_slots: outputs bit-equal to
+    ``generate`` (greedy), and requests sharing a system prefix of two
+    whole 4-token pages are lent those pages."""
     import jax
     import jax.numpy as jnp
     from fedml_tpu.llm.model import LlamaConfig, LlamaLM
-    from fedml_tpu.serving.batching import (ContinuousBatchingEngine,
-                                            SpeculativeBatchingEngine)
+    from fedml_tpu.serving.batching import ContinuousBatchingEngine
     from fedml_tpu.serving.templates.openai_compat import generate
 
     cfg = LlamaConfig(vocab_size=97, dim=32, n_layers=2, n_heads=4,
@@ -936,30 +810,16 @@ def test_prefix_cache_in_batching_engine():
             for pr in prompts]
 
     eng = ContinuousBatchingEngine(model, params, slots=2, buf_len=96,
-                                   prefix_cache_slots=4)
+                                   prefix_cache_slots=4, kv_page_tokens=4)
     try:
         outs = [eng.generate(pr, max_new_tokens=8) for pr in prompts]
+        kv = eng.kv_stats()
     finally:
         eng.stop()
     assert outs == refs
     assert eng.prefix_cache.stats["hits"] == 2
-    assert eng.prefix_cache.stats["exact_hits"] == 1
-
-    draft_cfg = LlamaConfig(vocab_size=97, dim=16, n_layers=1, n_heads=2,
-                            n_kv_heads=2, ffn_dim=32, max_seq_len=160,
-                            dtype=jnp.float32)
-    draft = LlamaLM(draft_cfg)
-    dparams = draft.init(jax.random.PRNGKey(1),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    spec = SpeculativeBatchingEngine(model, params, draft, dparams,
-                                     slots=2, buf_len=96, k=3,
-                                     prefix_cache_slots=4)
-    try:
-        outs = [spec.generate(pr, max_new_tokens=8) for pr in prompts]
-    finally:
-        spec.stop()
-    assert outs == refs
-    assert spec.prefix_cache.stats["hits"] == 2
+    assert eng.prefix_cache.stats["shared_pages"] == 4
+    assert kv["pages_shared"] == 4
 
 
 def test_server_weight_swap_over_http():
@@ -1053,14 +913,12 @@ def test_engine_weight_swap_serves_new_weights():
     """Round-4 advisor (medium): a server built with batch_slots kept
     serving its engine's construction-time weights after update_params().
     The engine must swap: post-swap greedy outputs equal a fresh engine
-    built on the new tree, the engine prefix cache clears with the swap,
-    and the speculative engine swaps target+draft while outputs stay
-    exact."""
+    built on the new tree, and the engine prefix cache clears with the
+    swap."""
     import jax
     import jax.numpy as jnp
     from fedml_tpu.llm.model import LlamaConfig, LlamaLM
-    from fedml_tpu.serving.batching import (ContinuousBatchingEngine,
-                                            SpeculativeBatchingEngine)
+    from fedml_tpu.serving.batching import ContinuousBatchingEngine
     from fedml_tpu.serving.templates.openai_compat import (OpenAICompatServer,
                                                            generate)
 
@@ -1081,12 +939,14 @@ def test_engine_weight_swap_serves_new_weights():
     assert ref0 != ref1  # differently-seeded inits must actually differ
 
     eng = ContinuousBatchingEngine(model, p0, slots=2, buf_len=96,
-                                   prefix_cache_slots=4)
+                                   prefix_cache_slots=4, kv_page_tokens=4)
     try:
         assert eng.generate(prompt, max_new_tokens=8) == ref0
+        assert len(eng.prefix_cache) == 1       # one whole page of the five
         eng.update_params({"params": p1})        # wrapped tree accepted
-        assert len(eng.prefix_cache._entries) == 0, \
+        assert len(eng.prefix_cache) == 0, \
             "engine prefix cache must clear with the swap"
+        assert eng.page_pool.pages_free == eng.page_pool.n_pages - 1
         assert eng.generate(prompt, max_new_tokens=8) == ref1, \
             "engine still serving construction-time weights after swap"
         assert eng.generate(prompt, max_new_tokens=8) == ref1
@@ -1110,26 +970,6 @@ def test_engine_weight_swap_serves_new_weights():
         assert out == ref1, "server engine path served old weights"
     finally:
         srv.stop()
-
-    # speculative engine: swap target+draft, outputs stay exact (greedy
-    # verification against the swapped target)
-    draft_cfg = LlamaConfig(vocab_size=97, dim=16, n_layers=1, n_heads=2,
-                            n_kv_heads=2, ffn_dim=32, max_seq_len=160,
-                            dtype=jnp.float32)
-    draft = LlamaLM(draft_cfg)
-    d0 = draft.init(jax.random.PRNGKey(1),
-                    jnp.zeros((1, 8), jnp.int32))["params"]
-    d1 = draft.init(jax.random.PRNGKey(2),
-                    jnp.zeros((1, 8), jnp.int32))["params"]
-    spec = SpeculativeBatchingEngine(model, p0, draft, d0, slots=2,
-                                     buf_len=96, k=3)
-    try:
-        assert spec.generate(prompt, max_new_tokens=8) == ref0
-        spec.update_params(p1, draft_params=d1)
-        assert spec.generate(prompt, max_new_tokens=8) == ref1
-        assert spec.raw_draft is d1
-    finally:
-        spec.stop()
 
 
 def test_multi_adapter_personalized_serving():

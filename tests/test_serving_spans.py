@@ -6,7 +6,6 @@ read: its ``readback`` / ``emit`` / ``free`` serve the previous dispatch, a
 ``serve.flush`` reads the last one of a drain, and a steady tick uploads
 nothing."""
 
-import dataclasses
 import os
 import sys
 import time
@@ -19,8 +18,7 @@ import pytest
 from fedml_tpu import obs
 from fedml_tpu.llm.model import LlamaConfig, LlamaLM
 from fedml_tpu.obs.jaxhooks import count_put
-from fedml_tpu.serving.batching import (ContinuousBatchingEngine,
-                                        SpeculativeBatchingEngine)
+from fedml_tpu.serving.batching import ContinuousBatchingEngine
 
 from tests.span_tree import children, named, spans_of
 
@@ -194,40 +192,6 @@ def test_idle_engine_waits_in_a_span(mt_model):
     waits = named(spans, "serve.wait")
     assert waits and all(w["parent"] is None for w in waits)
     assert max(w["t1"] - w["t0"] for w in waits) > 0.3e6
-
-
-def test_speculative_engine_opens_the_same_tick(mt_model):
-    cfg = LlamaConfig(vocab_size=97, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
-                      ffn_dim=64, max_seq_len=BUF + 8, dtype=jnp.float32,
-                      attn_impl="blockwise")
-    model = LlamaLM(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    draft = LlamaLM(dataclasses.replace(cfg, dim=16, n_layers=1, n_heads=2,
-                                        n_kv_heads=2, ffn_dim=32))
-    dparams = draft.init(jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"]
-    tracer = obs.configure(enabled=True, reset=True, jax_hooks=False)
-    try:
-        eng = SpeculativeBatchingEngine(model, params, draft, dparams, slots=2,
-                                        buf_len=BUF, k=3)
-        try:
-            outs = [eng.generate(p, max_new_tokens=n) for p, n in (([5, 17, 42], 9), ([7, 7], 4))]
-        finally:
-            eng.stop()
-        events = tracer.export_chrome()["traceEvents"]
-    finally:
-        obs.configure(enabled=False, reset=True)
-    assert fedtrace.validate_events(events) == []
-    spans = spans_of([e for e in events if e["ph"] in "BE"])
-    ticks = named(spans, "serve.tick")
-    assert ticks and sum(t["args"]["tokens"] for t in ticks) == sum(map(len, outs)) - 2
-    by_id = {s["id"]: s for s in spans}
-    for name in ("stage", "dispatch", "readback", "emit", "free"):
-        kids = named(spans, f"serve.tick.{name}")
-        assert len(kids) == len(ticks)
-        assert all(by_id[k["parent"]]["name"] == "serve.tick" for k in kids)
-    # the dense path's prefill span still nests under admission
-    assert all(by_id[s["parent"]]["name"] == "serve.admit"
-               for s in named(spans, "serve.prefill"))
 
 
 def test_off_means_no_event_and_no_other_transfer(mt_model):

@@ -422,11 +422,6 @@ def summarize(trace: Dict[str, Any]) -> Dict[str, Any]:
                 ph: round(sum(float(a.get(f"{ph}_s", 0.0))
                               for a in req_args) / e2e_total, 6)
                 for ph in ("queue", "prefill", "decode")}
-        drafts = sum(int(a.get("drafts_proposed", 0)) for a in req_args)
-        if drafts:
-            out["serve_drafts_proposed"] = drafts
-            out["serve_drafts_accepted"] = sum(
-                int(a.get("drafts_accepted", 0)) for a in req_args)
     return out
 
 

@@ -14,10 +14,17 @@ Design (static shapes throughout, nothing dropped):
   auxiliary loss (mean(token-fraction · prob-fraction) · E², the standard
   switch loss).
 - **Dispatch** (:func:`expert_ffn`): the ``N·k`` (token, expert) pairs are
-  sorted by expert, the held experts' SwiGLU runs as three grouped matmuls
-  (``jax.lax.ragged_dot``) over the sorted rows, and every pair's output
-  goes back to its token with its gate weight.  The shapes depend on
-  ``N·k`` alone, so no skew drops a token.
+  sorted by expert, the held experts' SwiGLU runs as grouped matmuls over
+  the sorted rows (``ops/grouped_matmul.py::swiglu``), and every pair's
+  output goes back to its token with its gate weight.  The shapes depend on
+  ``N·k`` alone, so no skew drops a token.  Where the program is lowered for
+  a TPU, the operands are bfloat16 and ``dim`` and ``ffn_dim`` are whole
+  128-lane tiles, the grouped matmuls are two Pallas
+  kernels (gate and up fused, then down) that read a hit expert's weights
+  once, skip the experts no pair chose and leave the row tiles past the last
+  held row untouched; everywhere else they are three
+  ``jax.lax.ragged_dot``s.  Same rounding points either way, and the
+  backward is always ``ragged_dot``'s.
 - **Which experts live here**: ``held = (first, count)`` — the layer routes
   over all ``n_experts``, holds the weights of ``count`` consecutive ones
   and computes their part of the result (the gate keeps its denominator
@@ -39,10 +46,13 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.mesh import MODEL_AXIS
+from ..ops.grouped_matmul import swiglu as grouped_swiglu
 
-#: the collection a layer sows ``[pairs, experts_hit, load_max]`` into
-#: (int32): pairs computed by the held experts, held experts that got at
-#: least one, the most any one got.  Mutable only where a caller asks.
+#: the collection a layer sows ``[pairs, experts_hit, load_max, tiles]``
+#: into (int32): pairs computed by the held experts, held experts that got
+#: at least one, the most any one got, and the (expert, row tile) visits of
+#: the grouped-matmul kernels (0 where ``ragged_dot`` ran).  Mutable only
+#: where a caller asks.
 COUNTERS = "moe_counters"
 
 
@@ -96,7 +106,8 @@ def expert_ffn(x, gates, experts, w_gate, w_up, w_down, first):
     ``x`` (N, d); ``gates``, ``experts`` (N, k); ``w_gate``/``w_up``
     (count, d, f) and ``w_down`` (count, f, d) hold experts ``first`` ..
     ``first + count - 1`` (``first`` may be traced).  Returns the (N, d)
-    float32 part and the (count,) int32 pairs each held expert computed."""
+    float32 part, the (count,) int32 pairs each held expert computed and
+    the row tiles the grouped-matmul kernels visited (0: ``ragged_dot``)."""
     n, k = experts.shape
     count = w_gate.shape[0]
     local = experts.reshape(-1) - first
@@ -105,15 +116,13 @@ def expert_ffn(x, gates, experts, w_gate, w_up, w_down, first):
     order = jnp.argsort(key, stable=True)
     sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
     rows = x[order // k]                               # (N·k, d), by expert
-    dot = lambda a, w: jax.lax.ragged_dot(
-        a, w, sizes, preferred_element_type=jnp.float32)
-    act = (nn.silu(dot(rows, w_gate)) * dot(rows, w_up)).astype(x.dtype)
-    y = dot(act, w_down)
+    y, tiles = grouped_swiglu(rows, w_gate, w_up, w_down, sizes)
     # back to (token, choice) order; rows past the held groups count nothing
+    # (a select: the kernels leave them unwritten)
     back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
     g = jnp.where(here, gates.reshape(-1), 0.0)
     y = jnp.where(here[:, None], y[back], 0.0) * g[:, None]
-    return y.reshape(n, k, -1).sum(1), sizes
+    return y.reshape(n, k, -1).sum(1), sizes, tiles
 
 
 class MoEMLP(nn.Module):
@@ -172,19 +181,21 @@ class MoEMLP(nn.Module):
 
             def part(xt, gates, experts, *w):
                 mine = first + jax.lax.axis_index(MODEL_AXIS) * per
-                out, sizes = expert_ffn(xt, gates, experts, *w, mine)
-                return jax.lax.psum(out, MODEL_AXIS), sizes
+                out, sizes, tiles = expert_ffn(xt, gates, experts, *w, mine)
+                return (jax.lax.psum(out, MODEL_AXIS), sizes,
+                        jax.lax.psum(tiles, MODEL_AXIS))
 
-            out, sizes = jax.shard_map(
+            out, sizes, tiles = jax.shard_map(
                 part, mesh=mesh,
                 in_specs=(P(), P(), P()) + (P(MODEL_AXIS),) * 3,
-                out_specs=(P(), P(MODEL_AXIS)), check_vma=False)(
+                out_specs=(P(), P(MODEL_AXIS), P()), check_vma=False)(
                     xt, gates, experts,
                     *[_ep_constraint(m, self.mesh) for m in w])
         else:
-            out, sizes = expert_ffn(xt, gates, experts, *w, first)
+            out, sizes, tiles = expert_ffn(xt, gates, experts, *w, first)
         self.sow(COUNTERS, "layer", jnp.stack(
-            [sizes.sum(), (sizes > 0).sum(), sizes.max()]).astype(jnp.int32))
+            [sizes.sum(), (sizes > 0).sum(), sizes.max(), tiles]
+        ).astype(jnp.int32))
         return out.reshape(b, s, dim).astype(x.dtype)
 
 

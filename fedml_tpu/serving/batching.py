@@ -100,17 +100,18 @@ def _unwrap_params(params):
 
 def _moe_counters(mut):
     """What the sparse layers of one apply sowed (``llm/moe.py``:
-    ``[pairs, experts_hit, load_max]`` a layer), one row a layer; None for
-    a model without such layers."""
+    ``[pairs, experts_hit, load_max, tiles]`` a layer), one row a layer;
+    None for a model without such layers."""
     from ..llm.moe import COUNTERS
     leaves = jax.tree_util.tree_leaves(mut.get(COUNTERS, {}))
     return _fold_counters(jnp.stack(leaves)) if leaves else None
 
 
 def _fold_counters(rows):
-    """Rows of ``[pairs, experts_hit, load_max]`` as one: pairs and experts
-    hit summed, the largest load of any."""
-    return jnp.stack([rows[:, 0].sum(), rows[:, 1].sum(), rows[:, 2].max()])
+    """Rows of ``[pairs, experts_hit, load_max, tiles]`` as one: pairs,
+    experts hit and the kernels' row tiles summed, the largest load of any."""
+    return jnp.stack([rows[:, 0].sum(), rows[:, 1].sum(), rows[:, 2].max(),
+                      rows[:, 3].sum()])
 
 
 def _with_counters(tokens, rows):
@@ -510,7 +511,7 @@ class ContinuousBatchingEngine:
         # sparse layers: the chunk program's first accumulator
         self._moe_layers = sum(
             cfg.sparse_layer(i) for i in range(cfg.n_layers))
-        self._chunk_acc0 = jnp.zeros((4,), jnp.int32) \
+        self._chunk_acc0 = jnp.zeros((5,), jnp.int32) \
             if self._moe_layers else None
         self._chunk_words = self.prefill_chunk + 5 + key_words
         self._host_device = jax.devices("cpu")[0]
@@ -536,9 +537,11 @@ class ContinuousBatchingEngine:
         self._pending_params = None
         self._ticks = 0  # batched steps executed (observability)
         # what the sparse layers did (kv_stats): pairs the
-        # held experts computed in ticks and finished prefills; held
+        # held experts computed in ticks and finished prefills, and the
+        # row tiles the grouped-matmul kernels visited for them; held
         # experts that got a token and sparse layers run, over the ticks
         self._expert_pairs = 0
+        self._expert_tiles = 0
         self._experts_hit = 0
         self._moe_layers_ticked = 0
         self._expert_load_max = 0
@@ -1093,6 +1096,7 @@ class ContinuousBatchingEngine:
             chunks = self._chunks_total
             shared, private = self._pages_shared, self._pages_private
             pairs, hit = self._expert_pairs, self._experts_hit
+            tiles = self._expert_tiles
             layers = self._moe_layers_ticked
         out["pool"] = dict(self.page_pool.stats)
         out["pages_free"] = self.page_pool.pages_free
@@ -1103,6 +1107,7 @@ class ContinuousBatchingEngine:
         out["kv_bytes_per_token"] = self._kv_bytes_per_token
         out["expert_pairs"] = pairs
         out["experts_hit"] = hit
+        out["expert_tiles"] = tiles
         out["moe_layers_ticked"] = layers
         if self.prefix_cache is not None:
             out["prefix"] = dict(self.prefix_cache.stats)
@@ -1378,9 +1383,10 @@ class ContinuousBatchingEngine:
         # a request's first token brings what the experts computed over
         # its prompt's chunks
         prefill_pairs = sum(int(out[1]) for out in first_got if out.size > 1)
-        pairs = hit = ticked = 0
+        prefill_tiles = sum(int(out[4]) for out in first_got if out.size > 1)
+        pairs = hit = tiles = ticked = 0
         if len(counters):
-            pairs, hit, most = (int(c) for c in counters)
+            pairs, hit, most, tiles = (int(c) for c in counters)
             ticked = self._moe_layers * self.horizon
             self._expert_load_max = most  # fedrace: disable=unguarded-shared-write
         if ticked or prefill_pairs:
@@ -1388,6 +1394,7 @@ class ContinuousBatchingEngine:
             # its last token finds the tick that made it in kv_stats()
             with self._stats_lock:
                 self._expert_pairs += pairs + prefill_pairs
+                self._expert_tiles += tiles + prefill_tiles
                 self._experts_hit += hit
                 self._moe_layers_ticked += ticked
         with tracer.span("serve.tick.emit", cat="engine") as emit:
@@ -1426,7 +1433,7 @@ class ContinuousBatchingEngine:
             args = {"tokens": tokens, "burned": burned}
             if ticked:
                 args.update(expert_pairs=pairs, experts_hit=hit,
-                            expert_load_max=most)
+                            expert_load_max=most, expert_tiles=tiles)
             if first_got:
                 args["first_tokens"] = len(first_got)
                 if first_got[0].size > 1:

@@ -131,6 +131,33 @@ def test_scatter_merge_compiles_on_four_chip_mesh(topo):
     assert per_chip < 16e9
 
 
+# -- the expert layer's grouped matmuls at the latent cell's shapes ----------
+
+@pytest.mark.parametrize("rows", [512, 4096], ids=["tick", "chunk"])
+@pytest.mark.parametrize("call", ["gate", "down", "gate_up", "swiglu"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, call, rows):
+    """``bf16[512|4096, 7168] x bf16[12, 7168, 2048]`` and back: a tick's and
+    a prefill chunk's (token, expert) pairs against the 12 held experts of
+    ``a.x-k1-ep16-d7``.  ``swiglu`` is what ``expert_ffn`` calls: lowered for
+    the chip it is the two kernels and no ``ragged_dot``."""
+    from fedml_tpu.ops import grouped_matmul as gm
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    x, act = shape(rows, 7168), shape(rows, 2048)
+    wide, narrow = shape(12, 7168, 2048), shape(12, 2048, 7168)
+    sizes = shape(12, dtype=jnp.int32)
+    fn, args, kernels = {
+        "gate": (gm.grouped_matmul, (x, wide, sizes), 1),
+        "down": (gm.grouped_matmul, (act, narrow, sizes), 1),
+        "gate_up": (gm.gated_matmul, (x, wide, wide, sizes), 1),
+        "swiglu": (gm.swiglu, (x, wide, wide, narrow, sizes), 2),
+    }[call]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert hlo.count("tpu_custom_call") == kernels and "ragged-dot" not in hlo
+
+
 # -- the paged decode programs never move a whole page pool -----------------
 
 @pytest.fixture(scope="module")
@@ -253,8 +280,13 @@ def test_paged_program_never_moves_a_whole_pool(request, model, program):
     assert len({p.shape for p in pools}) == 1
     if model == "latent":
         assert pools[0].shape == (10241, 16, 640) and len(pools) == 2
-        # the experts run as grouped matmuls over the sorted pairs
-        assert hlo.count("ragged-dot") >= 3
+        # the experts run as the two grouped-matmul kernels over the sorted
+        # pairs (ops/grouped_matmul.py): the program the chip runs holds no
+        # grouped matmul of the compiler's own
+        assert "ragged-dot" not in hlo
+        assert hlo.count("tpu_custom_call") >= 2
+        # under the kernels' own names, which is what a device trace shows
+        assert "%gated_matmul" in hlo and "%grouped_matmul" in hlo
     instrs = _pool_sized_instructions(hlo, pools[0].size)
     naming = {"parameter", "get-tuple-element", "tuple", "bitcast"}
     moving = [(op, line[:200]) for op, line in instrs

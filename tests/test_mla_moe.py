@@ -367,7 +367,7 @@ def test_every_token_to_one_expert_still_equals_the_reference():
         want, _ = ref.experts(hn[0], layer_w, dict(cfg, topk_group=4), None, None)
     assert rel(got[0], want) < TOL
     # (h) by hand: 40 tokens, 4 experts each, all on the same four
-    assert np.asarray(state[moe.COUNTERS]["layer"][0]).tolist() == [160, 4, 40]
+    assert np.asarray(state[moe.COUNTERS]["layer"][0]).tolist() == [160, 4, 40, 0]
 
 
 def test_counters_of_a_share_by_hand():
@@ -376,8 +376,8 @@ def test_counters_of_a_share_by_hand():
     gates = jnp.ones((6, 2)) * 0.5
     x = jax.random.normal(jax.random.PRNGKey(0), (6, 8))
     w = [jax.random.normal(jax.random.PRNGKey(i), s) for i, s in enumerate(((3, 8, 4), (3, 8, 4), (3, 4, 8)))]
-    out, sizes = moe.expert_ffn(x, gates, experts, *w, 2)
-    assert sizes.tolist() == [4, 2, 1]                       # expert 2: tokens 0, 1, 3, 5
+    out, sizes, tiles = moe.expert_ffn(x, gates, experts, *w, 2)
+    assert sizes.tolist() == [4, 2, 1] and int(tiles) == 0   # ragged_dot: no kernel's tiles                       # expert 2: tokens 0, 1, 3, 5
     want = np.zeros((6, 8))
     for n in range(6):
         for e in np.asarray(experts[n]):

@@ -71,8 +71,9 @@ def test_moe_dropless_under_skew():
     ref = _reference_moe(params, x, e, 1, cap=b * s)
     assert np.abs(ref).max(-1).min() > 1e-6          # no row is zero
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5, rtol=1e-4)
-    pairs, hit, most = np.asarray(state["moe_counters"]["layer"][0])
+    pairs, hit, most, tiles = np.asarray(state["moe_counters"]["layer"][0])
     assert (pairs, hit, most) == (s, 1, s)
+    assert tiles == 0                   # widths of 8 and 16: ragged_dot ran
 
 
 def test_moe_expert_parallel_on_mesh():

@@ -108,6 +108,27 @@ class LlamaConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_scaling: Optional[YarnScaling] = None
+    #: a head's width; 0 = ``dim // n_heads``
+    head_dim: int = 0
+    #: the kind of every layer's attention, ``"full_attention"`` (causal over
+    #: everything before) or ``"sliding_attention"`` (key j visible to query
+    #: i iff ``0 <= i - j < sliding_window``); None = all full
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: int = 0
+    #: False: the full layers carry no positional embedding, the window
+    #: layers keep the rotary one
+    rope_full_layers: bool = True
+    #: ``x + Attn(n) + FFN(n)`` with one norm ``n`` for both, in place of
+    #: the sequential residual
+    parallel_block: bool = False
+    #: ``rms``, or ``layer``: the mean is subtracted first (scale, no bias)
+    norm_kind: str = "rms"
+    #: the head is the embedding, transposed; the logits times ``logit_scale``
+    tie_embeddings: bool = False
+    logit_scale: float = 1.0
+    #: what the shared experts' sum is multiplied by (1 / their number where
+    #: a model averages them)
+    shared_expert_scale: float = 1.0
     #: >0 fuses the lm_head matmul into a vocab-chunked streaming softmax
     #: cross-entropy on the training path (ops/xent.py) — peak activation
     #: memory O(B*S*chunk) instead of the O(B*S*V) logit tensor.
@@ -130,6 +151,13 @@ class LlamaConfig:
     #: the single-request paths are always dense).
     kv_page_tokens: int = 0
     kv_pool_pages: int = 0
+    #: >0 (the engine sets it for a model with layers of both kinds): the
+    #: window layers' pools have this many pages and are addressed through
+    #: a table of their own, a ring: block j of a slot is entry ``j %
+    #: entries``, and the serving engine takes the pages wholly behind the
+    #: window back while the request runs (docs/SERVING.md).  0: every
+    #: layer's pool has ``kv_pool_pages`` and one table addresses them all.
+    kv_window_pool_pages: int = 0
 
     def __post_init__(self):
         # typos must fail loudly — a silently-defaulted knob produces
@@ -152,6 +180,28 @@ class LlamaConfig:
         if self.kv_pool_pages == 1:
             raise ValueError("kv_pool_pages=1 is only the reserved trash "
                              "page — need at least 2")
+        if self.norm_kind not in ("rms", "layer"):
+            raise ValueError(f"norm_kind={self.norm_kind!r}: must be 'rms' "
+                             "or 'layer'")
+        kinds = self.layer_types
+        if kinds is not None:
+            if len(kinds) != self.n_layers or set(kinds) - {
+                    "full_attention", "sliding_attention"}:
+                raise ValueError(
+                    f"layer_types={kinds!r}: one of 'full_attention' and "
+                    f"'sliding_attention' for each of {self.n_layers} layers")
+            if "sliding_attention" in kinds and self.sliding_window <= 0:
+                raise ValueError("sliding_attention layers need "
+                                 "sliding_window > 0")
+        if self.windowed and (self.kv_lora_rank > 0
+                              or self.kv_cache_dtype == "int8"):
+            raise ValueError(
+                "a sliding window is computed for grouped-query attention "
+                "over a cache of the model's own type: not for latent "
+                "attention, nor over an int8 cache")
+        if self.tie_embeddings and self.streaming_xent_chunk:
+            raise ValueError("streaming_xent_chunk reads lm_head/kernel: "
+                             "not with tie_embeddings")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_scoring={self.moe_scoring!r}: must be "
                              "'softmax' or 'sigmoid'")
@@ -187,6 +237,25 @@ class LlamaConfig:
     @property
     def latent_attention(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def windowed(self) -> bool:
+        """Does any layer look back a fixed window only?"""
+        return bool(self.layer_types) \
+            and "sliding_attention" in self.layer_types
+
+    @property
+    def mixed_attention(self) -> bool:
+        """Window and full layers in one stack."""
+        return self.windowed and "full_attention" in self.layer_types
+
+    def layer_window(self, i: int) -> int:
+        """Layer ``i``'s window; 0 = everything before."""
+        return self.sliding_window if self.windowed \
+            and self.layer_types[i] == "sliding_attention" else 0
+
+    def layer_rope(self, i: int) -> bool:
+        return self.rope_full_layers or self.layer_window(i) > 0
 
     def sparse_layer(self, i: int) -> bool:
         """Does layer ``i`` carry routed experts?"""
@@ -257,6 +326,24 @@ class RMSNorm(nn.Module):
         return (x * jax.lax.rsqrt(var + self.eps)).astype(x.dtype) * scale
 
 
+class LayerNorm(nn.Module):
+    """``(x - mean x) / sqrt(var x + eps) * scale``, no bias."""
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        xf = x.astype(jnp.float32)
+        xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        return (xf * jax.lax.rsqrt(var + self.eps)).astype(x.dtype) * scale
+
+
+def _norm(cfg: "LlamaConfig", name: str):
+    return (LayerNorm if cfg.norm_kind == "layer" else RMSNorm)(
+        cfg.norm_eps, name=name)
+
+
 class LoRADense(nn.Module):
     """Dense with an optional low-rank adapter in the "lora" collection:
     y = x·W + (α/r)·(x·A)·B.  W lives in "params" (frozen for FedLoRA);
@@ -320,14 +407,93 @@ def _attn_impl(cfg: "LlamaConfig") -> str:
     return "flash" if jax.default_backend() == "tpu" else "blockwise"
 
 
+#: the paged read walks a block table in slabs, under a running softmax,
+#: where what the whole-window gather would hold at once (K and V of every
+#: row's whole table, or the scores over them) passes this many bytes; a
+#: window layer always walks
+WALK_MIN_BYTES = 1 << 28
+#: the scores of one slab of the walk, in elements
+WALK_SLAB_SCORES = 1 << 26
+
+
+def _walk_pages(q, pool_k, pool_v, tables, pos, window: int, ring: bool,
+                sm_scale: float, dtype):
+    """Attention of ``q`` (b, h_kv, rep, s, d) at positions ``pos`` (b, s)
+    over the pages ``tables`` (b, entries) names in ``pool_k``/``pool_v``
+    (pages, P, h_kv, d), a slab of entries at a time under a running
+    softmax, as far as the longest row of the batch reaches: the trip count
+    is data, and no step holds more than a slab of every row.
+
+    Entry ``e`` of a table stands for block ``e`` of the sequence, or, in a
+    ``ring``, for the one block ``j`` in ``(last - entries, last]`` with
+    ``j % entries == e``, ``last`` the block of the row's highest position
+    in this call.  A key at position j is visible to the query at i iff
+    ``0 <= i - j`` (``< window``, where the layer has one).  A row whose
+    table is all trash (a lane that is not live) is walked over not at
+    all."""
+    b, g, rep, s, d = q.shape
+    ptok, entries = pool_k.shape[1], tables.shape[1]
+    slab = min(max(WALK_SLAB_SCORES // (b * g * rep * s * ptok), 8), 64,
+               entries)
+    padded = -(-entries // slab) * slab
+    tables = jnp.pad(tables, ((0, 0), (0, padded - entries)))
+    last = pos[:, -1] // ptok                               # (b,)
+    reach = jnp.minimum(last + 1, entries) if ring else last + 1
+    reach = jnp.where(jnp.any(tables != 0, axis=1), reach, 0)
+    trips = (jnp.max(reach) + slab - 1) // slab
+
+    def body(i, carry):
+        m, l, acc = carry
+        e = i * slab + jnp.arange(slab)                     # entries
+        tab = jax.lax.dynamic_slice_in_dim(tables, i * slab, slab, axis=1)
+        kblk = pool_k[tab].reshape(b, slab * ptok, g, d)
+        vblk = pool_v[tab].reshape(b, slab * ptok, g, d)
+        if ring:
+            block = last[:, None] - jnp.mod(last[:, None] - e[None, :],
+                                            entries)
+        else:
+            block = jnp.broadcast_to(e[None, :], (b, slab))
+        kv_pos = (block[:, :, None] * ptok + jnp.arange(ptok)).reshape(
+            b, 1, slab * ptok)
+        ahead = pos[:, :, None] - kv_pos                    # (b, s, K)
+        valid = (ahead >= 0) & (kv_pos >= 0) \
+            & jnp.repeat(e < entries, ptok)[None, None, :]
+        if window:
+            valid &= ahead < window
+        scores = jnp.einsum("bgrqd,bkgd->bgrqk", q, kblk.astype(q.dtype),
+                            preferred_element_type=jnp.float32) * sm_scale
+        scores = jnp.where(valid[:, None, None], scores, -1e30)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        # a slab wholly outside a row's window leaves its running max at
+        # -1e30; the row's own key, in a later slab, wipes what is counted
+        # here (alpha = 0)
+        p = jnp.exp(scores - m_new[..., None])
+        l = l * alpha + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bgrqk,bkgd->bgrqd", p.astype(dtype), vblk.astype(dtype),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((b, g, rep, s), -1e30, jnp.float32)
+    m, l, acc = jax.lax.fori_loop(
+        0, trips, body, (m0, jnp.zeros_like(m0),
+                         jnp.zeros((b, g, rep, s, d), jnp.float32)))
+    return (acc / jnp.maximum(l[..., None], 1e-30)).astype(dtype)
+
+
 class Attention(nn.Module):
     cfg: LlamaConfig
+    #: > 0: key j is visible to query i iff ``0 <= i - j < window``
+    window: int = 0
+    #: rotary embedding of q and k (False: no positional embedding)
+    rope: bool = True
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False,
                  block_tables=None):
         cfg = self.cfg
-        head_dim = cfg.dim // cfg.n_heads
+        head_dim = cfg.head_dim or cfg.dim // cfg.n_heads
         dense = _projection(cfg)
         q = dense(cfg.n_heads * head_dim, "wq")(x)
         k = dense(cfg.n_kv_heads * head_dim, "wk")(x)
@@ -336,8 +502,9 @@ class Attention(nn.Module):
         q = q.reshape(b, s, cfg.n_heads, head_dim).transpose(0, 2, 1, 3)
         k = k.reshape(b, s, cfg.n_kv_heads, head_dim).transpose(0, 2, 1, 3)
         v = v.reshape(b, s, cfg.n_kv_heads, head_dim).transpose(0, 2, 1, 3)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        if self.rope:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
 
         if decode:
             if block_tables is not None:
@@ -350,6 +517,9 @@ class Attention(nn.Module):
         impl = _attn_impl(cfg)
         if impl == "ring":
             from ..ops.ring_attention import ring_attention
+            if self.window:
+                raise NotImplementedError(
+                    "ring attention has no sliding window")
             if cfg.n_kv_heads != cfg.n_heads:  # ring path still repeats
                 rep = cfg.n_heads // cfg.n_kv_heads
                 k = jnp.repeat(k, rep, axis=1)
@@ -358,9 +528,10 @@ class Attention(nn.Module):
         elif impl == "flash":
             # flash + blockwise consume grouped KV natively (index-mapped
             # heads — no h/h_kv × HBM blow-up from jnp.repeat)
-            out = flash_attention(q, k, v, True, None)
+            out = flash_attention(q, k, v, True, None, self.window)
         else:
-            out = blockwise_attention(q, k, v, causal=True)
+            out = blockwise_attention(q, k, v, causal=True,
+                                      window=self.window)
         out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * head_dim)
         return dense(cfg.dim, "wo")(out)
 
@@ -431,7 +602,10 @@ class Attention(nn.Module):
             scores = scores * cks.value[:, :, None, None, :]
         scores = scores / (head_dim ** 0.5)
         kv_pos = jnp.arange(cache_len)
-        mask = kv_pos[None, :] <= positions[:, None]      # (s, cache_len)
+        ahead = positions[:, None] - kv_pos[None, :]      # (s, cache_len)
+        mask = ahead >= 0
+        if self.window:
+            mask &= ahead < self.window
         scores = jnp.where(mask[None, None, None], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1)
         if int8_kv:
@@ -473,10 +647,20 @@ class Attention(nn.Module):
         past a slot's reservation (chunk padding, horizon burn-out) land
         there; reads of it are always masked because a reserved prefix
         covers every window position <= the slot's own position.
+
+        A window layer of a model that has full layers too
+        (``kv_window_pool_pages``) has a pool of that size and a short
+        table, a ring: position p is written through entry ``(p // P) %
+        entries``, and the engine, which takes the pages wholly behind the
+        window back while the request runs, has zeroed the entries of the
+        blocks it took and of those it has not given yet.  Its read, and
+        any read too large to gather whole (``WALK_MIN_BYTES``), walks the
+        table in slabs (:func:`_walk_pages`).
         """
         cfg = self.cfg
         ptok = cfg.kv_page_tokens
-        pool_pages = cfg.kv_pool_pages
+        ring = self.window > 0 and cfg.kv_window_pool_pages > 0
+        pool_pages = cfg.kv_window_pool_pages if ring else cfg.kv_pool_pages
         int8_kv = cfg.kv_cache_dtype == "int8"
         store_dtype = jnp.int8 if int8_kv else cfg.dtype
         pk = self.variable("cache", "k", jnp.zeros,
@@ -486,7 +670,9 @@ class Attention(nn.Module):
                            (pool_pages, ptok, cfg.n_kv_heads, head_dim),
                            store_dtype)
         pos = positions.astype(jnp.int32)                   # (b, s)
-        page = jnp.take_along_axis(block_tables, pos // ptok, axis=1)
+        max_blocks = block_tables.shape[1]
+        entry = (pos // ptok) % max_blocks if ring else pos // ptok
+        page = jnp.take_along_axis(block_tables, entry, axis=1)
         offs = pos % ptok                                   # (b, s)
         k_w = k.transpose(0, 2, 1, 3)                       # (b, s, hkv, d)
         v_w = v.transpose(0, 2, 1, 3)
@@ -517,8 +703,23 @@ class Attention(nn.Module):
             pv.value = pv.value.at[page, offs].set(v_w.astype(cfg.dtype))
         # gather the slot windows AFTER the write so a chunk attends to
         # its own earlier tokens (in-chunk causality via the mask below)
-        max_blocks = block_tables.shape[1]
         window = max_blocks * ptok
+        rep = cfg.n_heads // cfg.n_kv_heads
+        qg = q.reshape(b, cfg.n_kv_heads, rep, s, head_dim)
+        whole = b * window * max(
+            2 * cfg.n_kv_heads * head_dim * jnp.dtype(store_dtype).itemsize,
+            4 * cfg.n_heads * s)
+
+        def project(out):                # (b, hkv, rep, s, d) -> (b, s, dim)
+            out = out.reshape(b, cfg.n_heads, s, head_dim)
+            out = out.transpose(0, 2, 1, 3).reshape(
+                b, s, cfg.n_heads * head_dim)
+            return dense(cfg.dim, "wo")(out)
+
+        if self.window or (whole > WALK_MIN_BYTES and not int8_kv):
+            return project(_walk_pages(
+                qg, pk.value, pv.value, block_tables, pos, self.window, ring,
+                head_dim ** -0.5, cfg.dtype))
 
         def gather_window(pool):                     # -> (b, hkv, W, ...)
             g = pool[block_tables]                   # (b, MB, P, hkv, ...)
@@ -527,8 +728,6 @@ class Attention(nn.Module):
 
         kf = gather_window(pk.value)
         vf = gather_window(pv.value)
-        rep = cfg.n_heads // cfg.n_kv_heads
-        qg = q.reshape(b, cfg.n_kv_heads, rep, s, head_dim)
         scores = jnp.einsum("bgrqd,bgkd->bgrqk", qg, kf.astype(qg.dtype),
                             preferred_element_type=jnp.float32)
         if int8_kv:
@@ -544,10 +743,7 @@ class Attention(nn.Module):
         out = jnp.einsum("bgrqk,bgkd->bgrqd", probs, vf.astype(cfg.dtype),
                          preferred_element_type=jnp.float32
                          ).astype(cfg.dtype)
-        out = out.reshape(b, cfg.n_heads, s, head_dim)
-        out = out.transpose(0, 2, 1, 3).reshape(
-            b, s, cfg.n_heads * head_dim)
-        return dense(cfg.dim, "wo")(out)
+        return project(out)
 
 
 class MLP(nn.Module):
@@ -571,6 +767,10 @@ class Block(nn.Module):
     cfg: LlamaConfig
     #: routed experts (and the shared one) in place of the dense SwiGLU
     sparse: bool = False
+    #: the attention's window (0: everything before) and whether it rotates
+    #: q and k: what differs by layer is data of the configuration
+    window: int = 0
+    rope: bool = True
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False,
@@ -580,25 +780,40 @@ class Block(nn.Module):
             from .mla import MLA
             attend = MLA(cfg, name="attention")
         else:
-            attend = Attention(cfg, name="attention")
-        h = x + attend(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions,
-                       decode=decode, block_tables=block_tables)
-        hn = RMSNorm(cfg.norm_eps, name="mlp_norm")(h)
-        if not self.sparse:
-            return h + MLP(cfg, name="mlp")(hn)
-        from .moe import MoEMLP
-        width = cfg.moe_ffn_dim or cfg.ffn_dim
-        y = MoEMLP(dim=cfg.dim, ffn_dim=width, n_experts=cfg.n_experts,
-                   top_k=cfg.moe_top_k, scoring=cfg.moe_scoring,
-                   n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
-                   norm_topk=cfg.moe_norm_topk,
-                   routed_scale=cfg.moe_routed_scale, held=cfg.experts_held,
-                   dtype=cfg.dtype, param_dtype=cfg.store_dtype,
-                   name="moe_mlp")(hn)
-        if cfg.n_shared_experts:
-            y = y + MLP(cfg, width=cfg.n_shared_experts * width,
-                        name="shared_expert")(hn)
-        return h + y
+            attend = Attention(cfg, window=self.window, rope=self.rope,
+                               name="attention")
+        n = _norm(cfg, "attn_norm")(x)
+        attn = attend(n, positions, decode=decode, block_tables=block_tables)
+        if cfg.parallel_block:       # one norm for both
+            return x + attn + _feed_forward(cfg, self.sparse, n)
+        h = x + attn
+        return h + _feed_forward(cfg, self.sparse, _norm(cfg, "mlp_norm")(h))
+
+
+def _feed_forward(cfg: LlamaConfig, sparse: bool, hn):
+    """A block's feed-forward over its normed input: the dense SwiGLU, or the
+    routed experts beside the shared ones.  Called inside ``Block``'s own
+    scope (a method would put its name into every scope under it, the
+    kernels' among them)."""
+    if not sparse:
+        return MLP(cfg, name="mlp")(hn)
+    from .moe import MoEMLP
+    width = cfg.moe_ffn_dim or cfg.ffn_dim
+    y = MoEMLP(dim=cfg.dim, ffn_dim=width, n_experts=cfg.n_experts,
+               top_k=cfg.moe_top_k, scoring=cfg.moe_scoring,
+               n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
+               norm_topk=cfg.moe_norm_topk,
+               routed_scale=cfg.moe_routed_scale, held=cfg.experts_held,
+               dtype=cfg.dtype, param_dtype=cfg.store_dtype,
+               name="moe_mlp")(hn)
+    if cfg.n_shared_experts:
+        shared = MLP(cfg, width=cfg.n_shared_experts * width,
+                     name="shared_expert")(hn)
+        if cfg.shared_expert_scale != 1.0:
+            shared = shared * jnp.asarray(cfg.shared_expert_scale,
+                                          shared.dtype)
+        y = y + shared
+    return y
 
 
 class LlamaLM(nn.Module):
@@ -615,12 +830,15 @@ class LlamaLM(nn.Module):
         ``tokens[:, 0]`` — the caller owns position bookkeeping so the
         jitted single-token step stays stateless.  ``block_tables``
         ((B, max_blocks) int32, traced) selects the paged-pool decode
-        path (``kv_page_tokens``/``kv_pool_pages`` on the config).
+        path (``kv_page_tokens``/``kv_pool_pages`` on the config); for a
+        model with ``kv_window_pool_pages`` it is ``{"full": ...,
+        "window": ...}``, a table per kind of layer.
         ``return_hidden=True`` returns final-norm hidden states without
         the lm_head projection (the streaming cross-entropy path)."""
         cfg = self.cfg
-        x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
-                     param_dtype=cfg.store_dtype, name="tok_embed")(tokens)
+        embed = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
+                         param_dtype=cfg.store_dtype, name="tok_embed")
+        x = embed(tokens)
         positions = jnp.arange(tokens.shape[-1])
         if start_pos is not None:
             start_pos = jnp.asarray(start_pos)
@@ -640,10 +858,14 @@ class LlamaLM(nn.Module):
         else:   # "full": recompute block activations in backward — HBM for
             mk_block = nn.remat(Block, static_argnums=(3,))  # FLOPs
         for i in range(cfg.n_layers):
-            block = mk_block(cfg, sparse=cfg.sparse_layer(i),
-                             name=f"layer_{i}")
-            x = block(x, positions, decode, block_tables)
-        x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
+            window = cfg.layer_window(i)
+            block = mk_block(cfg, sparse=cfg.sparse_layer(i), window=window,
+                             rope=cfg.layer_rope(i), name=f"layer_{i}")
+            tables = block_tables
+            if isinstance(tables, dict):
+                tables = tables["window" if window else "full"]
+            x = block(x, positions, decode, tables)
+        x = _norm(cfg, "final_norm")(x)
         if return_hidden:
             # streaming cross-entropy path (ops/xent.py): the caller fuses
             # the lm_head matmul into a vocab-chunked loss instead of
@@ -651,8 +873,15 @@ class LlamaLM(nn.Module):
             # init must run the default path so lm_head params exist.
             return x
         # kernel stored in store_dtype, compute still f32 (logit precision)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-                          param_dtype=cfg.store_dtype, name="lm_head")(x)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
+                                embed.embedding.astype(jnp.float32))
+        else:
+            logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                              dtype=jnp.float32, param_dtype=cfg.store_dtype,
+                              name="lm_head")(x)
+        if cfg.logit_scale != 1.0:
+            logits = logits * cfg.logit_scale
         return logits
 
 
@@ -672,6 +901,12 @@ _PUBLISHED_KEYS = {
     "n_group": "moe_n_group", "topk_group": "moe_topk_group",
     "norm_topk_prob": "moe_norm_topk",
     "routed_scaling_factor": "moe_routed_scale",
+    # the cohere2_moe family's names
+    "head_dim": "head_dim", "layer_norm_eps": "norm_eps",
+    "num_experts": "n_experts", "num_shared_experts": "n_shared_experts",
+    "expert_selection_fn": "moe_scoring", "sliding_window": "sliding_window",
+    "use_parallel_block": "parallel_block", "logit_scale": "logit_scale",
+    "tie_word_embeddings": "tie_embeddings",
 }
 
 
@@ -705,7 +940,63 @@ def config_from_published(published) -> dict:
             "hidden_act", "silu") != "silu":
         raise ValueError("attention_bias and activations other than silu "
                          "are not computed here")
+    if published.get("layer_types"):
+        out["layer_types"] = tuple(published["layer_types"])
+        if "sliding_attention" not in out["layer_types"]:
+            out.pop("sliding_window", None)
+    else:       # a window no layer is said to have
+        out.pop("sliding_window", None)
+    out.update(_unnamed_fields(published))
     return out
+
+
+def _unnamed_fields(published: dict) -> dict:
+    """The fields that a ``config.json`` states by more than one key's name,
+    read from the keys it carries and from no family's name: a
+    ``layer_norm_eps`` with no ``rms_norm_eps`` is a norm that subtracts the
+    mean; ``shared_expert_combination_strategy`` says how the shared experts
+    join the routed sum; ``rope_full_layers`` (no published key: a
+    configuration states it as assumed) says whether the full-attention layers
+    carry the rotary embedding.  A key that asks for what is not computed
+    raises by name; the ``prefix_dense_*`` keys do nothing without leading
+    dense layers and are passed over."""
+    if published.get("use_qk_norm"):
+        raise ValueError("use_qk_norm: norms on q and k are not computed "
+                         "here")
+    if float(published.get("rotary_pct", 1)) != 1.0:
+        raise ValueError(f"rotary_pct {published['rotary_pct']!r}: the rotary "
+                         "embedding turns every pair of a head, or none")
+    if not published.get("use_gated_activation", True):
+        raise ValueError("use_gated_activation false: only the gated "
+                         "(SwiGLU) feed-forward is computed here")
+    if published.get("position_embedding_type", "rope_gptj") != "rope_gptj":
+        raise ValueError(
+            f"position_embedding_type "
+            f"{published['position_embedding_type']!r}: _rope turns "
+            "interleaved pairs (rope_gptj)")
+    if int(published.get("first_k_dense_replace") or 0) > 0 and any(
+            k.startswith("prefix_dense_") for k in published):
+        raise ValueError(
+            "first_k_dense_replace > 0 with prefix_dense_* keys: leading "
+            "dense layers of a width and a window pattern of their own are "
+            "not computed here")
+    fields = {}
+    if (published.get("layer_norm_eps") is not None
+            and published.get("rms_norm_eps") is None):
+        fields["norm_kind"] = "layer"
+    if published.get("rope_full_layers") is not None:
+        fields["rope_full_layers"] = bool(published["rope_full_layers"])
+    how = published.get("shared_expert_combination_strategy")
+    if how is not None:
+        if how != "average":
+            raise ValueError(
+                f"shared_expert_combination_strategy {how!r}: only "
+                "'average' (the mean of the shared experts' outputs, added "
+                "to the routed sum) is computed here")
+        if published.get("num_shared_experts"):
+            fields["shared_expert_scale"] = 1.0 / int(
+                published["num_shared_experts"])
+    return fields
 
 
 def config_from_args(args, vocab: Optional[int] = None) -> LlamaConfig:

@@ -41,7 +41,8 @@ def _block_scores(q, k, sm_scale):
 
 def blockwise_attention(q, k, v, causal: bool = True,
                         sm_scale: Optional[float] = None,
-                        block_k: int = 256, q_positions=None):
+                        block_k: int = 256, q_positions=None,
+                        window: int = 0):
     """Streaming-softmax attention.
 
     q, k: (..., S, D); v: (..., S, Dv), Dv = D unless the values have a
@@ -57,6 +58,9 @@ def blockwise_attention(q, k, v, causal: bool = True,
     every query row among the keys, where the queries are not the keys'
     own first S rows (a prefill chunk over a cache window); the causal
     mask is then ``key index <= position``.
+
+    ``window`` > 0 (causal only): a query at position i sees key j iff
+    ``0 <= i - j < window``.
     """
     if (q.ndim == 4 and k.ndim == 4 and k.shape[1] != q.shape[1]):
         b, h, s_q_, d_ = q.shape
@@ -69,8 +73,11 @@ def blockwise_attention(q, k, v, causal: bool = True,
                 q_positions, (b, h, s_q_)).reshape(b, h_kv, rep, s_q_)
         out = blockwise_attention(qg, k[:, :, None], v[:, :, None],
                                   causal=causal, sm_scale=sm_scale,
-                                  block_k=block_k, q_positions=q_positions)
+                                  block_k=block_k, q_positions=q_positions,
+                                  window=window)
         return out.reshape(b, h, s_q_, v.shape[-1])
+    if window and not causal:
+        raise ValueError("a window is defined for causal attention only")
     *lead, s_q, d = q.shape
     s_k, d_v = k.shape[-2], v.shape[-1]
     if sm_scale is None:
@@ -103,11 +110,16 @@ def blockwise_attention(q, k, v, causal: bool = True,
         valid = kv_pos < s_k
         if causal:
             valid = valid[None, :] & (kv_pos[None, :] <= q_pos[..., None])
+            if window:
+                valid = valid & (q_pos[..., None] - kv_pos[None, :] < window)
             scores = jnp.where(valid, scores, NEG_INF)
         else:
             scores = jnp.where(valid, scores, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
         alpha = jnp.exp(m - m_new)
+        # a block that lies wholly behind a row's window leaves its running
+        # max at NEG_INF: the row's own key, in a later block, is what
+        # wipes the ones counted here (alpha = 0)
         p = jnp.exp(scores - m_new[..., None])
         l_new = l * alpha + jnp.sum(p, axis=-1)
         acc_new = acc * alpha[..., None] + jnp.einsum(
@@ -127,7 +139,7 @@ def blockwise_attention(q, k, v, causal: bool = True,
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_ref, l_ref, acc_ref, *,
                       block_q: int, block_k: int, sm_scale: float,
-                      causal: bool, seq_k: int):
+                      causal: bool, seq_k: int, window: int = 0):
     """Grid: (batch*heads, q_blocks, k_blocks); k innermost ("arbitrary").
     Scratch m/l/acc persist across the k dimension for one (bh, qi) pair.
     Also emits the per-row logsumexp (m + log l) for the backward pass.
@@ -153,6 +165,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     # skip its matmuls entirely (halves the work for causal attention)
     if causal:
         live = kj * block_k <= qi * block_q + block_q - 1
+        if window:      # nor does one wholly behind the block's first window
+            live &= kj * block_k + block_k - 1 > qi * block_q - window
     else:
         live = kj >= 0
 
@@ -173,6 +187,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         mask = kv_pos < seq_k
         if causal:
             mask = mask & (kv_pos <= q_pos)
+            if window:
+                mask = mask & (q_pos - kv_pos < window)
         scores = jnp.where(mask, scores, NEG_INF)
 
         m_prev = m_ref[:]                           # (block_q, 1)
@@ -277,9 +293,10 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = True,
                                block_q: Optional[int] = None,
                                block_k: Optional[int] = None,
                                return_lse: bool = False,
-                               interpret: bool = False):
+                               interpret: bool = False, window: int = 0):
     """q: (B, H, S, D); k, v: (B, H_kv, S, D) with H_kv | H (GQA served by
-    index-mapping, no KV repeat) → (B, H, S, D) [+ logsumexp (B, H, S)]."""
+    index-mapping, no KV repeat) → (B, H, S, D) [+ logsumexp (B, H, S)].
+    ``window`` > 0: key j is visible to query i iff ``0 <= i - j < window``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -301,7 +318,8 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = True,
 
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k,
-        sm_scale=float(sm_scale), causal=causal, seq_k=s_k)
+        sm_scale=float(sm_scale), causal=causal, seq_k=s_k,
+        window=int(window))
 
     out, lse = pl.pallas_call(
         kernel,
@@ -340,7 +358,8 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = True,
 # -- Pallas TPU backward kernels ---------------------------------------------
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_acc, *, block_q: int, block_k: int,
-                         sm_scale: float, causal: bool, seq_k: int):
+                         sm_scale: float, causal: bool, seq_k: int,
+                         window: int = 0):
     """dQ pass.  Grid: (bh, q_blocks, k_blocks), k innermost; dq accumulates
     in scratch across k for one (bh, qi)."""
     import jax.experimental.pallas as pl
@@ -355,6 +374,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     if causal:
         live = kj * block_k <= qi * block_q + block_q - 1
+        if window:      # nor does one wholly behind the block's first window
+            live &= kj * block_k + block_k - 1 > qi * block_q - window
     else:
         live = kj >= 0
 
@@ -376,6 +397,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         mask = kv_pos < seq_k
         if causal:
             mask = mask & (kv_pos <= q_pos)
+            if window:
+                mask = mask & (q_pos - kv_pos < window)
         p = jnp.where(mask, jnp.exp(scores - lse), 0.0)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * sm_scale
@@ -390,7 +413,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
                           block_k: int, sm_scale: float, causal: bool,
-                          seq_k: int, seq_q: int):
+                          seq_k: int, seq_q: int, window: int = 0):
     """dK/dV pass.  Grid: (bh, k_blocks, q_blocks), q innermost; dk/dv
     accumulate in scratch across q for one (bh, kj)."""
     import jax.experimental.pallas as pl
@@ -407,6 +430,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if causal:
         # q blocks strictly above the diagonal band see none of this k block
         live = qi * block_q + block_q - 1 >= kj * block_k
+        if window:      # nor do q blocks wholly past this k block's windows
+            live &= qi * block_q - (kj * block_k + block_k - 1) < window
     else:
         live = qi >= 0
 
@@ -432,6 +457,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         mask = (kv_pos < seq_k) & (q_pos < seq_q)
         if causal:
             mask = mask & (kv_pos <= q_pos)
+            if window:
+                mask = mask & (q_pos - kv_pos < window)
         p = jnp.where(mask, jnp.exp(scores - lse), 0.0)
         dv_acc[:] += jnp.dot(p.astype(do.dtype).T, do,
                              preferred_element_type=jnp.float32)
@@ -450,7 +477,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = True,
                                sm_scale: Optional[float] = None,
                                block_q: Optional[int] = None,
                                block_k: Optional[int] = None,
-                               interpret: bool = False):
+                               interpret: bool = False, window: int = 0):
     """Flash-attention backward: (dq, dk, dv), no S×S materialization and no
     forward recompute beyond the score blocks (reference capability target:
     the HF flash-attn patch at ``train/llm/models/attention.py:30``).
@@ -482,7 +509,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = True,
     kv_row = _kv_head_map(b, h, h_kv)
 
     common = dict(block_q=block_q, block_k=block_k, sm_scale=float(sm_scale),
-                  causal=causal, seq_k=s_k)
+                  causal=causal, seq_k=s_k, window=int(window))
     common_kv = dict(common, seq_q=s_q)
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
     k_spec = pl.BlockSpec((1, block_k, d),
@@ -535,13 +562,15 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = True,
 
 
 # -- public entry with custom vjp --------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = True,
-                    sm_scale: Optional[float] = None):
+                    sm_scale: Optional[float] = None, window: int = 0):
     """Fused attention: Pallas forward + Pallas flash backward on TPU
     (logsumexp saved from the forward, no S×S materialization and no full
-    recompute), blockwise-scan semantics + blockwise VJP everywhere else."""
-    return _fa_fwd(q, k, v, causal, sm_scale)[0]
+    recompute), blockwise-scan semantics + blockwise VJP everywhere else.
+    ``window`` > 0 (causal): key j is visible to query i iff
+    ``0 <= i - j < window``."""
+    return _fa_fwd(q, k, v, causal, sm_scale, window)[0]
 
 
 def _on_tpu() -> bool:
@@ -572,25 +601,26 @@ def _note_impl(impl: str, q, k) -> None:
              tuple(k.shape))
 
 
-def _fa_fwd(q, k, v, causal, sm_scale):
+def _fa_fwd(q, k, v, causal, sm_scale, window=0):
     # the kernels take one head width: values of another take the scan
     if v.shape[-1] == q.shape[-1] and _use_pallas(k.shape[2], k.shape[3]):
         _note_impl("pallas", q, k)
         out, lse = flash_attention_fwd_pallas(q, k, v, causal, sm_scale,
-                                              return_lse=True)
+                                              return_lse=True, window=window)
         return out, (q, k, v, out, lse)
     _note_impl("blockwise", q, k)
-    out = blockwise_attention(q, k, v, causal, sm_scale)
+    out = blockwise_attention(q, k, v, causal, sm_scale, window=window)
     return out, (q, k, v, None, None)
 
 
-def _fa_bwd(causal, sm_scale, res, g):
+def _fa_bwd(causal, sm_scale, window, res, g):
     q, k, v, out, lse = res
     if lse is not None:
         return flash_attention_bwd_pallas(q, k, v, out, lse, g, causal,
-                                          sm_scale)
+                                          sm_scale, window=window)
     _, vjp = jax.vjp(
-        lambda q, k, v: blockwise_attention(q, k, v, causal, sm_scale),
+        lambda q, k, v: blockwise_attention(q, k, v, causal, sm_scale,
+                                            window=window),
         q, k, v)
     return vjp(g)
 

@@ -76,9 +76,9 @@ class _UnservableError(Exception):
     or parking would deadlock the engine."""
 
 
-#: what a staged slot row asks of ``slot_rows``: write the whole row, or
-#: only end the lane (``left`` := 0)
-_ROW_PUT, _ROW_MASK = 1, 2
+#: what a staged slot row asks of ``slot_rows``: write the whole row, only
+#: end the lane (``left`` := 0), or only write the window table
+_ROW_PUT, _ROW_MASK, _ROW_TABLE = 1, 2, 3
 
 #: how the TPU's compiler is asked to build the paged tick and chunk
 #: programs.  By default it prefetches every weight matrix and adapter bank
@@ -137,6 +137,12 @@ class _Slot:
                  # slot's block-table reservation size
                  "prefilling", "pf_ids", "pf_next", "pf_n", "pf_sub",
                  "pf_atok", "pf_acc", "n_blocks",
+                 # the window pool (a model with window and full layers):
+                 # the slot holds the pages of blocks ``w_first`` ..
+                 # ``w_next - 1``, at most ``w_keep`` of the request's
+                 # ``w_end``; ``dpos`` is the position the next tick to be
+                 # dispatched writes (``pos`` follows delivery, behind it)
+                 "w_first", "w_next", "w_keep", "w_end", "dpos",
                  # fedslo request-lifecycle telemetry (host monotonic
                  # clocks, engine-thread-confined like the decode state)
                  "t_submit", "t_admit", "t_prefill_end", "t_first",
@@ -163,6 +169,8 @@ class _Slot:
         self.pf_atok = None
         self.pf_acc = None
         self.n_blocks = 0
+        self.w_first = self.w_next = self.w_keep = self.w_end = 0
+        self.dpos = 0
         self.t_submit = 0.0
         self.t_admit: Optional[float] = None
         self.t_prefill_end = 0.0
@@ -187,6 +195,7 @@ class ContinuousBatchingEngine:
                  hist_labels: int = 8,
                  slo_rules: Optional[List[Dict[str, Any]]] = None,
                  kv_page_tokens: int = 16, kv_pool_pages: int = 0,
+                 kv_window_pool_pages: int = 0,
                  prefill_chunk_tokens: int = 0, prefill_lanes: int = 1,
                  adapter_cache_slots: int = 0,
                  adapter_store_dir: Optional[str] = None):
@@ -299,10 +308,40 @@ class ContinuousBatchingEngine:
         pool_pages = int(kv_pool_pages) or \
             (1 + self.n_slots * self.blocks_cap)
         self.kv_pool_pages = pool_pages
-        self.paged_model = type(model)(dataclasses.replace(
-            cfg, kv_page_tokens=ptok, kv_pool_pages=pool_pages))
+        # a pool per kind of layer (docs/SERVING.md, "A pool per kind of
+        # layer"): a model with window and full layers in one stack keeps
+        # the full layers' K/V in ``page_pool`` under the rule above, and
+        # the window layers' in ``window_pool``.  There a slot holds at
+        # most ``window_blocks`` pages (window + overhang + a page of
+        # tokens), its table is a ring (block j at entry j % window_blocks),
+        # and before every chunk and tick the pages wholly behind
+        # ``position - window + 1`` go back to the free list and as many
+        # are taken for the blocks ahead (``_slide_window``).  A model of
+        # one kind of layer has the one pool.
+        self.window = int(cfg.sliding_window) \
+            if getattr(cfg, "mixed_attention", False) else 0
+        self.window_pool: Optional[PagedBlockPool] = None
+        self.window_blocks = 0
+        geometry = {"kv_page_tokens": ptok, "kv_pool_pages": pool_pages}
+        if self.window:
+            if prefix_cache_slots:
+                raise PagedKVUnsupportedError(
+                    "prefix pages are not shared over a window pool: a "
+                    "lent page would be taken back behind the first "
+                    "sharer's window while a later one still reads it "
+                    "(prefix_cache_slots=0 for a model with window and "
+                    "full layers)")
+            self.window_blocks = math.ceil(
+                (self.window + overhang) / ptok) + 1
+            window_pages = int(kv_window_pool_pages) or (
+                1 + self.n_slots * min(self.window_blocks, self.blocks_cap))
+            geometry["kv_window_pool_pages"] = window_pages
+            self.window_pool = PagedBlockPool(window_pages)
+        self._window_pages_freed = 0
+        self.paged_model = type(model)(dataclasses.replace(cfg, **geometry))
         self.page_pool = PagedBlockPool(pool_pages)
         self._btabs = np.zeros((self.n_slots, self.max_blocks), np.int32)
+        self._wtabs = np.zeros((self.n_slots, self.window_blocks), np.int32)
 
         # prefix_cache_slots > 0: admission shares *pages* for shared
         # prompt prefixes: PagedPrefixCache (LRU, longest common prefix in
@@ -326,6 +365,17 @@ class ContinuousBatchingEngine:
         # only the TPU's compiler knows the options
         options = (PAGED_TPU_COMPILER_OPTIONS
                    if jax.default_backend() == "tpu" else None)
+        key_words = int(np.asarray(jax.random.PRNGKey(0)).size)
+        two_pools = self.window_pool is not None
+
+        def tables(state, pick):
+            """What the model takes as ``block_tables``: ``pick`` of the
+            carried table, or of one a kind of layer where there are two
+            pools."""
+            if not two_pools:
+                return pick(state["btabs"])
+            return {"full": pick(state["btabs"]),
+                    "window": pick(state["wtabs"])}
 
         def paged_tick(params, lora_slots, pool, state):
             """``horizon`` scanned steps of every lane from the slot state
@@ -347,7 +397,7 @@ class ContinuousBatchingEngine:
             # in HBM; per-matmul dequant fuses) — no-op for plain trees
             params = dequantize_params(params, wdtype)
             live = state["left"] > 0
-            btabs = jnp.where(live[:, None], state["btabs"], 0)
+            btabs = tables(state, lambda t: jnp.where(live[:, None], t, 0))
 
             def body(carry, _):
                 pool, toks, poss, keys = carry
@@ -394,7 +444,9 @@ class ContinuousBatchingEngine:
             # one fixed-shape (1, C) prefill chunk for one slot.
             # ``chunk`` is everything the host knows of it, one
             # upload: the C token ids, then ``start idx slot pos
-            # left`` and the sample key's words.  The sample index is
+            # left``, the sample key's words and, where the window
+            # layers have a pool of their own, the slot's window table
+            # as the host has just slid it.  The sample index is
             # TRACED so intermediate chunks (token discarded) and the
             # final chunk (token at n-1-chunk_start) ride one compiled
             # program; the slot's block table and temperature are its
@@ -408,14 +460,18 @@ class ContinuousBatchingEngine:
             # and the final chunk's one read-back brings the request's
             params = dequantize_params(params, wdtype)
             start, idx, slot, pos, left = (chunk[C + j] for j in range(5))
-            key = jax.lax.bitcast_convert_type(chunk[C + 5:], jnp.uint32)
+            key = jax.lax.bitcast_convert_type(
+                chunk[C + 5:C + 5 + key_words], jnp.uint32)
+            if two_pools:       # the table before the slot's row is read
+                state = dict(state, wtabs=state["wtabs"].at[slot].set(
+                    chunk[C + 5 + key_words:]))
             variables = {"params": params, "cache": pool}
             if lora is not None:
                 variables["lora"] = lora
             logits, mut = pm.apply(
                 variables, chunk[None, :C], decode=True,
                 start_pos=start[None],
-                block_tables=state["btabs"][slot][None],
+                block_tables=tables(state, lambda t: t[slot][None]),
                 mutable=["cache", COUNTERS])
             tok = _sample_live(logits[0, idx], key, state["temps"][slot],
                                self.top_k, self.top_p)
@@ -439,15 +495,16 @@ class ContinuousBatchingEngine:
         # (docs/SERVING.md, "The loop"): one row a slot, on the device.
         # The host writes a row only where an admission or a late finish
         # changed it, all of an iteration's changes in one staged array
-        # ``[block table | tok pos left temp aid | key words | op]``.
-        key_words = int(np.asarray(jax.random.PRNGKey(0)).size)
+        # ``[block table | window table | tok pos left temp aid | key
+        # words | op]``.
         blocks = self.max_blocks
+        tabs = blocks + self.window_blocks
 
         @partial(jax.jit, donate_argnums=(0,))
         def slot_rows(state, rows):
             op = rows[:, -1]
             put = op == _ROW_PUT
-            cols = rows[:, blocks:]
+            cols = rows[:, tabs:]
             new = {"toks": cols[:, 0], "poss": cols[:, 1],
                    "left": cols[:, 2],
                    "temps": jax.lax.bitcast_convert_type(
@@ -456,10 +513,15 @@ class ContinuousBatchingEngine:
                    "keys": jax.lax.bitcast_convert_type(
                        cols[:, 5:5 + key_words], jnp.uint32),
                    "btabs": rows[:, :blocks]}
+            if two_pools:
+                new["wtabs"] = rows[:, blocks:tabs]
             out = {name: jnp.where(put.reshape((-1,) + (1,) * (old.ndim - 1)),
                                    new[name], old)
                    for name, old in state.items()}
             out["left"] = jnp.where(op == _ROW_MASK, 0, out["left"])
+            if two_pools:
+                out["wtabs"] = jnp.where((op == _ROW_TABLE)[:, None],
+                                         new["wtabs"], out["wtabs"])
             return out
 
         self._slot_rows = slot_rows
@@ -470,9 +532,11 @@ class ContinuousBatchingEngine:
                      "temps": jnp.zeros(n, jnp.float32),
                      "keys": jnp.zeros((n, key_words), jnp.uint32),
                      "btabs": jnp.zeros((n, blocks), jnp.int32)}
+        if two_pools:
+            self._dev["wtabs"] = jnp.zeros((n, self.window_blocks), jnp.int32)
         if self.registry is not None:
             self._dev["aids"] = jnp.zeros(n, jnp.int32)
-        self._rows = np.zeros((n, blocks + 6 + key_words), np.int32)
+        self._rows = np.zeros((n, tabs + 6 + key_words), np.int32)
         self._rows_staged = False
         # the dispatch whose results the host has not read yet, as
         # ``[tokens, [(slot, queue)] of its lanes, first tokens]``, and the
@@ -489,7 +553,9 @@ class ContinuousBatchingEngine:
         # materialize the page pool from the chunk program's shape
         # (eval_shape only)
         chunk0 = jnp.zeros((1, self.prefill_chunk), jnp.int32)
-        btab0 = jnp.zeros((1, self.max_blocks), jnp.int32)
+        btab0 = tables({"btabs": jnp.zeros((1, self.max_blocks), jnp.int32),
+                        "wtabs": jnp.zeros((1, self.window_blocks),
+                                           jnp.int32)}, lambda t: t)
 
         def _shape_probe(p):
             variables = {"params": dequantize_params(p, wdtype)}
@@ -504,16 +570,18 @@ class ContinuousBatchingEngine:
         self._pool = jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes["cache"])
         # what one cached token costs over all layers, whatever a page
-        # holds (K and V rows of every kv head; one latent row)
+        # holds (K and V rows of every kv head; one latent row) and
+        # whichever pool the layer's pages lie in
         self._kv_bytes_per_token = sum(
-            p.nbytes for p in jax.tree_util.tree_leaves(self._pool)
-        ) // (pool_pages * ptok)
+            p.nbytes // (p.shape[0] * ptok)
+            for p in jax.tree_util.tree_leaves(self._pool))
         # sparse layers: the chunk program's first accumulator
         self._moe_layers = sum(
             cfg.sparse_layer(i) for i in range(cfg.n_layers))
         self._chunk_acc0 = jnp.zeros((5,), jnp.int32) \
             if self._moe_layers else None
-        self._chunk_words = self.prefill_chunk + 5 + key_words
+        self._chunk_words = self.prefill_chunk + 5 + key_words \
+            + self.window_blocks
         self._host_device = jax.devices("cpu")[0]
 
         self._slots = [_Slot() for _ in range(self.n_slots)]
@@ -742,11 +810,24 @@ class ContinuousBatchingEngine:
         admission knows (it leaves token, position and steps to the
         request's final chunk, which writes them on the device)."""
         row = self._rows[slot]
+        tabs = self.max_blocks + self.window_blocks
         row[:self.max_blocks] = self._btabs[slot]
-        row[self.max_blocks:-1] = (
+        row[self.max_blocks:tabs] = self._wtabs[slot]
+        row[tabs:-1] = (
             tok, pos, left, np.float32(temp).view(np.int32), aid,
             *np.asarray(key).view(np.int32))
         row[-1] = _ROW_PUT
+        self._rows_staged = True  # fedrace: disable=unguarded-shared-write
+
+    def _stage_table(self, slot: int) -> None:
+        """Stage ``slot``'s window table alone, as ``_slide_window`` has
+        just left it: the tick's own token, position and key stay the
+        device's."""
+        row = self._rows[slot]
+        row[self.max_blocks:self.max_blocks + self.window_blocks] = \
+            self._wtabs[slot]
+        if row[-1] == 0:
+            row[-1] = _ROW_TABLE
         self._rows_staged = True  # fedrace: disable=unguarded-shared-write
 
     def _mask_lane(self, slot: int) -> None:
@@ -787,6 +868,11 @@ class ContinuousBatchingEngine:
                 [int(p) for p in self._btabs[i, :s.n_blocks]])
             self._btabs[i, :] = 0  # fedrace: disable=unguarded-shared-write
             s.n_blocks = 0
+        if s.w_next > s.w_first:      # what the window still holds
+            held = self._wtabs[i][self._wtabs[i] != 0]
+            self.window_pool.release([int(p) for p in held])
+            self._wtabs[i, :] = 0  # fedrace: disable=unguarded-shared-write
+            s.w_first = s.w_next = 0
         if s.q is not None:
             s.q.put(None)
         s.q = None
@@ -872,7 +958,9 @@ class ContinuousBatchingEngine:
     def _reserve_pages(self, req: dict, slot: int) -> None:
         """Wire ``slot``'s block table: longest shareable prefix pages
         (incref'd) + fresh private pages for the rest of the request's
-        worst-case window.  Raises :class:`PageExhaustedError` when the
+        worst-case window; in the window pool, where the model has one, the
+        first ``min(that, window_blocks)`` blocks' pages: room in both, or
+        nothing is taken.  Raises :class:`PageExhaustedError` when a
         pool is dry (caller parks) and :class:`_UnservableError` when the
         reservation can never fit (caller fails the request open)."""
         ids = req["prompt_ids"]
@@ -884,6 +972,17 @@ class ContinuousBatchingEngine:
             raise _UnservableError(
                 f"request needs {need_blocks} pages; pool has "
                 f"{self.page_pool.n_pages - 1} usable")
+        keep = min(need_blocks, self.window_blocks)
+        wpool = self.window_pool
+        if wpool is not None:
+            if keep > wpool.n_pages - 1:
+                raise _UnservableError(
+                    f"request needs {keep} window pages; pool has "
+                    f"{wpool.n_pages - 1} usable")
+            if not wpool.can_reserve(keep):   # before the other pool gives
+                wpool.stats["exhausted"] += 1
+                raise PageExhaustedError(
+                    f"need {keep} window pages, {wpool.pages_free} free")
         atok = req.get("adapter_token")
         full, shared = (self.prefix_cache.lookup(ids, self.raw_params, atok)
                         if self.prefix_cache is not None and n > 0
@@ -903,7 +1002,10 @@ class ContinuousBatchingEngine:
         self._btabs[slot, :] = 0  # fedrace: disable=unguarded-shared-write
         self._btabs[slot, :full] = shared
         self._btabs[slot, full:need_blocks] = pages
-        req["_kv"] = (full, need_blocks)
+        if wpool is not None:       # blocks 0 .. keep - 1, entry j for j
+            self._wtabs[slot, :] = 0  # fedrace: disable=unguarded-shared-write
+            self._wtabs[slot, :keep] = wpool.reserve(keep)
+        req["_kv"] = (full, need_blocks, keep)
         with self._stats_lock:  # kv_stats() reads from caller threads
             self._pages_shared += full
             self._pages_private += priv
@@ -916,7 +1018,7 @@ class ContinuousBatchingEngine:
         t_admit = time.monotonic()
         ids = req["prompt_ids"]
         n = len(ids)
-        full, need_blocks = req.pop("_kv")
+        full, need_blocks, keep = req.pop("_kv")
         # same split sequence as the single-request path: sub samples the
         # first token (on the final chunk), key carries into decode.  On
         # the host's own device: the same integers, and no wait behind the
@@ -941,6 +1043,8 @@ class ContinuousBatchingEngine:
         s.pf_atok = req.get("adapter_token")
         s.pf_acc = self._chunk_acc0
         s.n_blocks = need_blocks
+        if self.window_pool is not None:
+            s.w_first, s.w_next, s.w_keep, s.w_end = 0, keep, keep, need_blocks
         s.t_submit = req.get("t_submit", t_admit)
         s.t_admit = t_admit
         s.t_prefill_end = t_admit
@@ -951,6 +1055,35 @@ class ContinuousBatchingEngine:
         s.traceparent = req.get("traceparent")
         s.request = req.get("request")
         self._stage_row(slot, key, req["temperature"], s.adapter_row)
+
+    def _slide_window(self, i: int, s: "_Slot", lo: int):
+        """Slide slot ``i``'s window table to a program whose lowest query
+        position is ``lo`` (a chunk's start, a tick's position): the pages
+        of the blocks wholly behind ``lo - window + 1``, which no later
+        program of the slot reads, go back to the free list and their ring
+        entries to the trash page, and as many pages are taken for the
+        blocks ahead, up to the request's last, so that the slot holds
+        ``w_keep`` blocks or all that are left.  It never holds more than
+        it did, so the take cannot fail.  Host book-keeping in dispatch
+        order: the device runs the programs in that order too, so a page
+        is written by its next holder only after every program that read
+        it for this one.  Returns whether the table changed, and the pages
+        that went back."""
+        ptok, ring = self.kv_page_tokens, self.window_blocks
+        tab = self._wtabs[i]
+        first = min(max((lo - self.window + 1) // ptok, s.w_first), s.w_next)
+        behind = [j % ring for j in range(s.w_first, first)]
+        if behind:
+            self.window_pool.release([int(tab[e]) for e in behind])
+            tab[behind] = 0
+            with self._stats_lock:
+                self._window_pages_freed += len(behind)
+        upto = min(first + s.w_keep, s.w_end)
+        ahead = [j % ring for j in range(s.w_next, upto)]
+        if ahead:
+            tab[ahead] = self.window_pool.reserve(len(ahead))
+        s.w_first, s.w_next = first, max(upto, s.w_next)
+        return bool(behind or ahead), len(behind)
 
     def _prefill_tick(self) -> None:
         """Run up to ``prefill_lanes`` fixed-shape prefill chunks, one per
@@ -970,18 +1103,22 @@ class ContinuousBatchingEngine:
             final = cs + C >= n
             with tracer.span("serve.chunk", cat="engine", slot=i,
                              request=s.request, start=cs,
-                             tokens=min(C, n - cs), final=int(final)):
-                self._prefill_chunk(tracer, i, s, cs, final)
+                             tokens=min(C, n - cs), final=int(final)) as span:
+                freed = self._prefill_chunk(tracer, i, s, cs, final)
+                if self.window_pool is not None:
+                    span.set(window_pages_freed=freed)
 
     def _prefill_chunk(self, tracer, i: int, s: "_Slot", cs: int,
-                       final: bool) -> None:
+                       final: bool) -> int:
         """One chunk of slot ``i``'s prompt from position ``cs``.  The
         final one flips the slot live: its sampled token goes into the
         slot's row on the device, so the slot joins the tick of this same
         pass, and the host reads the token (and, behind it, what the
-        experts computed over the request's chunks) with that tick's."""
+        experts computed over the request's chunks) with that tick's.
+        Returns the pages the slot's window gave back for this chunk."""
         C = self.prefill_chunk
         n = s.pf_n
+        freed = 0
         with tracer.span("serve.chunk.gather", cat="engine",
                          adapter_row=s.adapter_row):
             lora = (self.registry.lora_for_row(s.adapter_row)
@@ -1000,7 +1137,12 @@ class ContinuousBatchingEngine:
             # the final chunk samples at the prompt's last position
             chunk[C:C + 5] = (cs, max(n - 1 - cs, 0) if final else 0, i, n,
                               s.steps if final else -1)
-            chunk[C + 5:] = s.pf_sub.view(np.int32)
+            words = C + 5 + s.pf_sub.size
+            chunk[C + 5:words] = s.pf_sub.view(np.int32)
+            if self.window_pool is not None:
+                # the table as this chunk finds it rides its upload
+                _, freed = self._slide_window(i, s, cs)
+                chunk[words:] = self._wtabs[i]
             # the page pool is engine-thread-confined like the other
             # decode state (see _stage_row); step_programs reads it at rest
             # fedrace: disable-next-line=unguarded-shared-write
@@ -1013,12 +1155,12 @@ class ContinuousBatchingEngine:
             s.pf_next = cs + C
             if s.pf_acc is not None:
                 s.pf_acc = tok
-            return
+            return freed
         self._firsts.append((i, s.q, tok))
         s.pf_acc = None
         s.prefilling = False
         s.live = True
-        s.pos = n
+        s.pos = s.dpos = n
         s.t_prefill_end = time.monotonic()
         if self.prefix_cache is not None and n > 0:
             fullpages = n // self.kv_page_tokens
@@ -1032,6 +1174,7 @@ class ContinuousBatchingEngine:
                     self.raw_params, s.pf_atok)
         s.pf_ids = None
         s.pf_sub = None
+        return freed
 
     def _admit_one(self, req: dict, slot: int, tracer) -> bool:
         """Admission front door: cache-mode adapter pin (deferred from
@@ -1098,6 +1241,7 @@ class ContinuousBatchingEngine:
             pairs, hit = self._expert_pairs, self._experts_hit
             tiles = self._expert_tiles
             layers = self._moe_layers_ticked
+            freed_early = self._window_pages_freed
         out["pool"] = dict(self.page_pool.stats)
         out["pages_free"] = self.page_pool.pages_free
         out["pool_pages"] = self.page_pool.n_pages
@@ -1105,6 +1249,15 @@ class ContinuousBatchingEngine:
         out["pages_shared"] = shared
         out["pages_private"] = private
         out["kv_bytes_per_token"] = self._kv_bytes_per_token
+        if self.window_pool is not None:
+            # the window layers' pool beside the full layers' (``pool``):
+            # reserved, released and refused (``exhausted``) of its own,
+            # and the pages that went back behind a window, early
+            out["window_pool"] = dict(self.window_pool.stats)
+            out["window_pages_free"] = self.window_pool.pages_free
+            out["window_pool_pages"] = self.window_pool.n_pages
+            out["window_blocks"] = self.window_blocks
+            out["window_pages_freed"] = freed_early
         out["expert_pairs"] = pairs
         out["experts_hit"] = hit
         out["expert_tiles"] = tiles
@@ -1279,6 +1432,11 @@ class ContinuousBatchingEngine:
             tot = shared + self._pages_private
             chunks = self._chunks_total
         tracer.counter("serve.kv_pages_free", self.page_pool.pages_free)
+        if self.window_pool is not None:
+            tracer.counter("serve.kv_pages_free.full",
+                           self.page_pool.pages_free)
+            tracer.counter("serve.kv_pages_free.window",
+                           self.window_pool.pages_free)
         tracer.counter("serve.kv_page_hit_rate",
                        shared / tot if tot else 0.0)
         tracer.counter("serve.prefill_chunks", chunks)
@@ -1318,6 +1476,8 @@ class ContinuousBatchingEngine:
         ahead = int(self._unread is not None)
         with self._tick_span(tracer, live, tracing) as tick:
             with tracer.span("serve.tick.stage", cat="engine"):
+                if self.window_pool is not None:
+                    self._slide_lanes(tick, live)
                 self._sync_rows()
             with tracer.span("serve.tick.dispatch", cat="engine"):
                 if self.registry is not None:
@@ -1345,6 +1505,26 @@ class ContinuousBatchingEngine:
                 self._ticks_ahead += ahead
             tick.set(ahead=ahead)
             self._collect(tracer, tick, rec)
+
+    def _slide_lanes(self, tick, live) -> None:
+        """Before a tick is dispatched: every lane's window table slid to
+        the position the tick writes, the changed tables staged, and on the
+        span what one layer of each kind holds for the tick's lanes once it
+        has written: everything up to the position, and what the window's
+        pages do."""
+        ptok = self.kv_page_tokens
+        full = held = freed = 0
+        for i in live:
+            s = self._slots[i]
+            changed, behind = self._slide_window(i, s, s.dpos)
+            if changed:
+                self._stage_table(i)
+            freed += behind
+            full += s.dpos + 1
+            held += s.dpos + 1 - s.w_first * ptok
+            s.dpos += self.horizon
+        tick.set(window_pages_freed=freed, live_full_tokens=full,
+                 live_window_tokens=held)
 
     def _flush(self) -> None:
         """Read back what is outstanding when there is nothing to launch

@@ -460,7 +460,8 @@ class OpenAICompatServer:
                  kv_pool_pages: int = 0,
                  prefill_chunk_tokens: int = 0, prefill_lanes: int = 1,
                  adapter_cache_slots: int = 0,
-                 adapter_store_dir: Optional[str] = None):
+                 adapter_store_dir: Optional[str] = None,
+                 kv_window_pool_pages: int = 0):
         """``host`` defaults to loopback — the endpoint is unauthenticated,
         so exposing it on all interfaces requires an explicit
         ``host="0.0.0.0"``.  ``model`` (optional): flax module supporting
@@ -477,7 +478,8 @@ class OpenAICompatServer:
         Memory-plane knobs (engine mode only; docs/SERVING.md):
         ``kv_page_tokens`` is the page size of the engine's KV page pool
         (None = the engine's default; ``kv_pool_pages`` sizes the pool,
-        0 = auto), a prompt enters in chunks
+        0 = auto; ``kv_window_pool_pages`` the window layers' pool of a
+        model that has full layers too), a prompt enters in chunks
         (``prefill_chunk_tokens``/``prefill_lanes``);
         ``adapter_cache_slots`` > 0 demotes the adapter bank to an N-row
         cache over a host/disk store (``adapter_store_dir`` spills cold
@@ -605,6 +607,7 @@ class OpenAICompatServer:
                 adapter_registry=self.registry,
                 slo_rules=slo_rules,
                 kv_pool_pages=int(kv_pool_pages),
+                kv_window_pool_pages=int(kv_window_pool_pages),
                 prefill_chunk_tokens=int(prefill_chunk_tokens),
                 prefill_lanes=int(prefill_lanes),
                 adapter_cache_slots=int(adapter_cache_slots),

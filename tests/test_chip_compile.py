@@ -256,6 +256,79 @@ def latent_programs(one_chip):
     return _engine_programs(eng, one_chip)
 
 
+@pytest.fixture(scope="module")
+def mixed_programs(one_chip):
+    """The same two programs of a model with window and full layers in one
+    stack at the widths and the engine geometry of ``benchmarks/workloads/
+    serve-mixed-12k.command-a-plus-ep8-d4.json`` (48 slots, a buffer of
+    14,112, chunk 1,024, 17 bank rows; 128 query and 8 kv heads of 128, a
+    window of 4,096, a parallel block, 16 of 128 experts held beside 4
+    shared ones, a tied head), one window and one full layer deep, with a
+    third of the cell's pages in either pool (what a pool costs is per
+    page).  The weights are shapes only."""
+    from fedml_tpu.llm.model import LlamaConfig, LlamaLM
+    from fedml_tpu.serving.batching import ContinuousBatchingEngine
+
+    cfg = LlamaConfig(
+        vocab_size=512, dim=4096, n_layers=2, n_heads=128, n_kv_heads=8,
+        head_dim=128, ffn_dim=4096, max_seq_len=14112, rope_theta=5e4,
+        norm_eps=1e-5, dtype=jnp.bfloat16, lora_rank=16, lora_alpha=16.0,
+        layer_types=("sliding_attention", "full_attention"),
+        sliding_window=4096, rope_full_layers=False, parallel_block=True,
+        norm_kind="layer", tie_embeddings=True, n_experts=128, moe_top_k=8,
+        n_shared_experts=4, shared_expert_scale=0.25, moe_scoring="sigmoid",
+        experts_held=(0, 16))
+    model = LlamaLM(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ContinuousBatchingEngine(
+        model, params, slots=48, buf_len=14112, adapter_slots=17,
+        kv_page_tokens=16, kv_pool_pages=4801, kv_window_pool_pages=2507,
+        prefill_chunk_tokens=1024)
+    assert eng.window_blocks == 321 and eng.max_blocks == 946
+    return _engine_programs(eng, one_chip)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_two_pool_program_never_moves_a_whole_pool(mixed_programs, program):
+    """The rule below, for a pool per kind of layer: each of the four pools
+    (K and V of the window layer's, K and V of the full layer's) is updated
+    where it lies by one scatter, alone in its fusion, and is otherwise only
+    named and carried (the walk over a block table is a loop whose body
+    gathers a slab of pages from it: the pool itself passes through the
+    loop's tuple).  The slot state has the window tables beside the seven
+    vectors of a one-pool engine.  The experts run as the two kernels."""
+    compiled, donated = mixed_programs
+    pools = [p for p in donated if p.ndim >= 3]
+    state = [p for p in donated if p.ndim < 3]
+    assert sorted({p.shape for p in pools}) == [(2507, 16, 8, 128),
+                                                (4801, 16, 8, 128)]
+    assert len(pools) == 4 and len(state) == 8
+    assert {p.shape for p in state if p.ndim == 2 and p.dtype == jnp.int32} \
+        == {(48, 946), (48, 321)}
+    compiled = compiled[program]
+    hlo = compiled.as_text()
+    assert "ragged-dot" not in hlo and hlo.count("tpu_custom_call") >= 2
+    assert "%gated_matmul" in hlo and "%grouped_matmul" in hlo
+    naming = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+    scatters = fusions = 0
+    for elems in {p.size for p in pools}:
+        instrs = _pool_sized_instructions(hlo, elems)
+        moving = [(op, line[:200]) for op, line in instrs
+                  if op not in naming | {"scatter", "fusion"}]
+        assert not moving, moving
+        assert all(line.startswith("ROOT ") for op, line in instrs
+                   if op == "scatter")
+        scatters += sum(op == "scatter" for op, _ in instrs)
+        fusions += sum(op == "fusion" for op, _ in instrs)
+    assert scatters == fusions == len(pools)
+    aliases = re.search(r"input_output_alias=\{(.*?) \}, ", hlo).group(1)
+    assert aliases.count("-alias)") == len(pools) + len(state), aliases
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    assert "slice-start" not in hlo and "copy-start" in hlo
+
+
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
 @pytest.mark.parametrize("model", ["dense", "latent"])
 def test_paged_program_never_moves_a_whole_pool(request, model, program):

@@ -407,22 +407,37 @@ def _attn_impl(cfg: "LlamaConfig") -> str:
     return "flash" if jax.default_backend() == "tpu" else "blockwise"
 
 
-#: the paged read walks a block table in slabs, under a running softmax,
-#: where what the whole-window gather would hold at once (K and V of every
-#: row's whole table, or the scores over them) passes this many bytes; a
-#: window layer always walks
+#: the paged read walks a block table, under a running softmax, where what
+#: the whole-window gather would hold at once (K and V of every row's whole
+#: table, or the scores over them) passes this many bytes; a window layer
+#: always walks
 WALK_MIN_BYTES = 1 << 28
-#: the scores of one slab of the walk, in elements
+#: the scores of one slab of the ``jnp`` walk, in elements
 WALK_SLAB_SCORES = 1 << 26
 
 
+def paged_read_walks(cfg: "LlamaConfig", window: int, b: int, s: int,
+                     entries: int) -> bool:
+    """Whether the paged read of a layer with this ``window``, for ``b``
+    lanes of ``s`` positions over tables of ``entries`` pages, walks its
+    table (:func:`_walk_pages`) and does not gather it whole: a window layer
+    always, a full layer where K and V of every row's whole table, or the
+    scores over them, pass ``WALK_MIN_BYTES`` (an int8 cache has no walk)."""
+    head_dim = cfg.head_dim or cfg.dim // cfg.n_heads
+    int8_kv = cfg.kv_cache_dtype == "int8"
+    itemsize = 1 if int8_kv else jnp.dtype(cfg.dtype).itemsize
+    whole = b * entries * cfg.kv_page_tokens * max(
+        2 * cfg.n_kv_heads * head_dim * itemsize, 4 * cfg.n_heads * s)
+    return bool(window or (whole > WALK_MIN_BYTES and not int8_kv))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("window", "ring", "sm_scale", "dtype"))
 def _walk_pages(q, pool_k, pool_v, tables, pos, window: int, ring: bool,
                 sm_scale: float, dtype):
     """Attention of ``q`` (b, h_kv, rep, s, d) at positions ``pos`` (b, s)
     over the pages ``tables`` (b, entries) names in ``pool_k``/``pool_v``
-    (pages, P, h_kv, d), a slab of entries at a time under a running
-    softmax, as far as the longest row of the batch reaches: the trip count
-    is data, and no step holds more than a slab of every row.
+    (pages, P, h_kv, d), under a running softmax.
 
     Entry ``e`` of a table stands for block ``e`` of the sequence, or, in a
     ``ring``, for the one block ``j`` in ``(last - entries, last]`` with
@@ -430,7 +445,30 @@ def _walk_pages(q, pool_k, pool_v, tables, pos, window: int, ring: bool,
     in this call.  A key at position j is visible to the query at i iff
     ``0 <= i - j`` (``< window``, where the layer has one).  A row whose
     table is all trash (a lane that is not live) is walked over not at
-    all."""
+    all, and what it reads is unspecified.
+
+    Where the program is lowered for a TPU and the operands allow
+    (``ops/paged_attention.py::kernel_can_run``: bfloat16, whole lanes of
+    head_dim, rows that tile) it is one Pallas kernel in which every lane
+    visits its own live pages; everywhere else (the CPU, a float32 model,
+    odd widths) the ``jnp`` loop of :func:`_walk_pages_jnp`."""
+    from ..ops import paged_attention as pa
+    walk = functools.partial(_walk_pages_jnp, window=window, ring=ring,
+                             sm_scale=sm_scale, dtype=dtype)
+    # fedlint: disable-next-line=recompile-hazard -- shapes and dtypes only
+    if q.dtype != dtype or not pa.kernel_can_run(q, pool_k, pool_v, tables):
+        return walk(q, pool_k, pool_v, tables, pos)
+    kernel = functools.partial(pa.paged_attention, window=window, ring=ring,
+                               sm_scale=sm_scale)
+    return jax.lax.platform_dependent(q, pool_k, pool_v, tables, pos,
+                                      tpu=kernel, default=walk)
+
+
+def _walk_pages_jnp(q, pool_k, pool_v, tables, pos, *, window: int,
+                    ring: bool, sm_scale: float, dtype):
+    """:func:`_walk_pages` in plain ``jnp``: a slab of entries at a time, as
+    far as the longest row of the batch reaches: the trip count is data, and
+    no step holds more than a slab of every row."""
     b, g, rep, s, d = q.shape
     ptok, entries = pool_k.shape[1], tables.shape[1]
     slab = min(max(WALK_SLAB_SCORES // (b * g * rep * s * ptok), 8), 64,
@@ -655,7 +693,9 @@ class Attention(nn.Module):
         window back while the request runs, has zeroed the entries of the
         blocks it took and of those it has not given yet.  Its read, and
         any read too large to gather whole (``WALK_MIN_BYTES``), walks the
-        table in slabs (:func:`_walk_pages`).
+        table (:func:`_walk_pages`: a Pallas kernel over each lane's live
+        pages where the program is lowered for a TPU, a ``jnp`` loop over
+        slabs elsewhere).
         """
         cfg = self.cfg
         ptok = cfg.kv_page_tokens
@@ -706,9 +746,6 @@ class Attention(nn.Module):
         window = max_blocks * ptok
         rep = cfg.n_heads // cfg.n_kv_heads
         qg = q.reshape(b, cfg.n_kv_heads, rep, s, head_dim)
-        whole = b * window * max(
-            2 * cfg.n_kv_heads * head_dim * jnp.dtype(store_dtype).itemsize,
-            4 * cfg.n_heads * s)
 
         def project(out):                # (b, hkv, rep, s, d) -> (b, s, dim)
             out = out.reshape(b, cfg.n_heads, s, head_dim)
@@ -716,7 +753,7 @@ class Attention(nn.Module):
                 b, s, cfg.n_heads * head_dim)
             return dense(cfg.dim, "wo")(out)
 
-        if self.window or (whole > WALK_MIN_BYTES and not int8_kv):
+        if paged_read_walks(cfg, self.window, b, s, max_blocks):
             return project(_walk_pages(
                 qg, pk.value, pv.value, block_tables, pos, self.window, ring,
                 head_dim ** -0.5, cfg.dtype))

@@ -582,6 +582,14 @@ class ContinuousBatchingEngine:
             if self._moe_layers else None
         self._chunk_words = self.prefill_chunk + 5 + key_words \
             + self.window_blocks
+        # the layers whose paged read is the paged-attention kernel in this
+        # process's programs, for the tick's lanes and for a chunk's rows:
+        # what ``attn_pages`` counts (none where the ``jnp`` forms run)
+        self._kernel_reads = {
+            "tick": self._reads_by_kernel(self.n_slots, 1),
+            "chunk": self._reads_by_kernel(1, self.prefill_chunk)} \
+            if two_pools else {}
+        self._attn_pages = 0
         self._host_device = jax.devices("cpu")[0]
 
         self._slots = [_Slot() for _ in range(self.n_slots)]
@@ -1056,6 +1064,50 @@ class ContinuousBatchingEngine:
         s.request = req.get("request")
         self._stage_row(slot, key, req["temperature"], s.adapter_row)
 
+    def _reads_by_kernel(self, b: int, s: int) -> List[tuple]:
+        """``(layers, window, ring, entries)`` a kind of layer: those of the
+        paged model whose read, in a program of ``b`` lanes of ``s``
+        positions, walks its table and whose walk is the kernel of
+        ``ops/paged_attention.py`` here."""
+        from ..llm.model import paged_read_walks
+        from ..ops import paged_attention as pa
+        cfg = self.paged_model.cfg
+        head_dim = cfg.head_dim or cfg.dim // cfg.n_heads
+
+        def shape(*dims, dtype=cfg.dtype):
+            return jax.ShapeDtypeStruct(dims, dtype)
+
+        q = shape(b, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, s,
+                  head_dim)
+        kinds: Dict[tuple, int] = {}
+        for i in range(cfg.n_layers):
+            window = cfg.layer_window(i)
+            ring = window > 0 and cfg.kv_window_pool_pages > 0
+            entries, pages = (self.window_blocks, cfg.kv_window_pool_pages) \
+                if ring else (self.max_blocks, cfg.kv_pool_pages)
+            pool = shape(pages, cfg.kv_page_tokens, cfg.n_kv_heads, head_dim,
+                         dtype=jnp.int8 if cfg.kv_cache_dtype == "int8"
+                         else cfg.dtype)
+            if paged_read_walks(cfg, window, b, s, entries) and pa.engages(
+                    q, pool, pool, shape(b, entries, dtype=jnp.int32)):
+                kinds[window, ring, entries] = \
+                    kinds.get((window, ring, entries), 0) + 1
+        return [(n, *kind) for kind, n in kinds.items()]
+
+    def _count_attn_pages(self, program: str, pos: np.ndarray) -> int:
+        """Pages the kernel visits over all layers in one call of
+        ``program`` whose live lanes stand at ``pos`` (lanes, s)."""
+        from ..ops.paged_attention import visited_pages
+        live = np.ones(len(pos), np.int64)
+        pages = sum(
+            layers * visited_pages(pos, live, window=window, ring=ring,
+                                   entries=entries,
+                                   ptok=self.kv_page_tokens)
+            for layers, window, ring, entries in self._kernel_reads[program])
+        with self._stats_lock:
+            self._attn_pages += pages
+        return pages
+
     def _slide_window(self, i: int, s: "_Slot", lo: int):
         """Slide slot ``i``'s window table to a program whose lowest query
         position is ``lo`` (a chunk's start, a tick's position): the pages
@@ -1106,7 +1158,9 @@ class ContinuousBatchingEngine:
                              tokens=min(C, n - cs), final=int(final)) as span:
                 freed = self._prefill_chunk(tracer, i, s, cs, final)
                 if self.window_pool is not None:
-                    span.set(window_pages_freed=freed)
+                    span.set(window_pages_freed=freed,
+                             attn_pages=self._count_attn_pages(
+                                 "chunk", cs + np.arange(C)[None]))
 
     def _prefill_chunk(self, tracer, i: int, s: "_Slot", cs: int,
                        final: bool) -> int:
@@ -1242,6 +1296,7 @@ class ContinuousBatchingEngine:
             tiles = self._expert_tiles
             layers = self._moe_layers_ticked
             freed_early = self._window_pages_freed
+            attn_pages = self._attn_pages
         out["pool"] = dict(self.page_pool.stats)
         out["pages_free"] = self.page_pool.pages_free
         out["pool_pages"] = self.page_pool.n_pages
@@ -1258,6 +1313,9 @@ class ContinuousBatchingEngine:
             out["window_pool_pages"] = self.window_pool.n_pages
             out["window_blocks"] = self.window_blocks
             out["window_pages_freed"] = freed_early
+            # pages the paged-attention kernel visited over all layers, in
+            # ticks and chunks; 0 where the reads ran in ``jnp``
+            out["attn_pages"] = attn_pages
         out["expert_pairs"] = pairs
         out["experts_hit"] = hit
         out["expert_tiles"] = tiles
@@ -1514,6 +1572,8 @@ class ContinuousBatchingEngine:
         pages do."""
         ptok = self.kv_page_tokens
         full = held = freed = 0
+        pos = np.array([self._slots[i].dpos for i in live],
+                       np.int64).reshape(-1, 1)
         for i in live:
             s = self._slots[i]
             changed, behind = self._slide_window(i, s, s.dpos)
@@ -1524,7 +1584,9 @@ class ContinuousBatchingEngine:
             held += s.dpos + 1 - s.w_first * ptok
             s.dpos += self.horizon
         tick.set(window_pages_freed=freed, live_full_tokens=full,
-                 live_window_tokens=held)
+                 live_window_tokens=held,
+                 attn_pages=sum(self._count_attn_pages("tick", pos + step)
+                                for step in range(self.horizon)))
 
     def _flush(self) -> None:
         """Read back what is outstanding when there is nothing to launch

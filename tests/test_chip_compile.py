@@ -158,6 +158,42 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, call, rows):
     assert hlo.count("tpu_custom_call") == kernels and "ragged-dot" not in hlo
 
 
+# -- the paged read's walk at the mixed cell's shapes ---------------------------
+
+@pytest.mark.parametrize("table", ["full", "ring"])
+@pytest.mark.parametrize("shape", ["tick", "chunk"])
+def test_paged_attention_compiles_for_v5e(one_chip, shape, table):
+    """``ops/paged_attention.py`` at the two shapes and both kinds of table of
+    ``serve-mixed-12k.command-a-plus-ep8-d4``: a tick's 48 lanes of one query
+    and a chunk's 1,024, 128 query heads on 8 kv heads of 128, over the full
+    layers' table of 946 entries (a pool of 14,401 pages of 16 tokens) and the
+    window layers' ring of 321 (7,521 pages, window 4,096).  What
+    ``_walk_pages`` lowers to for the chip at these operands is this call
+    and no loop."""
+    from fedml_tpu.llm import model as M
+    from fedml_tpu.ops import paged_attention as pa
+
+    def described(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    b, s = {"tick": (48, 1), "chunk": (1, 1024)}[shape]
+    entries, pages, window = {"full": (946, 14401, 0),
+                              "ring": (321, 7521, 4096)}[table]
+    pool = described(pages, 16, 8, 128)
+    operands = (described(b, 8, 16, s, 128), pool, pool,
+                described(b, entries, dtype=jnp.int32),
+                described(b, s, dtype=jnp.int32))
+    assert pa.kernel_can_run(*operands[:4])
+    walk = jax.jit(lambda *a: M._walk_pages(
+        *a, window, table == "ring", 128 ** -0.5, jnp.bfloat16))
+    compiled = walk.lower(*operands).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1 and "%paged_attention" in hlo
+    assert not re.search(r" while\(", hlo)
+    # the pools are read where they lie: no temporary of a pool's size
+    assert compiled.memory_analysis().temp_size_in_bytes < pool.size
+
+
 # -- the paged decode programs never move a whole page pool -----------------
 
 @pytest.fixture(scope="module")
@@ -294,10 +330,12 @@ def test_two_pool_program_never_moves_a_whole_pool(mixed_programs, program):
     """The rule below, for a pool per kind of layer: each of the four pools
     (K and V of the window layer's, K and V of the full layer's) is updated
     where it lies by one scatter, alone in its fusion, and is otherwise only
-    named and carried (the walk over a block table is a loop whose body
-    gathers a slab of pages from it: the pool itself passes through the
-    loop's tuple).  The slot state has the window tables beside the seven
-    vectors of a one-pool engine.  The experts run as the two kernels."""
+    named: the walk over a block table is the paged-attention kernel
+    (``ops/paged_attention.py``), one custom call a layer that takes the
+    pools as operands and reads its pages from HBM itself, so no loop is
+    left in either program and nothing of a pool's size is gathered.  The
+    slot state has the window tables beside the seven vectors of a one-pool
+    engine.  The experts run as the two kernels."""
     compiled, donated = mixed_programs
     pools = [p for p in donated if p.ndim >= 3]
     state = [p for p in donated if p.ndim < 3]
@@ -310,7 +348,10 @@ def test_two_pool_program_never_moves_a_whole_pool(mixed_programs, program):
     hlo = compiled.as_text()
     assert "ragged-dot" not in hlo and hlo.count("tpu_custom_call") >= 2
     assert "%gated_matmul" in hlo and "%grouped_matmul" in hlo
-    naming = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+    # the window layer's read and the full layer's, under the kernel's name
+    assert len(re.findall(r"%paged_attention[.\d]* = ", hlo)) == 2
+    assert not re.search(r" while\(", hlo)
+    naming = {"parameter", "get-tuple-element", "tuple", "bitcast"}
     scatters = fusions = 0
     for elems in {p.size for p in pools}:
         instrs = _pool_sized_instructions(hlo, elems)
@@ -360,6 +401,9 @@ def test_paged_program_never_moves_a_whole_pool(request, model, program):
         assert hlo.count("tpu_custom_call") >= 2
         # under the kernels' own names, which is what a device trace shows
         assert "%gated_matmul" in hlo and "%grouped_matmul" in hlo
+    # neither reads through the walk: the dense model's tables are gathered
+    # whole, the latent model reads through ``llm/mla.py``
+    assert "%paged_attention" not in hlo
     instrs = _pool_sized_instructions(hlo, pools[0].size)
     naming = {"parameter", "get-tuple-element", "tuple", "bitcast"}
     moving = [(op, line[:200]) for op, line in instrs

@@ -447,6 +447,66 @@ def test_spans_and_gauges_of_the_two_pools(served):
     assert {"serve.kv_pages_free", "serve.kv_pages_free.full", "serve.kv_pages_free.window"} <= gauges
 
 
+def test_attn_pages_is_zero_where_the_jnp_walk_ran(served):
+    """On the CPU every walk is the ``jnp`` loop: the counter is on every
+    tick and chunk and in ``kv_stats()``, and it is 0, as ``expert_tiles``
+    is where ``ragged_dot`` ran."""
+    _, _, _, _, stats, events, _ = served
+    spans = [e for e in events if e["name"] in ("serve.tick", "serve.chunk") and e["ph"] == "E"]
+    assert {e["name"] for e in spans} == {"serve.tick", "serve.chunk"}
+    assert all(e["args"]["attn_pages"] == 0 for e in spans)
+    assert stats["attn_pages"] == 0
+
+
+def test_attn_pages_counts_what_the_kernel_visits(setting, monkeypatch):
+    """The kernel form forced on the CPU in interpret mode, for the window
+    layers' rings and (``WALK_MIN_BYTES`` 0) the full layer's table: every
+    served token is still the reference's best, and ``attn_pages`` on the
+    spans and in ``kv_stats()`` is what ``visited_pages`` counts for the
+    positions each chunk and tick of each request stood at."""
+    from fedml_tpu import obs
+    from fedml_tpu.ops import paged_attention as pa
+    from fedml_tpu.serving.batching import ContinuousBatchingEngine
+    lcfg, base, _, _ = setting
+    monkeypatch.setattr(M, "WALK_MIN_BYTES", 0)
+    monkeypatch.setattr(pa, "engages", lambda *operands: True)
+    monkeypatch.setattr(M, "_walk_pages", lambda q, k, v, tables, pos, window, ring, scale, dtype:
+                        pa.paged_attention(q, k, v, tables, pos, window=window, ring=ring,
+                                           sm_scale=scale, interpret=True))
+    obs.configure(enabled=True, reset=True, jax_hooks=False)
+    eng = ContinuousBatchingEngine(LlamaLM(lcfg), base, slots=2, buf_len=160, adapter_slots=2,
+                                   kv_page_tokens=4, prefill_chunk_tokens=16)
+    try:
+        loras = {"a0": weights.make_lora(TINY, 5, index=1)}
+        eng.registry.register("a0", loras["a0"])
+        rng = np.random.default_rng(7)
+        requests = [([int(t) for t in rng.integers(1, 256, size=n)], m, "a0")
+                    for n, m in ((100, 12), (30, 8), (53, 10))]
+        outs = _serve(eng, requests)
+        stats, events = eng.kv_stats(), obs.get_tracer().events()
+        blocks, ring = eng.max_blocks, eng.window_blocks
+    finally:
+        eng.stop()
+        obs.configure(enabled=False)
+    assert _gaps(base, loras, requests, outs) < 1e-4
+
+    kinds = [(lcfg.layer_types.count("sliding_attention"), dict(window=W, ring=True, entries=ring)),
+             (lcfg.layer_types.count("full_attention"), dict(window=0, ring=False, entries=blocks))]
+
+    def pages(pos):
+        return sum(n * pa.visited_pages(np.asarray(pos), np.ones(1, np.int64), ptok=4, **kind)
+                   for n, kind in kinds)
+
+    # a prompt's chunks stand at 0, 16, ...; its ticks write n .. n + m - 2
+    want = sum(sum(pages(cs + np.arange(16)[None]) for cs in range(0, len(ids), 16))
+               + sum(pages([[p]]) for p in range(len(ids), len(ids) + m - 1))
+               for ids, m, _ in requests)
+    assert stats["attn_pages"] == want > 0
+    spans = [e for e in events if e["name"] in ("serve.tick", "serve.chunk") and e["ph"] == "E"]
+    assert sum(e["args"]["attn_pages"] for e in spans) == want
+    assert all(e["args"]["attn_pages"] > 0 for e in spans)
+
+
 def test_a_page_freed_too_early_is_seen(setting, monkeypatch):
     """The planted fault of the rehearsal at tier-1 size: the window believed
     a page shorter than it is, so a page still inside it goes to another

@@ -289,6 +289,12 @@ def test_steady_tick_uploads_nothing_and_reads_back_once(mt_model):
                 t0 = eng.kv_stats()["ticks"]
                 for q in qs:
                     take(q, 12)
+                # the engine may have run ahead of this thread and have had
+                # these tokens queued already: ticks inside the audit count
+                deadline = time.monotonic() + 60
+                while eng.kv_stats()["ticks"] - t0 < 6 \
+                        and time.monotonic() < deadline:
+                    time.sleep(0.01)
                 t1 = eng.kv_stats()["ticks"]
         finally:
             jax.config.update("jax_transfer_guard_host_to_device", "allow")

@@ -43,6 +43,10 @@ class YarnScaling:
     mscale_all_dim: float = 0.0
 
 
+#: what a layer may mix by (``LlamaConfig.layer_types``)
+LAYER_KINDS = frozenset({"full_attention", "sliding_attention", "conv"})
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
@@ -110,11 +114,20 @@ class LlamaConfig:
     rope_scaling: Optional[YarnScaling] = None
     #: a head's width; 0 = ``dim // n_heads``
     head_dim: int = 0
-    #: the kind of every layer's attention, ``"full_attention"`` (causal over
-    #: everything before) or ``"sliding_attention"`` (key j visible to query
-    #: i iff ``0 <= i - j < sliding_window``); None = all full
+    #: the kind of every layer's mixer: ``"full_attention"`` (causal over
+    #: everything before), ``"sliding_attention"`` (key j visible to query
+    #: i iff ``0 <= i - j < sliding_window``) or ``"conv"`` (the gated short
+    #: convolution, :class:`ShortConv`, of ``conv_kernel`` taps); None = all
+    #: full
     layer_types: Optional[Tuple[str, ...]] = None
     sliding_window: int = 0
+    conv_kernel: int = 0
+    #: RMS norms on q and k, over each head's numbers with one learned scale
+    #: of ``head_dim``, before the rotary embedding
+    qk_norm: bool = False
+    #: the experts are chosen by ``scores + bias`` (a float32 buffer beside
+    #: the router, ``select_bias``); the gates are the chosen experts' scores
+    moe_select_bias: bool = False
     #: False: the full layers carry no positional embedding, the window
     #: layers keep the rotary one
     rope_full_layers: bool = True
@@ -158,6 +171,11 @@ class LlamaConfig:
     #: window back while the request runs (docs/SERVING.md).  0: every
     #: layer's pool has ``kv_pool_pages`` and one table addresses them all.
     kv_window_pool_pages: int = 0
+    #: >0 (the engine sets it for a model with ``"conv"`` layers): on the
+    #: paged path a convolution layer's state is one buffer of this many rows
+    #: and a trash row, a row a slot, addressed by the call's ``state_rows``.
+    #: 0: a row for each row of the batch (the single-request path).
+    state_slots: int = 0
 
     def __post_init__(self):
         # typos must fail loudly — a silently-defaulted knob produces
@@ -185,14 +203,20 @@ class LlamaConfig:
                              "or 'layer'")
         kinds = self.layer_types
         if kinds is not None:
-            if len(kinds) != self.n_layers or set(kinds) - {
-                    "full_attention", "sliding_attention"}:
+            if len(kinds) != self.n_layers or set(kinds) - LAYER_KINDS:
                 raise ValueError(
-                    f"layer_types={kinds!r}: one of 'full_attention' and "
-                    f"'sliding_attention' for each of {self.n_layers} layers")
+                    f"layer_types={kinds!r}: one of 'full_attention', "
+                    f"'sliding_attention' and 'conv' for each of "
+                    f"{self.n_layers} layers")
             if "sliding_attention" in kinds and self.sliding_window <= 0:
                 raise ValueError("sliding_attention layers need "
                                  "sliding_window > 0")
+            if "conv" in kinds and self.conv_kernel < 2:
+                raise ValueError("conv layers need conv_kernel >= 2 (the "
+                                 "taps of the short convolution)")
+        if self.qk_norm and self.kv_lora_rank > 0:
+            raise ValueError("qk_norm is computed for grouped-query "
+                             "attention, not for latent attention")
         if self.windowed and (self.kv_lora_rank > 0
                               or self.kv_cache_dtype == "int8"):
             raise ValueError(
@@ -248,6 +272,14 @@ class LlamaConfig:
     def mixed_attention(self) -> bool:
         """Window and full layers in one stack."""
         return self.windowed and "full_attention" in self.layer_types
+
+    @property
+    def conv_layers(self) -> int:
+        """How many layers mix by the short convolution and keep its state."""
+        return sum(k == "conv" for k in self.layer_types or ())
+
+    def layer_conv(self, i: int) -> bool:
+        return bool(self.layer_types) and self.layer_types[i] == "conv"
 
     def layer_window(self, i: int) -> int:
         """Layer ``i``'s window; 0 = everything before."""
@@ -540,6 +572,9 @@ class Attention(nn.Module):
         q = q.reshape(b, s, cfg.n_heads, head_dim).transpose(0, 2, 1, 3)
         k = k.reshape(b, s, cfg.n_kv_heads, head_dim).transpose(0, 2, 1, 3)
         v = v.reshape(b, s, cfg.n_kv_heads, head_dim).transpose(0, 2, 1, 3)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
         if self.rope:
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
@@ -703,19 +738,25 @@ class Attention(nn.Module):
         pool_pages = cfg.kv_window_pool_pages if ring else cfg.kv_pool_pages
         int8_kv = cfg.kv_cache_dtype == "int8"
         store_dtype = jnp.int8 if int8_kv else cfg.dtype
+        # heads narrower than a lane tile whose row of all kv heads is whole
+        # tiles: the pool keeps the row flat, (pages, P, h_kv * d).  With a
+        # trailing axis of half a tile the chip's compiler pads every page to
+        # twice its size and re-lays the pool out around the scatter and the
+        # gather (tests/test_chip_compile.py holds the programs to no copy)
+        row = (cfg.n_kv_heads * head_dim,) if (
+            head_dim % 128 and (cfg.n_kv_heads * head_dim) % 128 == 0
+            and not int8_kv) else (cfg.n_kv_heads, head_dim)
         pk = self.variable("cache", "k", jnp.zeros,
-                           (pool_pages, ptok, cfg.n_kv_heads, head_dim),
-                           store_dtype)
+                           (pool_pages, ptok) + row, store_dtype)
         pv = self.variable("cache", "v", jnp.zeros,
-                           (pool_pages, ptok, cfg.n_kv_heads, head_dim),
-                           store_dtype)
+                           (pool_pages, ptok) + row, store_dtype)
         pos = positions.astype(jnp.int32)                   # (b, s)
         max_blocks = block_tables.shape[1]
         entry = (pos // ptok) % max_blocks if ring else pos // ptok
         page = jnp.take_along_axis(block_tables, entry, axis=1)
         offs = pos % ptok                                   # (b, s)
-        k_w = k.transpose(0, 2, 1, 3)                       # (b, s, hkv, d)
-        v_w = v.transpose(0, 2, 1, 3)
+        k_w = k.transpose(0, 2, 1, 3).reshape((b, s) + row)  # (b, s) + row
+        v_w = v.transpose(0, 2, 1, 3).reshape((b, s) + row)
         if int8_kv:
             pks = self.variable("cache", "k_scale", jnp.zeros,
                                 (pool_pages, ptok, cfg.n_kv_heads),
@@ -758,13 +799,13 @@ class Attention(nn.Module):
                 qg, pk.value, pv.value, block_tables, pos, self.window, ring,
                 head_dim ** -0.5, cfg.dtype))
 
-        def gather_window(pool):                     # -> (b, hkv, W, ...)
+        def gather_window(pool, per_head=()):        # -> (b, hkv, W, ...)
             g = pool[block_tables]                   # (b, MB, P, hkv, ...)
-            g = g.reshape((b, window) + g.shape[3:])
+            g = g.reshape((b, window, cfg.n_kv_heads) + per_head)
             return jnp.moveaxis(g, 2, 1)
 
-        kf = gather_window(pk.value)
-        vf = gather_window(pv.value)
+        kf = gather_window(pk.value, (head_dim,))
+        vf = gather_window(pv.value, (head_dim,))
         scores = jnp.einsum("bgrqd,bgkd->bgrqk", qg, kf.astype(qg.dtype),
                             preferred_element_type=jnp.float32)
         if int8_kv:
@@ -781,6 +822,65 @@ class Attention(nn.Module):
                          preferred_element_type=jnp.float32
                          ).astype(cfg.dtype)
         return project(out)
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution, a mixer in place of attention
+    (``layer_types[i] == "conv"``): ``[B | C | z] = in_proj(x)``, ``u = B * z``,
+    ``c_t = sum_j w[:, j] * u_{t - (K-1) + j}`` (depthwise and causal over
+    ``K = conv_kernel`` taps, ``u`` zero before the sequence, no bias),
+    ``out_proj(C * c)``.
+
+    What a later call needs of the past is the last ``K - 1`` rows of ``u``:
+    on the decode paths they live in the ``cache`` collection as
+    ``conv_state``, a buffer shaped by **rows of state** and not by pages:
+    ``(state_slots + 1, K - 1, dim)`` for the engine's paged model (a row a
+    slot and the trash row), else a row for each row of the batch.
+    ``state = (rows, lens)``: the (b,) rows this call's lanes address
+    (None: ``arange(b)``) and how many of each lane's ``s`` positions are real
+    (None: all; the positions behind them are a chunk's padding).  Three rules,
+    all inside the call: a lane whose first position is 0 starts from zeros,
+    whatever its row holds (nothing precedes position 0: a request's first
+    chunk uploads and clears nothing); every call writes each lane's last
+    ``K - 1`` real rows back to its row; and a lane that must leave a slot's
+    state alone is given the trash row by its caller.  A call of ``s``
+    positions computes its convolution from the carried rows and its own ``u``
+    in one shifted sum."""
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, decode: bool = False, state=None):
+        cfg = self.cfg
+        taps = cfg.conv_kernel
+        dense = _projection(cfg)
+        b, s, d = x.shape
+        gate_in, gate_out, z = jnp.split(dense(3 * d, "in_proj")(x), 3, axis=-1)
+        u = gate_in * z
+        w = self.param("conv_weight", nn.initializers.normal(taps ** -0.5),
+                       (d, taps), cfg.store_dtype).astype(jnp.float32)
+        if decode:
+            rows, lens = state if state is not None else (None, None)
+            carried = self.variable(
+                "cache", "conv_state", jnp.zeros,
+                (cfg.state_slots + 1 if cfg.state_slots else b, taps - 1, d),
+                cfg.dtype)
+            if rows is None:
+                rows = jnp.arange(b)
+            fresh = jnp.reshape(positions[..., 0] == 0, (-1, 1, 1))
+            prev = jnp.where(fresh, 0, carried.value[rows]).astype(u.dtype)
+        else:
+            prev = jnp.zeros((b, taps - 1, d), u.dtype)
+        ext = jnp.concatenate([prev, u], axis=1)        # (b, K-1+s, d)
+        c = sum(ext[:, j:j + s].astype(jnp.float32) * w[:, j]
+                for j in range(taps)).astype(u.dtype)
+        if decode:
+            if lens is None:
+                last = ext[:, s:]
+            else:       # u of positions lens-K+1 .. lens-1, in ext's rows
+                at = lens[:, None] + jnp.arange(taps - 1)[None, :]
+                last = jnp.take_along_axis(ext, at[:, :, None], axis=1)
+            carried.value = carried.value.at[rows].set(last.astype(cfg.dtype))
+        return dense(d, "out_proj")(gate_out * c)
 
 
 class MLP(nn.Module):
@@ -808,19 +908,24 @@ class Block(nn.Module):
     #: q and k: what differs by layer is data of the configuration
     window: int = 0
     rope: bool = True
+    #: the mixer is the gated short convolution, not attention
+    conv: bool = False
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False,
-                 block_tables=None):
+                 block_tables=None, state=None):
         cfg = self.cfg
-        if cfg.latent_attention:
-            from .mla import MLA
-            attend = MLA(cfg, name="attention")
-        else:
-            attend = Attention(cfg, window=self.window, rope=self.rope,
-                               name="attention")
         n = _norm(cfg, "attn_norm")(x)
-        attn = attend(n, positions, decode=decode, block_tables=block_tables)
+        if self.conv:
+            attn = ShortConv(cfg, name="conv")(n, positions, decode, state)
+        elif cfg.latent_attention:
+            from .mla import MLA
+            attn = MLA(cfg, name="attention")(
+                n, positions, decode=decode, block_tables=block_tables)
+        else:
+            attn = Attention(cfg, window=self.window, rope=self.rope,
+                             name="attention")(
+                n, positions, decode=decode, block_tables=block_tables)
         if cfg.parallel_block:       # one norm for both
             return x + attn + _feed_forward(cfg, self.sparse, n)
         h = x + attn
@@ -840,7 +945,8 @@ def _feed_forward(cfg: LlamaConfig, sparse: bool, hn):
                top_k=cfg.moe_top_k, scoring=cfg.moe_scoring,
                n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
                norm_topk=cfg.moe_norm_topk,
-               routed_scale=cfg.moe_routed_scale, held=cfg.experts_held,
+               routed_scale=cfg.moe_routed_scale,
+               select_bias=cfg.moe_select_bias, held=cfg.experts_held,
                dtype=cfg.dtype, param_dtype=cfg.store_dtype,
                name="moe_mlp")(hn)
     if cfg.n_shared_experts:
@@ -859,7 +965,7 @@ class LlamaLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False, decode: bool = False,
                  start_pos=None, return_hidden: bool = False,
-                 block_tables=None):
+                 block_tables=None, state_rows=None, seq_lens=None):
         """``decode=True`` switches attention to the KV-cached path: the
         flax "cache" collection must be mutable in ``apply``, and
         ``start_pos`` (scalar int array — or a (B,) vector on the paged
@@ -869,7 +975,11 @@ class LlamaLM(nn.Module):
         ((B, max_blocks) int32, traced) selects the paged-pool decode
         path (``kv_page_tokens``/``kv_pool_pages`` on the config); for a
         model with ``kv_window_pool_pages`` it is ``{"full": ...,
-        "window": ...}``, a table per kind of layer.
+        "window": ...}``, a table per kind of layer.  A model with
+        ``"conv"`` layers keeps their state by rows (:class:`ShortConv`):
+        ``state_rows`` ((B,) int32, traced) says which row each lane of the
+        call addresses (None: lane i its own row i) and ``seq_lens`` ((B,))
+        how many of a lane's positions are real (None: all of them).
         ``return_hidden=True`` returns final-norm hidden states without
         the lm_head projection (the streaming cross-entropy path)."""
         cfg = self.cfg
@@ -896,6 +1006,11 @@ class LlamaLM(nn.Module):
             mk_block = nn.remat(Block, static_argnums=(3,))  # FLOPs
         for i in range(cfg.n_layers):
             window = cfg.layer_window(i)
+            if cfg.layer_conv(i):
+                block = mk_block(cfg, sparse=cfg.sparse_layer(i), conv=True,
+                                 name=f"layer_{i}")
+                x = block(x, positions, decode, None, (state_rows, seq_lens))
+                continue
             block = mk_block(cfg, sparse=cfg.sparse_layer(i), window=window,
                              rope=cfg.layer_rope(i), name=f"layer_{i}")
             tables = block_tables
@@ -944,6 +1059,10 @@ _PUBLISHED_KEYS = {
     "expert_selection_fn": "moe_scoring", "sliding_window": "sliding_window",
     "use_parallel_block": "parallel_block", "logit_scale": "logit_scale",
     "tie_word_embeddings": "tie_embeddings",
+    # the lfm2_moe family's names
+    "norm_eps": "norm_eps", "conv_L_cache": "conv_kernel",
+    "num_dense_layers": "first_dense_layers",
+    "use_expert_bias": "moe_select_bias", "use_qk_norm": "qk_norm",
 }
 
 
@@ -967,22 +1086,32 @@ def config_from_published(published) -> dict:
         names = {f.name for f in dataclasses.fields(YarnScaling)}
         out["rope_scaling"] = YarnScaling(
             **{k: v for k, v in scaling.items() if k in names})
-    if published.get("topk_method", "none") not in (
-            "none", "greedy", "group_limited_greedy"):
-        # e.g. "noaux_tc": selection by scores plus a learned bias
-        raise ValueError(f"topk_method {published['topk_method']!r} is not "
-                         "computed here (llm/moe.py::route has no "
-                         "score-correction bias)")
+    method = published.get("topk_method", "none")
+    if method == "noaux_tc":
+        # selection by scores plus a bias that is not in the gates, the
+        # groups scored over the same sum: ``route``'s rule with a bias
+        out["moe_select_bias"] = True
+    elif method not in ("none", "greedy", "group_limited_greedy"):
+        raise ValueError(f"topk_method {method!r} is not computed here "
+                         "(llm/moe.py::route chooses the k best of the best "
+                         "groups, with or without a selection bias)")
     if published.get("attention_bias") or published.get(
             "hidden_act", "silu") != "silu":
         raise ValueError("attention_bias and activations other than silu "
                          "are not computed here")
     if published.get("layer_types"):
         out["layer_types"] = tuple(published["layer_types"])
+        unknown = set(out["layer_types"]) - LAYER_KINDS
+        if unknown:
+            raise ValueError(
+                f"layer_types entries {sorted(unknown)!r}: a layer mixes by "
+                "'full_attention', 'sliding_attention' or 'conv' here")
         if "sliding_attention" not in out["layer_types"]:
             out.pop("sliding_window", None)
     else:       # a window no layer is said to have
         out.pop("sliding_window", None)
+    if "conv" not in out.get("layer_types", ()):    # taps no layer has
+        out.pop("conv_kernel", None)
     out.update(_unnamed_fields(published))
     return out
 
@@ -997,9 +1126,15 @@ def _unnamed_fields(published: dict) -> dict:
     carry the rotary embedding.  A key that asks for what is not computed
     raises by name; the ``prefix_dense_*`` keys do nothing without leading
     dense layers and are passed over."""
-    if published.get("use_qk_norm"):
-        raise ValueError("use_qk_norm: norms on q and k are not computed "
-                         "here")
+    if published.get("conv_bias"):
+        raise ValueError("conv_bias true: the short convolution and its "
+                         "projections are computed without a bias here")
+    if published.get("use_expert_bias") and not published.get(
+            "norm_topk_prob", True):
+        raise ValueError(
+            "use_expert_bias with norm_topk_prob false: gates that are the "
+            "chosen experts' scores, not renormalised over the chosen, have "
+            "not been compared with a reference under a selection bias")
     if float(published.get("rotary_pct", 1)) != 1.0:
         raise ValueError(f"rotary_pct {published['rotary_pct']!r}: the rotary "
                          "embedding turns every pair of a head, or none")

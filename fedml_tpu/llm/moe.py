@@ -10,7 +10,9 @@ Design (static shapes throughout, nothing dropped):
   the sum of its two best experts, the ``topk_group`` best groups stay and
   the k best experts among them are chosen; one group is plain top-k —
   the same code), optional renormalisation over the chosen k and a
-  constant scale.  The softmax router also sows the load-balancing
+  constant scale.  Under a selection bias (``select_bias``) groups and
+  experts are chosen by ``scores + bias`` and the gates stay the chosen
+  experts' scores.  The softmax router also sows the load-balancing
   auxiliary loss (mean(token-fraction · prob-fraction) · E², the standard
   switch loss).
 - **Dispatch** (:func:`expert_ffn`): the ``N·k`` (token, expert) pairs are
@@ -84,17 +86,22 @@ def _ep_constraint(x, mesh):
 
 
 def route(scores, top_k: int, n_group: int = 1, topk_group: int = 1,
-          norm_topk: bool = True, scale: float = 1.0):
+          norm_topk: bool = True, scale: float = 1.0, bias=None):
     """``scores`` (N, E) float32, positive → ``(gates, experts)``, both
     (N, k): the k best experts of the ``topk_group`` best groups and their
-    weights."""
+    weights.  ``bias`` (E,) float32, or None for a zero one: the groups and
+    the experts are chosen by ``scores + bias``, and the gates are the chosen
+    experts' ``scores`` (the bias is not in them)."""
     n, e = scores.shape
     per = e // n_group
-    best2, _ = jax.lax.top_k(scores.reshape(n, n_group, per), min(2, per))
+    chosen_by = scores if bias is None else scores + bias
+    best2, _ = jax.lax.top_k(chosen_by.reshape(n, n_group, per), min(2, per))
     _, groups = jax.lax.top_k(best2.sum(-1), topk_group)        # (N, tg)
     kept = jnp.any(groups[:, :, None] == jnp.arange(n_group), axis=1)
-    masked = jnp.where(jnp.repeat(kept, per, axis=1), scores, -jnp.inf)
+    masked = jnp.where(jnp.repeat(kept, per, axis=1), chosen_by, -jnp.inf)
     gates, experts = jax.lax.top_k(masked, top_k)
+    if bias is not None:
+        gates = jnp.take_along_axis(scores, experts, axis=1)
     if norm_topk:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-20)
     return gates * scale, experts
@@ -138,6 +145,9 @@ class MoEMLP(nn.Module):
     topk_group: int = 1
     norm_topk: bool = True
     routed_scale: float = 1.0
+    #: the experts are chosen by ``scores + select_bias``, a float32 buffer
+    #: of (E,) among the parameters that no gradient reaches
+    select_bias: bool = False
     #: (first, count): the experts whose weights this layer holds; None =
     #: all of them
     held: Optional[Tuple[int, int]] = None
@@ -159,8 +169,11 @@ class MoEMLP(nn.Module):
             scores = jax.nn.sigmoid(logits)
         else:
             scores = jax.nn.softmax(logits, axis=-1)             # (N, E)
+        bias = jax.lax.stop_gradient(self.param(
+            "select_bias", nn.initializers.zeros, (e,), jnp.float32)) \
+            if self.select_bias else None
         gates, experts = route(scores, k, self.n_group, self.topk_group,
-                               self.norm_topk, self.routed_scale)
+                               self.norm_topk, self.routed_scale, bias)
 
         if self.scoring == "softmax":
             # load-balancing aux loss (store for the trainer to read)

@@ -17,6 +17,9 @@ KV cache is ONE page pool per layer (:mod:`fedml_tpu.serving.paged_kv`,
 docs/SERVING.md "Memory plane"): every slot addresses its own pages through
 a block table carried as traced data, admission reserves pages on the host,
 and a prompt enters in fixed-size chunks that share the tick with decode.
+A layer whose decode needs state that is not K/V (the short convolution's
+last rows of input) keeps it beside the pool, a row a slot, with no
+reservation (docs/SERVING.md "State that is not pages").
 
 Multi-tenant LoRA (``adapter_slots``/``adapter_registry``, see
 :mod:`fedml_tpu.serving.adapters` and docs/SERVING.md): N adapters live
@@ -338,6 +341,26 @@ class ContinuousBatchingEngine:
             geometry["kv_window_pool_pages"] = window_pages
             self.window_pool = PagedBlockPool(window_pages)
         self._window_pages_freed = 0
+        # state beside pages (docs/SERVING.md, "State that is not pages"): a
+        # layer that mixes by the short convolution keeps, for every slot,
+        # the last rows of its input in one buffer a layer, a row a slot and
+        # a trash row.  A slot owns its row for as long as the engine lives:
+        # nothing is reserved and no request parks for state.  The programs
+        # keep three rules: a request's first chunk starts from zeros (its
+        # first position is 0: no upload, no host write), every chunk and
+        # tick writes its lanes' rows back, and a lane that is not live
+        # addresses the trash row, so a slot between two chunks rides a tick
+        # and keeps what its last chunk left.
+        self._stateful = bool(getattr(cfg, "conv_layers", 0))
+        if self._stateful:
+            if prefix_cache_slots:
+                raise PagedKVUnsupportedError(
+                    "prefix pages are not shared by a model with "
+                    "convolution layers: a lent prefix would need the "
+                    "layers' state at its end, of which no snapshot is "
+                    "kept (prefix_cache_slots=0 for a model with conv "
+                    "layers)")
+            geometry["state_slots"] = self.n_slots
         self.paged_model = type(model)(dataclasses.replace(cfg, **geometry))
         self.page_pool = PagedBlockPool(pool_pages)
         self._btabs = np.zeros((self.n_slots, self.max_blocks), np.int32)
@@ -367,6 +390,8 @@ class ContinuousBatchingEngine:
                    if jax.default_backend() == "tpu" else None)
         key_words = int(np.asarray(jax.random.PRNGKey(0)).size)
         two_pools = self.window_pool is not None
+        stateful = self._stateful
+        n_slots = self.n_slots
 
         def tables(state, pick):
             """What the model takes as ``block_tables``: ``pick`` of the
@@ -398,6 +423,11 @@ class ContinuousBatchingEngine:
             params = dequantize_params(params, wdtype)
             live = state["left"] > 0
             btabs = tables(state, lambda t: jnp.where(live[:, None], t, 0))
+            # the rows of state, for a model that keeps any: a lane that is
+            # not live addresses the trash row, the twin of its all-trash
+            # table
+            rows = {"state_rows": jnp.where(live, jnp.arange(n_slots),
+                                            n_slots)} if stateful else {}
 
             def body(carry, _):
                 pool, toks, poss, keys = carry
@@ -407,7 +437,7 @@ class ContinuousBatchingEngine:
                 logits, mut = pm.apply(
                     variables, toks[:, None], decode=True,
                     start_pos=poss, block_tables=btabs,
-                    mutable=["cache", COUNTERS])
+                    mutable=["cache", COUNTERS], **rows)
                 split = jax.vmap(jax.random.split)(keys)
                 nxt = jax.vmap(
                     lambda lg, sub, temp: _sample_live(
@@ -468,11 +498,15 @@ class ContinuousBatchingEngine:
             variables = {"params": params, "cache": pool}
             if lora is not None:
                 variables["lora"] = lora
+            # the slot's own row of state; of a final chunk's C positions
+            # those up to the prompt's last are real
+            rows = {"state_rows": slot[None], "seq_lens": jnp.where(
+                left >= 0, idx + 1, C)[None]} if stateful else {}
             logits, mut = pm.apply(
                 variables, chunk[None, :C], decode=True,
                 start_pos=start[None],
                 block_tables=tables(state, lambda t: t[slot][None]),
-                mutable=["cache", COUNTERS])
+                mutable=["cache", COUNTERS], **rows)
             tok = _sample_live(logits[0, idx], key, state["temps"][slot],
                                self.top_k, self.top_p)
             def handed(vec, new):
@@ -571,10 +605,15 @@ class ContinuousBatchingEngine:
             lambda s: jnp.zeros(s.shape, s.dtype), shapes["cache"])
         # what one cached token costs over all layers, whatever a page
         # holds (K and V rows of every kv head; one latent row) and
-        # whichever pool the layer's pages lie in
+        # whichever pool the layer's pages lie in; a leaf of state has no
+        # pages and is counted apart, whole
+        paged, state = [], []
+        for path, p in jax.tree_util.tree_leaves_with_path(self._pool):
+            (state if getattr(path[-1], "key", None) == "conv_state"
+             else paged).append(p)
         self._kv_bytes_per_token = sum(
-            p.nbytes // (p.shape[0] * ptok)
-            for p in jax.tree_util.tree_leaves(self._pool))
+            p.nbytes // (p.shape[0] * ptok) for p in paged)
+        self._state_bytes = sum(p.nbytes for p in state)
         # sparse layers: the chunk program's first accumulator
         self._moe_layers = sum(
             cfg.sparse_layer(i) for i in range(cfg.n_layers))
@@ -1157,6 +1196,10 @@ class ContinuousBatchingEngine:
                              request=s.request, start=cs,
                              tokens=min(C, n - cs), final=int(final)) as span:
                 freed = self._prefill_chunk(tracer, i, s, cs, final)
+                if self._stateful:
+                    # the row the chunk wrote back, and whether it read it
+                    # from an earlier chunk (a first chunk starts from zeros)
+                    span.set(state_rows=1, state_carried=int(cs > 0))
                 if self.window_pool is not None:
                     span.set(window_pages_freed=freed,
                              attn_pages=self._count_attn_pages(
@@ -1304,6 +1347,11 @@ class ContinuousBatchingEngine:
         out["pages_shared"] = shared
         out["pages_private"] = private
         out["kv_bytes_per_token"] = self._kv_bytes_per_token
+        if self._stateful:
+            # state that is not pages: every layer's buffer, whole, and its
+            # rows (a row a slot and the trash row)
+            out["state_bytes"] = self._state_bytes
+            out["state_rows"] = self.n_slots + 1
         if self.window_pool is not None:
             # the window layers' pool beside the full layers' (``pool``):
             # reserved, released and refused (``exhausted``) of its own,
@@ -1500,6 +1548,8 @@ class ContinuousBatchingEngine:
         tracer.counter("serve.prefill_chunks", chunks)
         tracer.counter("serve.kv_bytes_per_token",
                        self._kv_bytes_per_token)
+        if self._stateful:
+            tracer.counter("serve.state_bytes", self._state_bytes)
         if self._moe_layers:
             tracer.counter("serve.expert_load_max",
                            self._expert_load_max)
@@ -1516,10 +1566,17 @@ class ContinuousBatchingEngine:
     def _tick_span(self, tracer, live, tracing: bool):
         """The ``serve.tick`` span ``_dispatch`` opens; what needs a sum
         over the slots is summed only when tracing."""
+        args = {}
+        if self._stateful and tracing:
+            # rows of state the tick writes back (its live lanes), and the
+            # slots between two chunks of a prompt, whose rows it leaves
+            # alone (their lanes address the trash row)
+            args = {"state_rows": len(live), "state_held": sum(
+                s.prefilling and s.pf_next > 0 for s in self._slots)}
         return tracer.span(
             "serve.tick", cat="engine", live=len(live),
             live_kv_tokens=(sum(self._slots[i].pos for i in live)
-                            if tracing else None))
+                            if tracing else None), **args)
 
     def _dispatch(self, live):
         """One device tick for the slots with a lane in it, launched

@@ -92,6 +92,12 @@ def _sample_live(live, key, temp, top_k: int, top_p: float = 1.0):
     return jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
 
 
+def _keeps_state(model) -> bool:
+    """Does the model keep per-request state that is not K/V (the rows of
+    ``llm/model.py::ShortConv``), of which no snapshot can be taken?"""
+    return bool(getattr(getattr(model, "cfg", None), "conv_layers", 0))
+
+
 @functools.lru_cache(maxsize=32)
 def _build_plain_step(apply_fn: Callable, top_k: int, top_p: float):
     """Jitted full-buffer step, cached across requests (a per-request
@@ -132,11 +138,19 @@ def _build_cached_decode(model, top_k: int, top_p: float):
             v["lora"] = lora
         return v
 
+    # a model that keeps state by rows (llm/model.py::ShortConv) is told how
+    # many of a call's positions are real: the rest is the buffer's padding
+    stateful = _keeps_state(model)
+
+    def _real(n):
+        return {"seq_lens": jnp.reshape(n, (1,))} if stateful else {}
+
     @jax.jit
     def prefill(params, lora, buf, n, key, temp):
         logits, mut = model.apply(
             _vars(params, lora), buf, decode=True,
-            start_pos=jnp.zeros((), jnp.int32), mutable=["cache"])
+            start_pos=jnp.zeros((), jnp.int32), mutable=["cache"],
+            **_real(n))
         live = jax.lax.dynamic_index_in_dim(logits[0], n - 1, axis=0,
                                             keepdims=False)
         return _sample_live(live, key, temp, top_k, top_p), mut["cache"]
@@ -165,7 +179,8 @@ def _build_cached_decode(model, top_k: int, top_p: float):
                                       (1, TAIL_BLOCK))
         logits, mut = model.apply(
             {**_vars(params, lora), "cache": cache}, block,
-            decode=True, start_pos=start, mutable=["cache"])
+            decode=True, start_pos=start, mutable=["cache"],
+            **_real(n - start))
         live = jax.lax.dynamic_index_in_dim(logits[0], n - 1 - start,
                                             axis=0, keepdims=False)
         return _sample_live(live, key, temp, top_k, top_p), mut["cache"]
@@ -377,6 +392,10 @@ def generate(apply_fn: Callable, params, prompt_ids: List[int],
         # server's shared zero adapter) caches normally while a CHANGE of
         # adapter invalidates wholesale — stale cross-adapter KV can
         # never serve
+        if prefix_cache is not None and _keeps_state(model):
+            raise ValueError(
+                "prefix_cache with a model that has convolution layers: the "
+                "state at the end of a shared prefix is not kept")
         hit_len, hit_cache = (prefix_cache.lookup(prompt_ids, raw_params,
                                                   lora)
                               if prefix_cache is not None and n > 0
@@ -509,6 +528,12 @@ class OpenAICompatServer:
                              "target) — speculative decode is cache-based")
         if draft_model is not None and draft_params is None:
             raise ValueError("draft_model requires draft_params")
+        if draft_model is not None and (_keeps_state(model)
+                                        or _keeps_state(draft_model)):
+            raise ValueError(
+                "draft_model with a model that has convolution layers: a "
+                "rejected draft token has already moved the layers' state, "
+                "and no snapshot is kept to take it back")
         # prefix_cache_slots > 0 (requires ``model``): reuse prefill KV
         # for shared prompt prefixes.  Non-engine path: one PrefixCache
         # consulted by generate(); engine path: the engine shares pages
@@ -516,6 +541,12 @@ class OpenAICompatServer:
         # (self.prefix_cache aliases it below so stats stay reachable
         # either way; the fall-through around the engine uses none).
         self.prefix_cache = None
+        if prefix_cache_slots and _keeps_state(model):
+            raise ValueError(
+                "prefix_cache_slots with a model that has convolution "
+                "layers: a cached prefix would need the layers' state at "
+                "the end of the part that is shared, of which no snapshot "
+                "is kept")
         if prefix_cache_slots and model is None:
             raise ValueError("prefix_cache_slots requires `model` "
                              "(prefix caching is KV-cache-based)")

@@ -325,6 +325,89 @@ def mixed_programs(one_chip):
     return _engine_programs(eng, one_chip)
 
 
+@pytest.fixture(scope="module")
+def stateful_programs(one_chip):
+    """The same two programs of a model whose layers mix by the gated short
+    convolution or by attention, at the widths and the engine geometry of
+    ``benchmarks/workloads/serve-chat-512.lfm2-8b-a1b-d13.json`` (64 slots, a
+    buffer of 736, chunk 256, 2,721 pages of 16 tokens, 17 bank rows; 32 query
+    and 8 kv heads of 64 with norms on q and k, 32 experts of 1,792 chosen 4 a
+    token under a selection bias, a tied head), one convolution and one
+    attention layer deep, both sparse.  The weights are shapes only."""
+    from fedml_tpu.llm.model import LlamaConfig, LlamaLM
+    from fedml_tpu.serving.batching import ContinuousBatchingEngine
+
+    cfg = LlamaConfig(
+        vocab_size=512, dim=2048, n_layers=2, n_heads=32, n_kv_heads=8,
+        ffn_dim=7168, max_seq_len=736, rope_theta=1e6, norm_eps=1e-5,
+        dtype=jnp.bfloat16, lora_rank=16, lora_alpha=16.0,
+        layer_types=("conv", "full_attention"), conv_kernel=3, qk_norm=True,
+        tie_embeddings=True, n_experts=32, moe_top_k=4, moe_ffn_dim=1792,
+        moe_scoring="sigmoid", moe_select_bias=True)
+    model = LlamaLM(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ContinuousBatchingEngine(
+        model, params, slots=64, buf_len=736, adapter_slots=17,
+        kv_page_tokens=16, kv_pool_pages=2721, prefill_chunk_tokens=256)
+    assert eng.max_blocks == 62 and eng._state_bytes == 65 * 2 * 2048 * 2
+    return _engine_programs(eng, one_chip)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_stateful_program_moves_neither_a_pool_nor_the_state(
+        stateful_programs, program):
+    """The rule below, for state beside pages: the two pools (K and V of the
+    attention layer) and the convolution layer's rows of state (a row a slot
+    and the trash row) are each updated where they lie by one scatter, alone
+    in its fusion, donated and aliased, and otherwise only named: nothing of
+    a pool's or of the state's size is copied, transposed or re-laid out.
+    Heads 64 wide, 4 query rows a kv head, are not the paged-attention
+    kernel's (``ops/paged_attention.py::kernel_can_run``): the read is the
+    gather, out of a pool whose rows are flat (with a trailing axis of 64
+    the compiler padded every page to twice its size and copied each pool
+    four times a program).  The experts run as the two grouped-matmul kernels at 2048 and
+    1792."""
+    compiled, donated = stateful_programs
+    pools = [p for p in donated if p.ndim == 3 and p.shape[0] == 2721]
+    rows = [p for p in donated if p.ndim == 3 and p.shape[0] == 65]
+    state = [p for p in donated if p.ndim < 3]
+    # a page's row is the 8 kv heads of 64 side by side: whole lane tiles
+    assert [p.shape for p in pools] == [(2721, 16, 512)] * 2
+    assert [p.shape for p in rows] == [(65, 2, 2048)] and len(state) == 7
+    compiled = compiled[program]
+    hlo = compiled.as_text()
+    assert "ragged-dot" not in hlo and hlo.count("tpu_custom_call") >= 2
+    assert "%gated_matmul" in hlo and "%grouped_matmul" in hlo
+    assert "%paged_attention" not in hlo
+    naming = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+    instrs = _pool_sized_instructions(hlo, pools[0].size)
+    moving = [(op, line[:200]) for op, line in instrs
+              if op not in naming | {"scatter", "fusion"}]
+    assert not moving, moving
+    scatters = [line for op, line in instrs if op == "scatter"]
+    assert len(scatters) == 2 and all(
+        line.startswith("ROOT ") for line in scatters), scatters
+    assert sum(op == "fusion" for op, _ in instrs) == 2
+    # the state, half a megabyte a layer: written where it lies by the tick's
+    # one scatter of its lanes' rows, or by the chunk's one row.  The
+    # compiler may fetch it whole into fast memory and put it back, as it
+    # does a weight matrix (a start and a done each way); nothing re-lays it
+    # out
+    instrs = _pool_sized_instructions(hlo, rows[0].size)
+    moving = [(op, line[:200]) for op, line in instrs if op not in naming | {
+        "scatter", "dynamic-update-slice", "fusion", "copy-start",
+        "copy-done"}]
+    assert not moving, moving
+    assert sum(op in ("scatter", "dynamic-update-slice")
+               for op, _ in instrs) == 1
+    aliases = re.search(r"input_output_alias=\{(.*?) \}, ", hlo).group(1)
+    assert aliases.count("-alias)") == len(pools) + len(rows) + len(state)
+    held_bytes = sum(p.size * p.dtype.itemsize for p in pools + rows)
+    assert compiled.memory_analysis().alias_size_in_bytes >= held_bytes
+    assert "slice-start" not in hlo and "copy-start" in hlo
+
+
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
 def test_two_pool_program_never_moves_a_whole_pool(mixed_programs, program):
     """The rule below, for a pool per kind of layer: each of the four pools

@@ -169,7 +169,7 @@ def test_the_keys_and_no_familys_name_decide_the_fields():
 
 
 @pytest.mark.parametrize("key, value, named", [
-    ("use_qk_norm", True, "use_qk_norm"),
+    ("conv_bias", True, "conv_bias"),
     ("rotary_pct", 0.5, "rotary_pct"),
     ("use_gated_activation", False, "use_gated_activation"),
     ("shared_expert_combination_strategy", "concat", "shared_expert_combination_strategy"),

@@ -120,8 +120,12 @@ def test_published_config_arrives_through_the_arguments(tmp_path):
             cfg.n_shared_experts, cfg.moe_scoring, cfg.moe_n_group, cfg.moe_topk_group,
             cfg.moe_norm_topk, cfg.moe_routed_scale, cfg.experts_held) == (
                 32, 4, 16, 1, 1, "sigmoid", 4, 2, True, 2.5, (8, 8))
-    with pytest.raises(ValueError, match="noaux_tc"):
-        config_from_args(types.SimpleNamespace(llm_config_json={**published, "topk_method": "noaux_tc"}))
+    # selection under a bias that is not in the gates is route's own rule
+    assert config_from_args(types.SimpleNamespace(
+        llm_config_json={**published, "topk_method": "noaux_tc"})).moe_select_bias
+    assert not cfg.moe_select_bias
+    with pytest.raises(ValueError, match="some_other_rule"):
+        config_from_args(types.SimpleNamespace(llm_config_json={**published, "topk_method": "some_other_rule"}))
 
 
 def test_int8_cache_with_latent_attention_raises_at_construction(setting):

@@ -20,10 +20,17 @@ Three entries share the projections:
 
 - full sequence (training, the single-request path): the expanded form
   through ``ops/attention`` with q/k ``nope + rope`` wide and v ``v_head_dim``;
-- paged, over a slot's window of the pool, in whichever of two forms of the
-  same mathematics costs fewer operations at the call's shapes
-  (:func:`absorbed_is_cheaper`): **absorbed** — ``W_UK`` folded into the
-  query and ``W_UV`` applied after the weighted sum, so the products run over
+- paged, lowered for a TPU with a bfloat16 pool at widths that tile
+  (:func:`_read_pool`, ``ops/latent_attention.py::kernel_can_run``): the
+  **absorbed** form as one Pallas kernel that reads the pool where it lies,
+  each lane's own live pages under a running softmax, for the decode tick
+  and the prefill chunk alike (:func:`attend_pool`: ``W_UK`` folded into the
+  query before it, ``W_UV`` applied after it, one fetch of a page serving
+  key and value);
+- paged, everywhere else (the CPU, a float32 model): every lane's whole
+  table gathered into a window (:func:`attend_window`), then whichever of two
+  ``jnp`` forms of the same mathematics costs fewer operations at the call's
+  shapes (:func:`absorbed_is_cheaper`): **absorbed** — the products run over
   the latent itself (the decode tick, one query row a slot) — or **expanded**
   — the window's latent multiplied out to per-head keys and values once (a
   prefill chunk, hundreds of query rows).
@@ -34,12 +41,14 @@ product with an input.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import latent_attention as la
 from ..ops.attention import blockwise_attention, flash_attention
 from .model import (LlamaConfig, RMSNorm, _attn_impl, _projection, _rope,
                     yarn_mscale)
@@ -83,6 +92,26 @@ def expand(c_kv, k_r, w_kvb, nope: int):
     return jnp.concatenate([kv[..., :nope], k_r], axis=-1), kv[..., nope:]
 
 
+def absorb_query(q_nope, q_rope, w_uk, width: int, dtype):
+    """``W_UK`` folded into the query: ``q_nope`` (b, h, s, nope) and
+    ``q_rope`` (b, h, s, rope) -> (b, h, s, width) rows ``[q_nope · W_UK
+    (rank) ; q_rope ; zeros]`` in ``dtype``, one product with a pool row:
+    the query is zero where the row is."""
+    q_lat = jnp.einsum("bhsn,rhn->bhsr", q_nope, w_uk,
+                       preferred_element_type=jnp.float32).astype(dtype)
+    pad = width - q_lat.shape[-1] - q_rope.shape[-1]
+    return jnp.concatenate([q_lat, q_rope.astype(dtype),
+                            jnp.zeros(q_lat.shape[:-1] + (pad,), dtype)],
+                           axis=-1)
+
+
+def unabsorb(o_lat, w_uv):
+    """``W_UV`` after the weighted sum: ``o_lat`` (b, h, s, rank) ->
+    (b, h, s, v)."""
+    return jnp.einsum("bhsr,rhv->bhsv", o_lat, w_uv,
+                      preferred_element_type=jnp.float32).astype(o_lat.dtype)
+
+
 def attend_absorbed(q_nope, q_rope, window, w_kvb, pos, scale: float,
                     split: Tuple[int, int]):
     """``q_nope`` (b, h, s, nope), ``q_rope`` (b, h, s, rope), ``window``
@@ -90,14 +119,8 @@ def attend_absorbed(q_nope, q_rope, window, w_kvb, pos, scale: float,
     ``w_kvb`` (rank, h, nope + v), ``pos`` (b, s) the queries' positions
     -> (b, h, s, v)."""
     rank, nope = split
-    w_uk, w_uv = w_kvb[..., :nope], w_kvb[..., nope:]
-    q_lat = jnp.einsum("bhsn,rhn->bhsr", q_nope, w_uk,
-                       preferred_element_type=jnp.float32).astype(window.dtype)
-    # one product over the whole row: the query is zero where the row is
-    pad = window.shape[-1] - rank - q_rope.shape[-1]
-    q = jnp.concatenate([q_lat, q_rope.astype(window.dtype),
-                         jnp.zeros(q_lat.shape[:-1] + (pad,), window.dtype)],
-                        axis=-1)
+    q = absorb_query(q_nope, q_rope, w_kvb[..., :nope], window.shape[-1],
+                     window.dtype)
     scores = jnp.einsum("bhsc,bwc->bhsw", q, window,
                         preferred_element_type=jnp.float32) * scale
     mask = jnp.arange(window.shape[1])[None, None, :] <= pos[:, :, None]
@@ -105,8 +128,21 @@ def attend_absorbed(q_nope, q_rope, window, w_kvb, pos, scale: float,
     probs = jax.nn.softmax(scores, axis=-1).astype(window.dtype)
     o_lat = jnp.einsum("bhsw,bwr->bhsr", probs, window[..., :rank],
                        preferred_element_type=jnp.float32).astype(window.dtype)
-    return jnp.einsum("bhsr,rhv->bhsv", o_lat, w_uv,
-                      preferred_element_type=jnp.float32).astype(window.dtype)
+    return unabsorb(o_lat, w_kvb[..., nope:])
+
+
+def attend_pool(q_nope, q_rope, pool, tables, w_kvb, pos, scale: float,
+                split: Tuple[int, int], interpret: bool = False):
+    """:func:`attend_absorbed` over the pages ``tables`` (b, entries) names
+    in ``pool`` (pages, P, row), read where they lie by the kernel of
+    ``ops/latent_attention.py``: each lane's own live pages and no window
+    gathered."""
+    rank, nope = split
+    q = absorb_query(q_nope, q_rope, w_kvb[..., :nope], pool.shape[-1],
+                     pool.dtype)
+    o_lat = la.latent_attention(q, pool, tables, pos, rank=rank,
+                                sm_scale=scale, interpret=interpret)
+    return unabsorb(o_lat, w_kvb[..., nope:])
 
 
 def attend_expanded(q_nope, q_rope, window, w_kvb, pos, scale: float,
@@ -121,6 +157,39 @@ def attend_expanded(q_nope, q_rope, window, w_kvb, pos, scale: float,
     q = jnp.concatenate([q_nope, q_rope.astype(q_nope.dtype)], axis=-1)
     return blockwise_attention(q.astype(window.dtype), k, v, True, scale,
                                q_positions=pos[:, None, :])
+
+
+def attend_window(cfg: LlamaConfig, q_nope, q_rope, pool, tables, w_kvb, pos,
+                  scale: float):
+    """The paged read in plain ``jnp``: every lane's whole table gathered
+    into a window (b, entries · P, row), then whichever form costs fewer
+    operations at the call's rows (:func:`absorbed_is_cheaper`)."""
+    b, s = pos.shape
+    window = pool[tables].reshape(b, -1, pool.shape[-1])
+    attend = attend_absorbed if absorbed_is_cheaper(cfg, s) \
+        else attend_expanded
+    return attend(q_nope, q_rope, window, w_kvb, pos, scale,
+                  (cfg.kv_lora_rank, cfg.qk_nope_head_dim))
+
+
+def _read_pool(cfg: LlamaConfig, q_nope, q_rope, pool, tables, w_kvb, pos,
+               scale: float):
+    """Attention of a call's queries over the pool, the rows of the call
+    already written: where the program is lowered for a TPU and the
+    operands allow (``ops/latent_attention.py::kernel_can_run``: a bfloat16
+    pool, a row and a rank of whole lanes, pages and rows that tile) the
+    kernel over each lane's live pages (:func:`attend_pool`); everywhere
+    else (the CPU, a float32 model, odd widths) the gathered window
+    (:func:`attend_window`)."""
+    gathered = functools.partial(attend_window, cfg, scale=scale)
+    q = jax.ShapeDtypeStruct(q_nope.shape[:-1] + pool.shape[-1:], pool.dtype)
+    if not la.kernel_can_run(q, pool, tables, cfg.kv_lora_rank):
+        return gathered(q_nope, q_rope, pool, tables, w_kvb, pos)
+    kernel = functools.partial(
+        attend_pool, scale=scale,
+        split=(cfg.kv_lora_rank, cfg.qk_nope_head_dim))
+    return jax.lax.platform_dependent(q_nope, q_rope, pool, tables, w_kvb,
+                                      pos, tpu=kernel, default=gathered)
 
 
 class _Matrix(nn.Module):
@@ -199,14 +268,11 @@ class MLA(nn.Module):
                         c_kv.dtype)
         rows = jnp.concatenate([c_kv, k_r, pad], axis=-1).astype(cfg.dtype)
         pool.value = pool.value.at[page, pos % ptok].set(rows)
-        b, s = pos.shape
-        window = pool.value[block_tables].reshape(b, -1, width)
-        attend = attend_absorbed if absorbed_is_cheaper(cfg, s) \
-            else attend_expanded
-        return attend(q_nope, q_rope, window, w_kvb, pos, scale,
-                      (cfg.kv_lora_rank, cfg.qk_nope_head_dim))
+        return _read_pool(cfg, q_nope, q_rope, pool.value, block_tables,
+                          w_kvb, pos, scale)
 
 
-__all__ = ["MLA", "attend_absorbed", "attend_expanded", "expand",
+__all__ = ["MLA", "attend_absorbed", "attend_expanded", "attend_pool",
+           "attend_window", "absorb_query", "unabsorb", "expand",
            "absorbed_is_cheaper", "softmax_scale", "latent_width",
            "pool_row_width"]

@@ -621,13 +621,15 @@ class ContinuousBatchingEngine:
             if self._moe_layers else None
         self._chunk_words = self.prefill_chunk + 5 + key_words \
             + self.window_blocks
-        # the layers whose paged read is the paged-attention kernel in this
-        # process's programs, for the tick's lanes and for a chunk's rows:
-        # what ``attn_pages`` counts (none where the ``jnp`` forms run)
+        # the layers whose paged read is a kernel over each lane's live
+        # pages in this process's programs, for the tick's lanes and for a
+        # chunk's rows: what ``attn_pages`` counts (none where the ``jnp``
+        # forms run), for the models that have such a kernel
+        self._counts_attn_pages = two_pools or cfg.kv_lora_rank > 0
         self._kernel_reads = {
             "tick": self._reads_by_kernel(self.n_slots, 1),
             "chunk": self._reads_by_kernel(1, self.prefill_chunk)} \
-            if two_pools else {}
+            if self._counts_attn_pages else {}
         self._attn_pages = 0
         self._host_device = jax.devices("cpu")[0]
 
@@ -1107,7 +1109,8 @@ class ContinuousBatchingEngine:
         """``(layers, window, ring, entries)`` a kind of layer: those of the
         paged model whose read, in a program of ``b`` lanes of ``s``
         positions, walks its table and whose walk is the kernel of
-        ``ops/paged_attention.py`` here."""
+        ``ops/paged_attention.py`` here; of a model with latent attention,
+        every layer where its read is ``ops/latent_attention.py``'s."""
         from ..llm.model import paged_read_walks
         from ..ops import paged_attention as pa
         cfg = self.paged_model.cfg
@@ -1116,6 +1119,17 @@ class ContinuousBatchingEngine:
         def shape(*dims, dtype=cfg.dtype):
             return jax.ShapeDtypeStruct(dims, dtype)
 
+        if cfg.kv_lora_rank:    # one pool a layer, no window, no ring
+            from ..llm.mla import pool_row_width
+            from ..ops import latent_attention as la
+            row = pool_row_width(cfg)
+            if la.engages(
+                    shape(b, cfg.n_heads, s, row),
+                    shape(cfg.kv_pool_pages, cfg.kv_page_tokens, row),
+                    shape(b, self.max_blocks, dtype=jnp.int32),
+                    cfg.kv_lora_rank):
+                return [(cfg.n_layers, 0, False, self.max_blocks)]
+            return []
         q = shape(b, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, s,
                   head_dim)
         kinds: Dict[tuple, int] = {}
@@ -1201,9 +1215,10 @@ class ContinuousBatchingEngine:
                     # from an earlier chunk (a first chunk starts from zeros)
                     span.set(state_rows=1, state_carried=int(cs > 0))
                 if self.window_pool is not None:
-                    span.set(window_pages_freed=freed,
-                             attn_pages=self._count_attn_pages(
-                                 "chunk", cs + np.arange(C)[None]))
+                    span.set(window_pages_freed=freed)
+                if self._counts_attn_pages:
+                    span.set(attn_pages=self._count_attn_pages(
+                        "chunk", cs + np.arange(C)[None]))
 
     def _prefill_chunk(self, tracer, i: int, s: "_Slot", cs: int,
                        final: bool) -> int:
@@ -1361,8 +1376,9 @@ class ContinuousBatchingEngine:
             out["window_pool_pages"] = self.window_pool.n_pages
             out["window_blocks"] = self.window_blocks
             out["window_pages_freed"] = freed_early
-            # pages the paged-attention kernel visited over all layers, in
-            # ticks and chunks; 0 where the reads ran in ``jnp``
+        if self._counts_attn_pages:
+            # pages the reads' kernel visited over all layers, in ticks and
+            # chunks; 0 where the reads ran in ``jnp``
             out["attn_pages"] = attn_pages
         out["expert_pairs"] = pairs
         out["experts_hit"] = hit
@@ -1593,6 +1609,8 @@ class ContinuousBatchingEngine:
             with tracer.span("serve.tick.stage", cat="engine"):
                 if self.window_pool is not None:
                     self._slide_lanes(tick, live)
+                if self._counts_attn_pages:
+                    self._advance_lanes(tick, live)
                 self._sync_rows()
             with tracer.span("serve.tick.dispatch", cat="engine"):
                 if self.registry is not None:
@@ -1629,8 +1647,6 @@ class ContinuousBatchingEngine:
         pages do."""
         ptok = self.kv_page_tokens
         full = held = freed = 0
-        pos = np.array([self._slots[i].dpos for i in live],
-                       np.int64).reshape(-1, 1)
         for i in live:
             s = self._slots[i]
             changed, behind = self._slide_window(i, s, s.dpos)
@@ -1639,11 +1655,19 @@ class ContinuousBatchingEngine:
             freed += behind
             full += s.dpos + 1
             held += s.dpos + 1 - s.w_first * ptok
-            s.dpos += self.horizon
         tick.set(window_pages_freed=freed, live_full_tokens=full,
-                 live_window_tokens=held,
-                 attn_pages=sum(self._count_attn_pages("tick", pos + step)
+                 live_window_tokens=held)
+
+    def _advance_lanes(self, tick, live) -> None:
+        """Before a tick is dispatched: on the span the pages its reads
+        visit where they are a kernel's, and every lane's ``dpos`` moved to
+        what the next tick writes."""
+        pos = np.array([self._slots[i].dpos for i in live],
+                       np.int64).reshape(-1, 1)
+        tick.set(attn_pages=sum(self._count_attn_pages("tick", pos + step)
                                 for step in range(self.horizon)))
+        for i in live:
+            self._slots[i].dpos += self.horizon
 
     def _flush(self) -> None:
         """Read back what is outstanding when there is nothing to launch

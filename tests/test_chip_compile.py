@@ -194,6 +194,42 @@ def test_paged_attention_compiles_for_v5e(one_chip, shape, table):
     assert compiled.memory_analysis().temp_size_in_bytes < pool.size
 
 
+# -- the latent pool's paged read at the latent cell's shapes -------------------
+
+@pytest.mark.parametrize("shape", ["tick", "chunk"])
+def test_latent_attention_compiles_for_v5e(one_chip, shape):
+    """``ops/latent_attention.py`` at the two shapes of
+    ``serve-saturated-2k.a.x-k1-ep16-d7``: a tick's 64 lanes of one query and
+    a chunk's 512, 64 heads against a pool of 10,241 pages of 16 rows of 640
+    (rank 512) through tables of 213 entries.  What ``mla._read_pool`` lowers
+    to for the chip at these operands is this call between its two small
+    products, and no window gathered."""
+    from fedml_tpu.llm import mla
+    from fedml_tpu.llm.model import LlamaConfig
+
+    def described(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cfg = LlamaConfig(vocab_size=512, dim=7168, n_layers=1, n_heads=64,
+                      n_kv_heads=64, ffn_dim=18432, dtype=jnp.bfloat16,
+                      q_lora_rank=1536, kv_lora_rank=512,
+                      qk_nope_head_dim=128, qk_rope_head_dim=64,
+                      v_head_dim=128, kv_page_tokens=16, kv_pool_pages=10241)
+    b, s = {"tick": (64, 1), "chunk": (1, 512)}[shape]
+    pool = described(10241, 16, 640)
+    operands = (described(b, 64, s, 128), described(b, 64, s, 64), pool,
+                described(b, 213, dtype=jnp.int32),
+                described(512, 64, 256), described(b, s, dtype=jnp.int32))
+    read = jax.jit(lambda *a: mla._read_pool(cfg, *a, 0.1))
+    compiled = read.lower(*operands).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1 and "%latent_attention" in hlo
+    assert not re.search(r" while\(", hlo)
+    # the pool is read where it lies: no window of it, no temporary its size
+    assert f"bf16[{b},3408,640]" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < pool.size
+
+
 # -- the paged decode programs never move a whole page pool -----------------
 
 @pytest.fixture(scope="module")
@@ -484,6 +520,12 @@ def test_paged_program_never_moves_a_whole_pool(request, model, program):
         assert hlo.count("tpu_custom_call") >= 2
         # under the kernels' own names, which is what a device trace shows
         assert "%gated_matmul" in hlo and "%grouped_matmul" in hlo
+        # the read is ``ops/latent_attention.py``'s kernel, once a layer,
+        # over each lane's own pages: no window is gathered (a tick's 64
+        # lanes, a chunk's one) and none multiplied out to the heads' keys
+        assert len(re.findall(r"%latent_attention[.\d]* = ", hlo)) == 2
+        assert not re.search(r"bf16\[(64|1),3408,640\]", hlo)
+        assert "[1,64,3408,192]" not in hlo
     # neither reads through the walk: the dense model's tables are gathered
     # whole, the latent model reads through ``llm/mla.py``
     assert "%paged_attention" not in hlo

@@ -136,14 +136,27 @@ def test_int8_cache_with_latent_attention_raises_at_construction(setting):
 
 # -- (b) chunked prefill, then paged ticks -------------------------------------------
 
-@pytest.mark.parametrize("chunk", [8, 32], ids=["absorbed-chunks", "expanded-chunks"])
-def test_paged_prefill_and_ticks_agree_by_logits(setting, chunk):
+def _kernel_in_interpret_mode(monkeypatch):
+    """What ``MLA._paged_attend`` reads through where the program is lowered
+    for a TPU, on the CPU: the kernel of ``ops/latent_attention.py`` in
+    interpret mode, whatever the dtype."""
+    monkeypatch.setattr(mla, "_read_pool", lambda cfg, *operands: mla.attend_pool(
+        *operands, split=(cfg.kv_lora_rank, cfg.qk_nope_head_dim), interpret=True))
+
+
+@pytest.mark.parametrize("chunk,kernel", [(8, False), (32, False), (32, True)],
+                         ids=["absorbed-chunks", "expanded-chunks", "kernel-chunks-and-ticks"])
+def test_paged_prefill_and_ticks_agree_by_logits(setting, chunk, kernel, monkeypatch):
     """Two requests on two adapters: each prompt goes into the latent pool
     chunk by chunk, then both decode in ONE batch, each slot on its own
-    adapter; every position's logits against the reference's full forward."""
+    adapter; every position's logits against the reference's full forward.
+    The third case reads the pool through the kernel (the absorbed form over
+    each lane's own pages) in both programs: the wiring of ``_paged_attend``."""
     import dataclasses
     lcfg, base, _, _ = setting
     assert mla.absorbed_is_cheaper(lcfg, 8) and not mla.absorbed_is_cheaper(lcfg, 32)
+    if kernel:
+        _kernel_in_interpret_mode(monkeypatch)
     ptok, pages = 4, 40
     pm = LlamaLM(dataclasses.replace(lcfg, kv_page_tokens=ptok, kv_pool_pages=pages))
     loras = [weights.make_lora(TINY, 5, index=i + 1) for i in range(2)]
@@ -228,6 +241,66 @@ def test_engine_serves_two_adapters_and_counts(setting):
     # in a tick; a tick's idle lane repeats its last token and is counted
     assert stats["expert_pairs"] >= pairs > 0
     assert 0 < stats["experts_hit"] <= HELD[1] * stats["moe_layers_ticked"]
+
+
+def test_attn_pages_counts_what_the_latent_kernel_visits(setting, monkeypatch):
+    """The engine's two programs with the read forced through the kernel in
+    interpret mode: every served token is still the reference's best, and
+    ``attn_pages`` on the spans and in ``kv_stats()`` is what
+    ``visited_pages`` counts, over the model's three layers, for the
+    positions each chunk and tick of each request stood at.  Without the
+    kernel (this process lowers for the CPU) the same spans read 0."""
+    from fedml_tpu import obs
+    from fedml_tpu.ops import latent_attention as la
+    from fedml_tpu.ops import paged_attention as pa
+    from fedml_tpu.serving.batching import ContinuousBatchingEngine
+    lcfg, base, _, _ = setting
+    lora = weights.make_lora(TINY, 5, index=1)
+    rng = np.random.default_rng(3)
+    requests = [([int(t) for t in rng.integers(1, 256, size=n)], m) for n, m in ((41, 9), (19, 6), (33, 7))]
+
+    def serve():
+        obs.configure(enabled=True, reset=True, jax_hooks=False)
+        eng = ContinuousBatchingEngine(LlamaLM(lcfg), base, slots=2, buf_len=96, adapter_slots=2,
+                                       kv_page_tokens=4, prefill_chunk_tokens=16)
+        try:
+            eng.registry.register("a0", lora)
+            queues = [eng.submit(ids, max_new_tokens=m, adapter="a0") for ids, m in requests]
+            outs = []
+            for q in queues:
+                outs.append([])
+                while (t := q.get(timeout=300)) is not None:
+                    outs[-1].append(t)
+            spans = [e for e in obs.get_tracer().events()
+                     if e["name"] in ("serve.tick", "serve.chunk") and e["ph"] == "E"]
+            return outs, eng.kv_stats(), spans, eng.max_blocks
+        finally:
+            eng.stop()
+            obs.configure(enabled=False)
+
+    _, stats, spans, _ = serve()
+    assert stats["attn_pages"] == 0 and spans
+    assert all(e["args"]["attn_pages"] == 0 for e in spans)
+
+    _kernel_in_interpret_mode(monkeypatch)
+    monkeypatch.setattr(la, "engages", lambda *operands: True)
+    outs, stats, spans, blocks = serve()
+    for (ids, m), out in zip(requests, outs):
+        assert len(out) == m
+        got = ref.forced_gaps(base, lora, jnp.asarray(ids + out)[None], TINY, HELD)
+        assert float(jnp.max((got["gap"] / got["spread"])[len(ids) - 1:len(ids) + m - 1])) < 1e-4
+
+    def pages(pos):
+        return lcfg.n_layers * pa.visited_pages(np.asarray(pos), np.ones(1, np.int64), window=0,
+                                                ring=False, entries=blocks, ptok=4)
+
+    # a prompt's chunks stand at 0, 16, ...; its ticks write n .. n + m - 2
+    want = sum(sum(pages(cs + np.arange(16)[None]) for cs in range(0, len(ids), 16))
+               + sum(pages([[p]]) for p in range(len(ids), len(ids) + m - 1))
+               for ids, m in requests)
+    assert stats["attn_pages"] == want > 0
+    assert sum(e["args"]["attn_pages"] for e in spans) == want
+    assert all(e["args"]["attn_pages"] > 0 for e in spans)
 
 
 def _pairs_by_hand(base, lora, ids, n_prompt, chunk):

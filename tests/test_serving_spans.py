@@ -432,6 +432,20 @@ def test_sparse_gauges_and_bytes_a_token(traced_sparse):
     assert "serve.expert_load_max" in counters
 
 
+def test_latent_ticks_and_chunks_carry_attn_pages(traced_sparse, traced):
+    """A model with latent attention has a kernel for its paged read
+    (``ops/latent_attention.py``), so its ticks and chunks say how many pages
+    it visited: 0 here, where the programs are lowered for the CPU and the
+    ``jnp`` forms run (``tests/test_mla_moe.py`` forces the kernel and counts
+    by hand).  A dense model's spans have no such arg."""
+    served = named(traced_sparse["spans"], "serve.tick") \
+        + named(traced_sparse["spans"], "serve.chunk")
+    assert served and all(s["args"]["attn_pages"] == 0 for s in served)
+    assert traced_sparse["stats"]["attn_pages"] == 0
+    dense = named(traced["spans"], "serve.tick") + named(traced["spans"], "serve.chunk")
+    assert dense and not any("attn_pages" in s["args"] for s in dense)
+
+
 def test_dense_engine_has_no_expert_args(traced):
     ticks = named(traced["spans"], "serve.tick")
     assert ticks and not any("expert_pairs" in t["args"] for t in ticks)

@@ -12,6 +12,17 @@ measurement, on stdout and in ``chiprun_out/paged_attn_bench.jsonl``.  Fails
 off the TPU.
 
     python tools/tpu_paged_attn_bench.py [--reps 20] [--only tick|chunk]
+
+``--pool latent``: the latent-attention kernel (``ops/latent_attention.py``)
+against the ``jnp`` forms of ``llm/mla.py`` over the gathered window (absorbed;
+for a chunk also expanded), at
+the shapes of the latent cell's programs (``serve-saturated-2k.
+a.x-k1-ep16-d7``: 64 heads, a pool of 10,241 pages of 16 rows of 640, rank
+512, tables of 213 entries).  A tick: 64 lanes at 1,800-2,600 cached tokens.
+A chunk: 512 rows at offsets 0, 1,024 and 1,792.  The floors are the live
+latent's bytes and the absorbed products' flops (``2·h·(2·rank + rope)`` a
+visible pair); ``kernel_us`` is the ``pallas_call`` without the two small
+products around it.
 """
 
 import argparse
@@ -26,7 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fedml_tpu.llm import mla
 from fedml_tpu.llm import model as M
+from fedml_tpu.ops import latent_attention as la
 from fedml_tpu.ops import paged_attention as pa
 
 HBM_BYTES_PER_S = 819e9         # TPU v5e (benchmarks/peaks.json)
@@ -43,6 +56,16 @@ CHUNK = 1024
 CHUNK_ENDS = (1024, 5120, 13312)
 
 
+#: the latent cell: heads, rank, rope, nope, v, row, entries, pool pages
+L_H, L_RANK, L_ROPE, L_NOPE, L_V, L_ROW = 64, 512, 64, 128, 128, 640
+L_ENTRIES, L_PAGES, L_LANES, L_CHUNK = 213, 10241, 64, 512
+L_TICK_DEPTHS = (1800, 2600)
+L_CHUNK_OFFSETS = (0, 1024, 1792)
+L_TICK_SWEEP = (8, 16, 32, 64)
+L_CHUNK_SWEEP = ((8, 16), (16, 16), (32, 16), (8, 32), (16, 32), (32, 32),
+                 (16, 64))
+
+
 def timed(fn, args, reps):
     jax.block_until_ready(fn(*args))
     jax.block_until_ready(fn(*args))
@@ -51,6 +74,21 @@ def timed(fn, args, reps):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / reps
+
+
+def measure(emit, fn, args, reps, nbytes, flops, want=None, **fields):
+    """One JSON line for ``fn(*args)``: microseconds a call against the two
+    floors, and the widest gap to ``want``; returns the result in float32."""
+    secs = timed(fn, args, reps)
+    got = fn(*args).astype(jnp.float32)
+    gap = None if want is None else float(jnp.max(jnp.abs(got - want)))
+    emit(us=round(secs * 1e6, 1),
+         bytes_floor_us=round(nbytes / HBM_BYTES_PER_S * 1e6, 1),
+         flops_floor_us=round(flops / FLOPS_PER_S * 1e6, 1),
+         hbm_share=round(nbytes / secs / HBM_BYTES_PER_S, 4),
+         mxu_share=round(flops / secs / FLOPS_PER_S, 4),
+         max_abs_gap=gap, **fields)
+    return got
 
 
 def lanes(kind, depths, s, rng):
@@ -80,10 +118,81 @@ def floors(kind, pos):
             int(seen.sum()) * G * REP * D * 4)
 
 
+def latent(opts, emit):
+    """The latent pool's read: the kernel against the two ``jnp`` forms."""
+    rng = np.random.default_rng(0)
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    bf = jnp.bfloat16
+    scale = (L_NOPE + L_ROPE) ** -0.5
+    split = (L_RANK, L_NOPE)
+    pool = jax.random.normal(keys[2], (L_PAGES, PTOK, L_ROW), bf)
+    pool = pool.at[..., L_RANK + L_ROPE:].set(0)
+    w_kvb = jax.random.normal(keys[3], (L_RANK, L_H, L_NOPE + L_V), bf) \
+        * L_RANK ** -0.5
+    shapes = {
+        "tick": (1, [("cell", [int(d) for d in rng.integers(
+            *L_TICK_DEPTHS, L_LANES)])], [(n, 0) for n in L_TICK_SWEEP]),
+        "chunk": (L_CHUNK, [(f"offset_{o}", [o + L_CHUNK - 1])
+                            for o in L_CHUNK_OFFSETS], L_CHUNK_SWEEP)}
+    for shape, (s, loads, sweep) in shapes.items():
+        if opts.only and shape != opts.only:
+            continue
+        for load, depths in loads:
+            b = len(depths)
+            q_nope = jax.random.normal(keys[0], (b, L_H, s, L_NOPE), bf)
+            q_rope = jax.random.normal(keys[1], (b, L_H, s, L_ROPE), bf)
+            tables = np.zeros((b, L_ENTRIES), np.int32)
+            pos = np.zeros((b, s), np.int32)
+            free = iter(rng.permutation(np.arange(1, L_PAGES)))
+            for i, depth in enumerate(depths):
+                pos[i] = depth - s + 1 + np.arange(s)
+                for j in range(depth // PTOK + 1):
+                    tables[i, j] = next(free)
+            args = (q_nope, q_rope, pool, jnp.asarray(tables), w_kvb,
+                    jnp.asarray(pos))
+            nbytes = int((pos[:, -1] + 1).sum()) * L_ROW * 2
+            flops = int((pos + 1).sum()) * 2 * L_H * (2 * L_RANK + L_ROPE)
+
+            def report(impl, fn, want=None, **more):
+                return measure(emit, fn, args, opts.reps, nbytes, flops, want,
+                               pool="latent", shape=shape, load=load,
+                               impl=impl, **more)
+
+            def window(attend):
+                return jax.jit(lambda qn, qr, pool, tables, w, pos: attend(
+                    qn, qr, pool[tables].reshape(b, -1, L_ROW), w, pos,
+                    scale, split))
+
+            want = report("jnp_absorbed", window(mla.attend_absorbed))
+            if shape == "chunk":    # a tick's 64 windows multiplied out to
+                # 64 heads are 9 GB: no program runs that form there
+                report("jnp_expanded", window(mla.attend_expanded), want)
+            q = mla.absorb_query(q_nope, q_rope, w_kvb[..., :L_NOPE], L_ROW,
+                                 bf)
+            for npg, tile in sweep:
+                def kernel(q, pool, tables, pos):
+                    return la.latent_attention(
+                        q, pool, tables, pos, rank=L_RANK, sm_scale=scale,
+                        pages_per_step=npg, tile=tile)
+                alone = timed(jax.jit(kernel), (q,) + args[2:4] + args[5:],
+                              opts.reps)
+                report("pallas", jax.jit(
+                    lambda qn, qr, pool, tables, w, pos: mla.unabsorb(
+                        kernel(mla.absorb_query(qn, qr, w[..., :L_NOPE],
+                                                L_ROW, bf), pool, tables,
+                               pos), w[..., L_NOPE:])), want,
+                    kernel_us=round(alone * 1e6, 1), pages_per_step=npg,
+                    q_tile=tile or None, pages=pa.visited_pages(
+                        pos, np.ones(b, np.int32), window=0, ring=False,
+                        entries=L_ENTRIES, ptok=PTOK, tile=tile))
+    return 0
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--only", choices=("tick", "chunk"))
+    ap.add_argument("--pool", choices=("kv", "latent"), default="kv")
     opts = ap.parse_args()
     if jax.default_backend() != "tpu":
         raise SystemExit(f"needs a TPU, found {jax.default_backend()!r}")
@@ -96,6 +205,8 @@ def main():
         log.write(line + "\n")
         log.flush()
 
+    if opts.pool == "latent":
+        return latent(opts, emit)
     rng = np.random.default_rng(0)
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     bf = jnp.bfloat16
@@ -119,18 +230,9 @@ def main():
                 nbytes, flops = floors(kind, pos)
 
                 def report(impl, fn, want=None, **more):
-                    secs = timed(fn, args, opts.reps)
-                    got = fn(*args).astype(jnp.float32)
-                    gap = None if want is None else float(
-                        jnp.max(jnp.abs(got - want)))
-                    emit(shape=shape, table=kind, load=load, impl=impl,
-                         us=round(secs * 1e6, 1),
-                         bytes_floor_us=round(nbytes / HBM_BYTES_PER_S * 1e6, 1),
-                         flops_floor_us=round(flops / FLOPS_PER_S * 1e6, 1),
-                         hbm_share=round(nbytes / secs / HBM_BYTES_PER_S, 4),
-                         mxu_share=round(flops / secs / FLOPS_PER_S, 4),
-                         max_abs_gap=gap, **more)
-                    return got
+                    return measure(emit, fn, args, opts.reps, nbytes, flops,
+                                   want, shape=shape, table=kind, load=load,
+                                   impl=impl, **more)
 
                 want = report("jnp_walk", jax.jit(
                     lambda *a: M._walk_pages_jnp(

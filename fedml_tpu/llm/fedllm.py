@@ -27,6 +27,7 @@ from ..core import rng as rng_util
 from ..core import tree as tree_util
 from ..data.federated_dataset import FederatedDataset
 from ..obs import get_tracer
+from ..obs import programs as obs_programs
 from ..obs.jaxhooks import count_put
 from .model import (LlamaLM, causal_nll, config_from_args,
                     per_sequence_loglik)
@@ -163,6 +164,9 @@ class FedLLMAPI:
             self._build_round_fn(),
             out_shardings=(None if mesh is None
                            else (replicated(mesh), replicated(mesh))))
+        # the round program's registration with obs/programs.py, made at
+        # its first launch (``program_ops``); None until then
+        self._round_program = None
 
     # -- pure round --------------------------------------------------------
     def _build_round_fn(self):
@@ -272,11 +276,24 @@ class FedLLMAPI:
                 staged = self._stage(clients, (x, y, mask, w, rank_masks))
                 stage.set(bytes=count_put(tracer, staged))
             with tracer.span("fedllm.round.dispatch", cat="round"):
+                if self._round_program is None:
+                    self._round_program = obs_programs.register(
+                        "round_fn", self._round_fn,
+                        (self.base_params, self.global_lora, *staged))
                 self.global_lora, loss = self._round_fn(
                     self.base_params, self.global_lora, *staged)
             with tracer.span("fedllm.round.readback", cat="round"):
                 loss = float(loss)
         return {"train_loss": loss}
+
+    def program_ops(self):
+        """``{"round_fn": {instruction: {"path", "phase", "kernel", "op"}}}``
+        of the compiled round program (None before the first round): what
+        joins a device trace's operations to the model's modules and to
+        forward, recompute and backward (docs/OBSERVABILITY.md, "Device time
+        by module").  Lowers and compiles the round again (a load where a
+        persistent compile cache is set): not inside a timed round."""
+        return obs_programs.program_ops({"round_fn": self._round_program})
 
     def _stage(self, clients, arrays):
         """The round's host arrays on the device: padded to tile the

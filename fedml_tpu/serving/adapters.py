@@ -49,6 +49,8 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs import programs as obs_programs
+
 
 class BankFullError(RuntimeError):
     """Every non-reserved bank row is registered or still pinned by an
@@ -120,6 +122,9 @@ class AdapterRegistry:
 
         self._set_row = set_row
         self._gather_row = gather_row
+        # gather_row's registration with obs/programs.py, made at its first
+        # launch (None until then)
+        self._gather_row_program = None
         self.lock = threading.RLock()
         self._names: Dict[str, int] = {}
         self._rows = [_Row() for _ in range(capacity)]
@@ -148,6 +153,13 @@ class AdapterRegistry:
     def close(self) -> None:
         if self._fetcher is not None:
             self._fetcher.close()
+        obs_programs.unregister(self._gather_row_program)
+        self._gather_row_program = None
+
+    def program_ops(self) -> Dict[str, Optional[dict]]:
+        """``{"gather_row": its instruction -> module map}`` (None before its
+        first launch): see ``ContinuousBatchingEngine.program_ops``."""
+        return obs_programs.program_ops({"gather_row": self._gather_row_program})
 
     # -- routing -----------------------------------------------------------
     def names(self) -> List[str]:
@@ -260,7 +272,11 @@ class AdapterRegistry:
     def lora_for_row(self, row: int):
         """Gathered single-adapter tree for one row (prefill-time use)."""
         with self.lock:
-            return self._gather_row(self.bank, jnp.int32(row))
+            row = jnp.int32(row)
+            if self._gather_row_program is None:
+                self._gather_row_program = obs_programs.register(
+                    "gather_row", self._gather_row, (self.bank, row))
+            return self._gather_row(self.bank, row)
 
     # -- membership --------------------------------------------------------
     def _check_tree(self, lora_tree) -> None:

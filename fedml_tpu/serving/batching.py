@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import get_tracer
+from ..obs import programs as obs_programs
 from ..obs.histogram import ServeHistograms
 from .adapters import AdapterMissError, AdapterRegistry
 from .paged_kv import PagedBlockPool, PagedPrefixCache, PageExhaustedError
@@ -524,6 +525,10 @@ class ContinuousBatchingEngine:
         self._step = paged_step if self.registry is None \
             else paged_step_mt
         self._chunk = paged_chunk
+        # each program's registration with obs/programs.py, made at its
+        # first launch (``program_ops``); None until then
+        self._programs: Dict[str, Any] = {
+            "step": None, "chunk": None, "slot_rows": None}
 
         # the slot state the tick program carries from launch to launch
         # (docs/SERVING.md, "The loop"): one row a slot, on the device.
@@ -817,6 +822,36 @@ class ContinuousBatchingEngine:
             self.metrics_server = None
         if self._owns_registry and self.registry is not None:
             self.registry.close()
+        # obs/programs.py holds the programs weakly; an engine that is
+        # stopped but still referenced drops out of it here
+        obs_programs.unregister(*self._programs.values())
+
+    def _register_program(self, key: str, fn, *args) -> None:
+        """``fn``'s first launch: its name and this launch's shapes go to
+        ``obs/programs.py``, which keeps no array and no reference to the
+        engine.  Written on the engine's thread only, once a key;
+        ``program_ops`` and ``stop`` read a handle or None."""
+        # fedrace: disable-next-line=unguarded-shared-write
+        self._programs[key] = obs_programs.register(fn.__name__, fn, args)
+
+    def program_ops(self) -> Dict[str, Optional[dict]]:
+        """The instruction -> module maps of this engine's compiled programs
+        by program name (``paged_step`` or ``paged_step_mt``, ``paged_chunk``,
+        ``slot_rows``, and the bank's ``gather_row``), each ``{instruction:
+        {"path", "phase", "kernel", "op"}}``, or None for a program not
+        launched yet: what joins a device trace's operations to the model's
+        modules (docs/OBSERVABILITY.md, "Device time by module").  Lowers and
+        compiles each program again (a load where a persistent compile cache
+        is set): seconds.  Not for the engine's own thread."""
+        if threading.current_thread() is self._thread:
+            raise RuntimeError("program_ops() compiles: not on the engine's thread")
+        handles = {fn.__name__: self._programs[key] for key, fn in (
+            ("step", self._step), ("chunk", self._chunk),
+            ("slot_rows", self._slot_rows))}
+        out = obs_programs.program_ops(handles)
+        if self.registry is not None:
+            out.update(self.registry.program_ops())
+        return out
 
     def step_programs(self):
         """fedverify hook (ISSUE 10, docs/FEDVERIFY.md): the engine's
@@ -895,8 +930,12 @@ class ContinuousBatchingEngine:
         # it); staging goes on in a new one
         rows, self._rows = self._rows, np.zeros_like(self._rows)  # fedrace: disable=unguarded-shared-write
         self._rows_staged = False  # fedrace: disable=unguarded-shared-write
+        rows = jax.device_put(rows)
+        if self._programs["slot_rows"] is None:
+            self._register_program("slot_rows", self._slot_rows,
+                                   self._dev, rows)
         # fedrace: disable-next-line=unguarded-shared-write
-        self._dev = self._slot_rows(self._dev, jax.device_put(rows))
+        self._dev = self._slot_rows(self._dev, rows)
 
     def _finish(self, i: int, aborted: bool = False):
         s = self._slots[i]
@@ -1255,12 +1294,17 @@ class ContinuousBatchingEngine:
                 # the table as this chunk finds it rides its upload
                 _, freed = self._slide_window(i, s, cs)
                 chunk[words:] = self._wtabs[i]
+            chunk = jax.device_put(chunk)
+            if self._programs["chunk"] is None:
+                self._register_program(
+                    "chunk", self._chunk, self.raw_params, lora,
+                    self._pool, self._dev, chunk, s.pf_acc)
             # the page pool is engine-thread-confined like the other
             # decode state (see _stage_row); step_programs reads it at rest
             # fedrace: disable-next-line=unguarded-shared-write
             tok, self._pool, self._dev = self._chunk(
-                self.raw_params, lora, self._pool, self._dev,
-                jax.device_put(chunk), s.pf_acc)
+                self.raw_params, lora, self._pool, self._dev, chunk,
+                s.pf_acc)
         with self._stats_lock:
             self._chunks_total += 1
         if not final:
@@ -1620,10 +1664,18 @@ class ContinuousBatchingEngine:
                     # launch (the dispatch itself is async and fast;
                     # registration is the rare path)
                     with self.registry.lock:
+                        if self._programs["step"] is None:
+                            self._register_program(
+                                "step", self._step, self.raw_params,
+                                self.registry.bank, self._pool, self._dev)
                         toks, self._pool, self._dev = self._step(
                             self.raw_params, self.registry.bank,
                             self._pool, self._dev)
                 else:
+                    if self._programs["step"] is None:
+                        self._register_program(
+                            "step", self._step, self.raw_params,
+                            self._pool, self._dev)
                     toks, self._pool, self._dev = self._step(
                         self.raw_params, self._pool, self._dev)
                 lanes = []

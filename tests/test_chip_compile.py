@@ -547,3 +547,42 @@ def test_paged_program_never_moves_a_whole_pool(request, model, program):
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
     # a prefetch is one start and one done: no weight or bank comes in slices
     assert "slice-start" not in hlo and "copy-start" in hlo
+
+
+# -- the instruction -> module map of the programs the chip runs ----------------
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+@pytest.mark.parametrize("model", ["latent", "mixed"])
+def test_kernels_map_to_their_name_and_module(request, model, program):
+    """``obs/programs.py`` on the text the chip's compiler gives: every Pallas
+    custom call of the two paged programs maps to its kernel's ``name=`` and to
+    the path of the module that called it (the attention's paged method, the
+    expert layer), one a layer, and everything else that can show in a trace
+    has a row with a path or ``""``."""
+    from fedml_tpu.obs import programs
+    compiled, _ = request.getfixturevalue(f"{model}_programs")
+    hlo = compiled[program].as_text()
+    ops = programs.parse_hlo(hlo)
+    calls = re.findall(r"^\s+(?:ROOT )?%([\w.\-]+) = .*custom_call_target=\"tpu_custom_call\"",
+                       hlo, flags=re.M)
+    assert calls and all(ops[c]["kernel"] for c in calls)
+    kernels = {}
+    for c in calls:
+        kernels.setdefault(ops[c]["kernel"], []).append(ops[c]["path"])
+    read, method = {"latent": ("latent_attention", "_paged_attend"),
+                    "mixed": ("paged_attention", "_paged_decode_attend")}[model]
+    assert set(kernels) == {read, "gated_matmul", "grouped_matmul"}
+    assert sorted(kernels[read]) == [f"layer_{i}/attention.{method}/{read}" for i in (0, 1)]
+    sparse = (1,) if model == "latent" else (0, 1)      # the latent model's layer 0 is dense
+    for name in ("gated_matmul", "grouped_matmul"):
+        assert sorted(kernels[name]) == [f"layer_{i}/moe_mlp/{name}" for i in sparse]
+    # the instruction is called what the kernel is, which is what a trace shows
+    assert all(c.split(".")[0] == ops[c]["kernel"] for c in calls)
+    assert {row["phase"] for row in ops.values()} == {"forward"}
+    paths = {row["path"] for row in ops.values()}
+    assert {"", "lm_head" if model == "latent" else "tok_embed", "final_norm",
+            "layer_0/attn_norm", "layer_1/moe_mlp"} <= paths, sorted(paths)
+    # the entry's instructions are all there: what the trace names is in the map
+    entry = hlo[hlo.index("\nENTRY "):]
+    named = re.findall(r"^\s+(?:ROOT )?%([\w.\-]+) = ", entry, flags=re.M)
+    assert len(named) > 100 and set(named) <= set(ops)

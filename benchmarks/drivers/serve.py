@@ -20,7 +20,9 @@ import traffic
 import weights
 from reference import dense_decoder as ref
 
-#: how long past the window's close an answer is waited for
+#: how long an answer still open at the window's close is waited for, counted
+#: from ``finish``'s own start: in a traced run ``stop_trace`` comes between
+#: the close and ``finish`` and can take most of a minute
 WAIT_S = 60.0
 
 
@@ -108,7 +110,7 @@ def finish(state: dict, run) -> dict:
     seconds = run.seconds
     t0 = state["t0"]
     t1 = t0 + seconds
-    client.drain(t1 + WAIT_S)
+    client.drain(time.perf_counter() + WAIT_S)
     drained = time.perf_counter()
     kv = srv._engine.kv_stats()
     # requests of the ramp that had ended before the window opened are no part of it

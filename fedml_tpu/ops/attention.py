@@ -224,8 +224,10 @@ def _kv_head_map(b: int, h: int, h_kv: int):
 # Tiles from a tools/tpu_flash_tune.py sweep on TPU v5e.  Keyed by
 # (seq_k, head_dim); callers that pass explicit blocks bypass the table.
 #
-# AUTOTUNE-OR-FALLBACK POLICY: entries in this table are shapes where a
-# sweep recorded the Pallas kernel faster than the XLA blockwise scan.
+# AUTOTUNE-OR-FALLBACK POLICY: an entry is a shape where a sweep timed the
+# Pallas forward + dq pass + dK/dV pass, at the entered tile, faster than
+# the blockwise scan's forward + backward (what a training step runs of
+# either), and the tile is the one with the least such total.
 # ``flash_attention`` uses Pallas only for tuned shapes; untuned shapes take
 # the blockwise path.  Which of the two a traced call took is logged at
 # trace time (``_note_impl``), so "flash" never quietly means "blockwise".
@@ -233,6 +235,10 @@ def _kv_head_map(b: int, h: int, h_kv: int):
 # off the TPU) | "off" (always blockwise) | "auto" (default policy).
 _TUNED_BLOCKS = {
     (1024, 64): (256, 1024),
+    # b8 h32 kv8 s1024 d128 (fedlora-round.mistral-7b-d12's round), TPU v5
+    # lite: forward + dq + dK/dV 4.27 ms a call (forward 0.98) against the
+    # scan's forward + backward 35.95 ms (forward 6.43); (512, 512) 5.97 ms
+    (1024, 128): (1024, 1024),
 }
 # untuned shapes keep the round-2 tile — only measured shapes change
 _DEFAULT_BLOCKS = (512, 512)
@@ -347,6 +353,7 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = True,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
     out = out.reshape(b, h, s_q, d)
     if return_lse:
@@ -526,6 +533,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = True,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dq",
     )(qr, kr, vr, dor, lser, delta)
 
     # dkv pass: grid over k blocks, scan q
@@ -550,6 +558,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = True,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dkv",
     )(qr, kr, vr, dor, lser, delta)
     dq = dq.reshape(b, h, s_q, d)
     dk = dk.reshape(b, h, s_k, d)

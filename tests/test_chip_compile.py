@@ -8,9 +8,11 @@ module is imported — and every such compile lives in THIS file: the process
 that describes the topology loads the TPU's library and keeps it.
 """
 
+import json
 import math
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,16 +22,23 @@ from jax.sharding import (NamedSharding, PartitionSpec,
 
 from fedml_tpu.ops import attention as A
 
-# (batch, q heads, kv heads, seq, head_dim): the tile table's one shape, a
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+# (batch, q heads, kv heads, seq, head_dim): the tile table's first shape, a
 # GQA variant of it, the 1.075B flagship's attention as chip_smoke.py's
-# kernel phase and a two-client cohort trace it, and two long-context GQA/MHA
-# shapes at head_dim 128
+# kernel phase and a two-client cohort trace it, two long-context GQA/MHA
+# shapes at head_dim 128, and the round of fedlora-round.mistral-7b-d12 (4
+# clients x batch 2, 32 q heads on 8 kv heads of 128) at its entered tile
 SHAPES = [
     (4, 12, 12, 1024, 64),
     (2, 32, 4, 1024, 64),
     (2, 16, 8, 256, 128),
     (2, 16, 8, 2048, 128),
     (1, 32, 32, 2048, 128),
+    (8, 32, 8, 1024, 128),
 ]
 _ids = ["b{}_h{}_kv{}_s{}_d{}".format(*s) for s in SHAPES]
 
@@ -64,7 +73,8 @@ def test_flash_forward_compiles_for_v5e(one_chip, shape):
     fwd = jax.jit(lambda q, k, v: A.flash_attention_fwd_pallas(
         q, k, v, True, None, return_lse=True))
     compiled = fwd.lower(q, kv, kv).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1 and "%flash_fwd" in hlo
 
 
 @pytest.mark.parametrize("shape", SHAPES, ids=_ids)
@@ -75,8 +85,10 @@ def test_flash_backward_compiles_for_v5e(one_chip, shape):
     bwd = jax.jit(lambda q, k, v, out, lse, do: A.flash_attention_bwd_pallas(
         q, k, v, out, lse, do, True, None))
     compiled = bwd.lower(q, kv, kv, q, lse, q).compile()
-    # the dq pass and the dk/dv pass
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+    # the dq pass and the dk/dv pass, under the kernels' names
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert "%flash_dq" in hlo and "%flash_dkv" in hlo
 
 
 def test_scatter_merge_compiles_on_four_chip_mesh(topo):
@@ -586,3 +598,88 @@ def test_kernels_map_to_their_name_and_module(request, model, program):
     entry = hlo[hlo.index("\nENTRY "):]
     named = re.findall(r"^\s+(?:ROOT )?%([\w.\-]+) = ", entry, flags=re.M)
     assert len(named) > 100 and set(named) <= set(ops)
+
+
+# -- the round's flash kernels in the map, and the rule that counts them --------
+
+ROUND_ATTN_METRICS = ("round_attn_ms", "attn_roofline")
+_TPU_CALL = re.compile(
+    r"^\s+(?:ROOT )?%([\w.\-]+) = .*custom_call_target=\"tpu_custom_call\"", re.M)
+
+
+@pytest.fixture(scope="module")
+def round_like_rows(one_chip):
+    """The Pallas calls' rows in the map of a round-shaped program: a ``vmap``
+    over two clients of a scan over two local steps of ``jax.grad`` of a
+    two-layer LoRA ``LlamaLM``'s loss under ``remat=full`` (the round's
+    nesting, which puts ``vmap()`` in a segment of its own), heads of 128,
+    grouped KV (4 q heads on 2), seq 256, bf16.  The gate
+    ``_use_pallas`` is patched to ``True``: off a chip it is ``False``, and
+    seq 256 has no entry in the tile table."""
+    from fedml_tpu.llm.model import LlamaConfig, LlamaLM
+    from fedml_tpu.obs import programs
+
+    cfg = LlamaConfig(vocab_size=512, dim=512, n_layers=2, n_heads=4, n_kv_heads=2,
+                      ffn_dim=1024, max_seq_len=256, dtype=jnp.bfloat16, lora_rank=4,
+                      lora_alpha=4.0, remat="full", attn_impl="flash")
+    model = LlamaLM(cfg)
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+    def loss(lora, params, tokens):
+        logits = model.apply({"params": params, "lora": lora}, tokens, train=True)
+        logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
+        return -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1))
+
+    def local_steps(lora, params, tokens):
+        def step(lora, batch):
+            grads = jax.grad(loss)(lora, params, batch)
+            return jax.tree_util.tree_map(lambda w, g: w - 1e-3 * g.astype(w.dtype), lora, grads), None
+        return jax.lax.scan(step, lora, tokens)[0]
+
+    clients = jax.vmap(local_steps, in_axes=(0, None, 0))
+
+    def described(tree, lead=()):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(lead + a.shape, a.dtype, sharding=one_chip), tree)
+
+    tokens = jax.ShapeDtypeStruct((2, 2, 2, 256), jnp.int32, sharding=one_chip)
+    gate = A._use_pallas
+    A._use_pallas = lambda s_k, d: True
+    try:
+        compiled = jax.jit(clients).lower(described(variables["lora"], (2,)),
+                                          described(variables["params"]), tokens).compile()
+    finally:
+        A._use_pallas = gate
+    hlo = compiled.as_text()
+    rows = programs.parse_hlo(hlo)
+    return {c: rows[c] for c in _TPU_CALL.findall(hlo)}
+
+
+def test_every_flash_call_lies_under_its_layers_attention_in_three_phases(round_like_rows):
+    """Each kernel sits at ``layer_i/attention/<name>`` under its own
+    ``name=``: per layer one ``flash_fwd`` in the forward, one again in the
+    recomputed forward (``remat=full``), and the ``flash_dq`` and
+    ``flash_dkv`` passes in the backward, not a second forward under
+    ``vjp``."""
+    calls = list(round_like_rows.values())
+    assert calls and all(row["kernel"] in ("flash_fwd", "flash_dq", "flash_dkv")
+                         for row in calls), calls
+    assert all(re.fullmatch(rf"layer_[01]/attention/{row['kernel']}", row["path"])
+               for row in calls), calls
+    for i in (0, 1):
+        mine = [row for row in calls if row["path"].startswith(f"layer_{i}/")]
+        by_phase = {phase: sorted(row["kernel"] for row in mine if row["phase"] == phase)
+                    for phase in ("forward", "recompute", "backward")}
+        assert by_phase == {"forward": ["flash_fwd"], "recompute": ["flash_fwd"],
+                            "backward": ["flash_dkv", "flash_dq"]}, (i, by_phase)
+    # the instruction is called what the kernel is, which is what a trace shows
+    assert all(c.split(".")[0] == row["kernel"] for c, row in round_like_rows.items())
+
+
+@pytest.mark.parametrize("metric", ROUND_ATTN_METRICS)
+def test_the_rule_counts_every_flash_call(round_like_rows, metric):
+    from readers import ops
+    with open(os.path.join(BENCH, "layer_metrics", f"{metric}.json")) as f:
+        args = json.load(f)["args"]
+    missed = [(c, row) for c, row in round_like_rows.items() if not ops.matches(row, args)]
+    assert not missed

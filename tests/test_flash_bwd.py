@@ -82,16 +82,23 @@ def test_gqa_grouped_paths_match_repeated():
                                    rtol=1e-3)
 
 
-def _walk_dots(jaxpr, out):
-    """Collect every dot_general eqn in a (nested) jaxpr."""
+def _eqns(jaxpr, kernels=True):
+    """Every eqn of a (nested) jaxpr; ``kernels=False`` stays out of the
+    bodies of Pallas kernels."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            out.append(eqn)
+        yield eqn
+        if eqn.primitive.name == "pallas_call" and not kernels:
+            continue
         for v in eqn.params.values():
             if hasattr(v, "jaxpr"):          # ClosedJaxpr
-                _walk_dots(v.jaxpr, out)
+                yield from _eqns(v.jaxpr, kernels)
             elif hasattr(v, "eqns"):         # Jaxpr
-                _walk_dots(v, out)
+                yield from _eqns(v, kernels)
+
+
+def _walk_dots(jaxpr, out):
+    """Collect every dot_general eqn in a (nested) jaxpr."""
+    out.extend(e for e in _eqns(jaxpr) if e.primitive.name == "dot_general")
     return out
 
 
@@ -133,3 +140,88 @@ def test_bf16_grads_finite_at_bisect_shape():
         jnp.sum(jnp.square(x.astype(jnp.float32)))
         for x in jax.tree.leaves(g)))))
     assert np.isfinite(gn), f"bf16 blockwise grads not finite: {gn}"
+
+
+# -- tools/tpu_flash_tune.py: what the sweep times ------------------------------
+
+@pytest.fixture(scope="module")
+def tool():
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "tpu_flash_tune.py")
+    spec = importlib.util.spec_from_file_location("tpu_flash_tune", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _live_eqns(fn, x):
+    """Every eqn left, at any depth but inside a kernel, once the jaxpr of
+    ``fn(x)`` has lost what nothing uses (what ``jit`` does before it
+    lowers): a pass whose result is dropped is not here."""
+    from jax._src.interpreters import partial_eval as pe
+
+    closed = jax.make_jaxpr(fn)(x)
+    jaxpr, _ = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
+    return list(_eqns(jaxpr, kernels=False))
+
+
+def _tune_operands():
+    b, h, hkv, s, d = 1, 4, 2, 256, 128
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (b, h, s, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, hkv, s, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, hkv, s, d), jnp.bfloat16)
+    g = jax.random.normal(ks[3], (b, h, s, d), jnp.bfloat16)
+    return q, k, v, g
+
+
+def test_tune_times_the_forward_and_both_backward_passes(tool):
+    """The chain a tile is timed by keeps the forward, the dq pass and the
+    dK/dV pass live: a step that returned ``dq`` alone left the dK/dV
+    ``pallas_call`` dead under ``jit``, so the sweep never timed it."""
+    q, k, v, g = _tune_operands()
+    step = tool.flash_step(k, v, g, 128, 128)
+    calls = [e.params["name"] for e in _live_eqns(
+        lambda x: tool._chain(step, x, 2), q) if e.primitive.name == "pallas_call"]
+    assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    fwd = [e.params["name"] for e in _live_eqns(
+        lambda x: tool._chain(tool.flash_forward(k, v, 128, 128), x, 2), q)
+        if e.primitive.name == "pallas_call"]
+    assert fwd == ["flash_fwd"]
+
+
+def test_tune_baseline_is_the_gradient_of_the_scan(tool):
+    """The scan is timed as what the round runs of it, its forward and its
+    transposed (reverse) scan in q, k and v; no kernel."""
+    q, k, v, g = _tune_operands()
+    eqns = _live_eqns(lambda x: tool._chain(tool.scan_step(k, v, g), x, 2), q)
+    assert not any(e.primitive.name == "pallas_call" for e in eqns)
+    scans = [e.params["reverse"] for e in eqns
+             if e.primitive.name == "scan" and e.params["length"] == 2]
+    assert scans == [False]                     # the chain itself
+    inner = [e.params["reverse"] for e in eqns
+             if e.primitive.name == "scan" and e.params["length"] != 2]
+    assert sorted(inner) == [False, True], inner
+    # and it is a gradient: one step moves q by dq, as jax.vjp gives it
+    _, vjp = jax.vjp(lambda q, k, v: blockwise_attention(q, k, v, True)
+                     .astype(jnp.float32), q, k, v)
+    dq, dk, dv = vjp(g.astype(jnp.float32))
+    want = tool._fold(q, dq, dk, dv)
+    np.testing.assert_allclose(np.asarray(tool.scan_step(k, v, g)(q), np.float32),
+                               np.asarray(want, np.float32), atol=1e-2)
+
+
+def test_tune_decides_on_forward_plus_backward(tool):
+    """The tile with the least forward + backward wins, whatever its
+    forward alone reads, and enters the table only if it beats the scan's
+    forward + backward."""
+    rows = [{"bq": 1024, "bk": 1024, "fwd_s": 0.010, "bwd_s": 0.060, "total_s": 0.070},
+            {"bq": 256, "bk": 512, "fwd_s": 0.012, "bwd_s": 0.030, "total_s": 0.042},
+            {"bq": 512, "bk": 1024, "error": "VMEM"}]
+    best, wins = tool.choose(rows, base_total_s=0.300)
+    assert (best["bq"], best["bk"]) == (256, 512) and wins
+    assert tool.choose(rows, base_total_s=0.040)[1] is False
+    assert tool.choose(rows[2:], base_total_s=0.3) == (None, False)
+    assert (8, 32, 8, 1024, 128) in tool.SHAPES

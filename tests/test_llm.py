@@ -718,6 +718,12 @@ def test_flash_autotune_fallback_policy(tmp_path, monkeypatch):
     tuned_key = next(iter(A._TUNED_BLOCKS))
     assert A._use_pallas(*tuned_key)
     assert not A._use_pallas(12345, 77)          # untuned -> blockwise
+    # the training round's shape takes the kernels at its swept tile; other
+    # lengths and widths keep their path
+    assert A._use_pallas(1024, 128)
+    assert A._pick_blocks(1024, 128, None, None) == (1024, 1024)
+    assert not A._use_pallas(1024, 192)          # latent attention's q/k width
+    assert not A._use_pallas(12288, 128)         # a 12k-token pass
     monkeypatch.setenv("FEDML_TPU_FLASH_MODE", "off")
     assert not A._use_pallas(*tuned_key)
     monkeypatch.setenv("FEDML_TPU_FLASH_MODE", "force")

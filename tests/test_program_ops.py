@@ -4,6 +4,9 @@ ISSUE 39): the ``op_name`` parser on canned optimized-HLO text, the registry
 asked for), and ``program_ops()`` of a tiny engine and a tiny ``FedLLMAPI``."""
 
 import gc
+import json
+import os
+import sys
 import threading
 import weakref
 
@@ -13,6 +16,13 @@ import numpy as np
 import pytest
 
 from fedml_tpu.obs import programs
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from readers import ops as bench_ops  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -315,3 +325,66 @@ def test_round_program_registers_at_the_first_round_and_maps_three_phases(api):
     compiles.clear()
     api.train_one_round(2)
     assert not compiles
+
+
+# -- the rule by which the benchmark reads the round's attention ---------------------
+# ``round_attn_ms`` and ``attn_roofline`` pick the round's attention out of
+# ``round_fn``'s map: everything under ``layer_*/attention`` but the projections,
+# whatever implements it.  On the round as it runs here (the blockwise ``jnp``
+# scan) that is exactly what the rule before it picked: the module's own
+# instructions and the scan's einsum scopes.
+
+#: the two metrics' ``args`` before the rule counted a kernel's own scope
+OLD_RULE = {"path": ["layer_*/attention", "layer_*/attention/*->*"]}
+ROUND_ATTN_METRICS = ("round_attn_ms", "attn_roofline")
+PROJECTIONS = ("wq", "wk", "wv", "wo")
+
+
+def _rule(name: str) -> dict:
+    with open(os.path.join(BENCH, "layer_metrics", f"{name}.json")) as f:
+        return json.load(f)["args"]
+
+
+def _picked(rows: dict, args: dict) -> set:
+    return {instr for instr, row in rows.items() if bench_ops.matches(row, args)}
+
+
+def _is_projection(path: str) -> bool:
+    parts = path.split("/")
+    return len(parts) > 2 and parts[1] == "attention" and parts[2] in PROJECTIONS
+
+
+@pytest.mark.parametrize("metric", ROUND_ATTN_METRICS)
+@pytest.mark.parametrize("path, counted", [
+    ("layer_3/attention", True),
+    ("layer_3/attention/...qd,...kd->...qk", True),
+    ("layer_3/attention/flash_fwd", True),             # the Pallas kernels' own scopes
+    ("layer_3/attention/flash_dq", True),
+    ("layer_3/attention/flash_dkv", True),
+    ("layer_3/attention/wq", False),
+    ("layer_3/attention/wo/base", False),
+    ("layer_3/attention/wk/lora_a", False),
+    ("layer_3/attention/wqkv", True),                  # not one of the four projections
+    ("layer_3/mlp/w_up", False),
+    ("layer_3/attn_norm", False),
+    ("layer_3/attention._paged_attend", False),       # serving's method scope: not the round's
+    ("", False),
+])
+def test_the_rule_on_paths(metric, path, counted):
+    row = {"path": path, "phase": "backward", "kernel": "", "op": "fusion"}
+    assert bench_ops.matches(row, _rule(metric)) is counted
+
+
+def test_todays_round_reads_the_same_instructions_under_both_rules(api):
+    assert np.isfinite(api.train_one_round(0)["train_loss"])
+    rows = api.program_ops()["round_fn"]
+    old = _picked(rows, OLD_RULE)
+    # the scan's products under their einsums' scopes, in all three phases
+    assert {rows[i]["phase"] for i in old} == {"forward", "recompute", "backward"}
+    assert any("->" in rows[i]["path"] for i in old)
+    for metric in ROUND_ATTN_METRICS:
+        assert _picked(rows, _rule(metric)) == old, metric
+    # the projections (and their base and adapter children) are there, and in neither
+    projections = {i for i, row in rows.items() if _is_projection(row["path"])}
+    assert {rows[i]["path"].split("/")[2] for i in projections} == set(PROJECTIONS)
+    assert not projections & old

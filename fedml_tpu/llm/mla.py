@@ -37,6 +37,10 @@ Three entries share the projections:
 
 ``kv_b`` carries no adapter: the absorbed form reads its matrix, not its
 product with an input.
+
+With ``gated_attention`` every head's output is multiplied by its own gate,
+``sigmoid(W_gate n)`` (``n`` the layer's normed input, ``h * v_head_dim``
+wide), before ``o``: after whichever of the three entries computed it.
 """
 
 from __future__ import annotations
@@ -249,6 +253,11 @@ class MLA(nn.Module):
             attend = flash_attention if impl == "flash" \
                 else blockwise_attention
             out = attend(q, k, v, True, scale)
+        if cfg.gated_attention:     # every head's output, by its own gate
+            gate = nn.Dense(h * dv, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=cfg.store_dtype, name="gate")(x)
+            gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+            out = out * gate.reshape(b, s, h, dv).transpose(0, 2, 1, 3)
         out = out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
         return dense(cfg.dim, "o")(out)
 

@@ -30,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 from ..core.mesh import MODEL_AXIS, SEQ_AXIS
 from ..models.base import FlaxModel
 from ..ops.attention import blockwise_attention, flash_attention
+from ..ops.grouped_matmul import swiglu_act
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,7 +45,14 @@ class YarnScaling:
 
 
 #: what a layer may mix by (``LlamaConfig.layer_types``)
-LAYER_KINDS = frozenset({"full_attention", "sliding_attention", "conv"})
+LAYER_KINDS = frozenset({"full_attention", "sliding_attention", "conv",
+                         "linear_attention"})
+#: the leaves of the ``cache`` collection that are a linear-attention layer's
+#: rows of state: its matrix state and its convolution's rows
+LINEAR_LEAVES = frozenset({"linear_state", "linear_conv"})
+#: the leaves of the ``cache`` collection that are rows of state a slot (no
+#: pages): the short convolution's and the linear attention's
+STATE_LEAVES = frozenset({"conv_state"}) | LINEAR_LEAVES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,12 +124,34 @@ class LlamaConfig:
     head_dim: int = 0
     #: the kind of every layer's mixer: ``"full_attention"`` (causal over
     #: everything before), ``"sliding_attention"`` (key j visible to query
-    #: i iff ``0 <= i - j < sliding_window``) or ``"conv"`` (the gated short
-    #: convolution, :class:`ShortConv`, of ``conv_kernel`` taps); None = all
-    #: full
+    #: i iff ``0 <= i - j < sliding_window``), ``"conv"`` (the gated short
+    #: convolution, :class:`ShortConv`, of ``conv_kernel`` taps) or
+    #: ``"linear_attention"`` (the gated delta rule,
+    #: ``llm/linear_attention.py::GatedDeltaNet``); None = all full
     layer_types: Optional[Tuple[str, ...]] = None
     sliding_window: int = 0
     conv_kernel: int = 0
+    #: the linear-attention layers' widths: key and value heads and their
+    #: widths, the taps of the convolution before the rule, the eps of the
+    #: output's norm and what its sigmoid gate is multiplied by
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 0
+    linear_norm_eps: float = 1e-6
+    linear_gate_scale: float = 2.0
+    #: latent attention: ``sigmoid(W_g n)`` multiplies every head's output
+    #: before ``o``
+    gated_attention: bool = False
+    #: norms after the mixer and after the feed-forward too:
+    #: ``h = x + N2(Mix(N1 x))``, ``y = h + N4(FF(N3 h))``
+    sandwich_norm: bool = False
+    #: what the gate of ``norm_kind="gated"`` is multiplied by
+    norm_gate_scale: float = 2.0
+    #: > 0: every SwiGLU (dense, shared, routed) computes
+    #: ``silu(min(gate, limit)) * clip(up, -limit, limit)``
+    swiglu_limit: float = 0.0
     #: RMS norms on q and k, over each head's numbers with one learned scale
     #: of ``head_dim``, before the rotary embedding
     qk_norm: bool = False
@@ -134,7 +164,9 @@ class LlamaConfig:
     #: ``x + Attn(n) + FFN(n)`` with one norm ``n`` for both, in place of
     #: the sequential residual
     parallel_block: bool = False
-    #: ``rms``, or ``layer``: the mean is subtracted first (scale, no bias)
+    #: ``rms``, ``layer`` (the mean is subtracted first; scale, no bias) or
+    #: ``gated`` (``x / rms(x) * norm_gate_scale * sigmoid(w)``, ``w`` zero
+    #: at init: the zero-centred gated norm)
     norm_kind: str = "rms"
     #: the head is the embedding, transposed; the logits times ``logit_scale``
     tie_embeddings: bool = False
@@ -171,10 +203,11 @@ class LlamaConfig:
     #: window back while the request runs (docs/SERVING.md).  0: every
     #: layer's pool has ``kv_pool_pages`` and one table addresses them all.
     kv_window_pool_pages: int = 0
-    #: >0 (the engine sets it for a model with ``"conv"`` layers): on the
-    #: paged path a convolution layer's state is one buffer of this many rows
-    #: and a trash row, a row a slot, addressed by the call's ``state_rows``.
-    #: 0: a row for each row of the batch (the single-request path).
+    #: >0 (the engine sets it for a model with ``"conv"`` or
+    #: ``"linear_attention"`` layers): on the paged path such a layer's state
+    #: is one buffer a leaf of this many rows and a trash row, a row a slot,
+    #: addressed by the call's ``state_rows``.  0: a row for each row of the
+    #: batch (the single-request path).
     state_slots: int = 0
 
     def __post_init__(self):
@@ -198,22 +231,40 @@ class LlamaConfig:
         if self.kv_pool_pages == 1:
             raise ValueError("kv_pool_pages=1 is only the reserved trash "
                              "page — need at least 2")
-        if self.norm_kind not in ("rms", "layer"):
-            raise ValueError(f"norm_kind={self.norm_kind!r}: must be 'rms' "
-                             "or 'layer'")
+        if self.norm_kind not in ("rms", "layer", "gated"):
+            raise ValueError(f"norm_kind={self.norm_kind!r}: must be 'rms', "
+                             "'layer' or 'gated'")
+        if self.sandwich_norm and self.parallel_block:
+            raise ValueError("sandwich_norm with parallel_block: norms after "
+                             "the mixer and the feed-forward of a parallel "
+                             "block are not computed here")
         kinds = self.layer_types
         if kinds is not None:
             if len(kinds) != self.n_layers or set(kinds) - LAYER_KINDS:
                 raise ValueError(
                     f"layer_types={kinds!r}: one of 'full_attention', "
-                    f"'sliding_attention' and 'conv' for each of "
-                    f"{self.n_layers} layers")
+                    f"'sliding_attention', 'conv' and 'linear_attention' for "
+                    f"each of {self.n_layers} layers")
             if "sliding_attention" in kinds and self.sliding_window <= 0:
                 raise ValueError("sliding_attention layers need "
                                  "sliding_window > 0")
             if "conv" in kinds and self.conv_kernel < 2:
                 raise ValueError("conv layers need conv_kernel >= 2 (the "
                                  "taps of the short convolution)")
+            if "linear_attention" in kinds and (
+                    min(self.linear_key_heads, self.linear_value_heads,
+                        self.linear_key_head_dim,
+                        self.linear_value_head_dim) <= 0
+                    or self.linear_conv_kernel < 2
+                    or self.linear_value_heads % self.linear_key_heads):
+                raise ValueError(
+                    "linear_attention layers need linear_key_heads, "
+                    "linear_value_heads (a multiple of the key heads), "
+                    "linear_key_head_dim, linear_value_head_dim and "
+                    "linear_conv_kernel >= 2")
+        if self.gated_attention and self.kv_lora_rank <= 0:
+            raise ValueError("gated_attention is computed for latent "
+                             "attention only")
         if self.qk_norm and self.kv_lora_rank > 0:
             raise ValueError("qk_norm is computed for grouped-query "
                              "attention, not for latent attention")
@@ -278,8 +329,22 @@ class LlamaConfig:
         """How many layers mix by the short convolution and keep its state."""
         return sum(k == "conv" for k in self.layer_types or ())
 
+    @property
+    def linear_layers(self) -> int:
+        """How many layers mix by the gated delta rule and keep its state."""
+        return sum(k == "linear_attention" for k in self.layer_types or ())
+
+    @property
+    def state_layers(self) -> int:
+        """How many layers keep state by rows (no pages)."""
+        return self.conv_layers + self.linear_layers
+
     def layer_conv(self, i: int) -> bool:
         return bool(self.layer_types) and self.layer_types[i] == "conv"
+
+    def layer_linear(self, i: int) -> bool:
+        return bool(self.layer_types) \
+            and self.layer_types[i] == "linear_attention"
 
     def layer_window(self, i: int) -> int:
         """Layer ``i``'s window; 0 = everything before."""
@@ -371,7 +436,25 @@ class LayerNorm(nn.Module):
         return (xf * jax.lax.rsqrt(var + self.eps)).astype(x.dtype) * scale
 
 
+class GatedNorm(nn.Module):
+    """The zero-centred gated norm: ``x / sqrt(mean x^2 + eps) * gate_scale *
+    sigmoid(w)``, ``w`` (float32) zero at init, so that the norm starts as a
+    plain RMS norm."""
+    eps: float = 1e-6
+    gate_scale: float = 2.0
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("gate", nn.initializers.zeros, (x.shape[-1],))
+        var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+                       keepdims=True)
+        return (x * jax.lax.rsqrt(var + self.eps)).astype(x.dtype) \
+            * (self.gate_scale * jax.nn.sigmoid(w))
+
+
 def _norm(cfg: "LlamaConfig", name: str):
+    if cfg.norm_kind == "gated":
+        return GatedNorm(cfg.norm_eps, cfg.norm_gate_scale, name=name)
     return (LayerNorm if cfg.norm_kind == "layer" else RMSNorm)(
         cfg.norm_eps, name=name)
 
@@ -897,7 +980,7 @@ class MLP(nn.Module):
             param_dtype=cfg.store_dtype, name=name)
         gate = dense(width, "w_gate")(x)
         up = dense(width, "w_up")(x)
-        return dense(cfg.dim, "w_down")(nn.silu(gate) * up)
+        return dense(cfg.dim, "w_down")(swiglu_act(gate, up, cfg.swiglu_limit))
 
 
 class Block(nn.Module):
@@ -910,6 +993,8 @@ class Block(nn.Module):
     rope: bool = True
     #: the mixer is the gated short convolution, not attention
     conv: bool = False
+    #: the mixer is the gated delta rule, not attention
+    linear: bool = False
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False,
@@ -918,6 +1003,10 @@ class Block(nn.Module):
         n = _norm(cfg, "attn_norm")(x)
         if self.conv:
             attn = ShortConv(cfg, name="conv")(n, positions, decode, state)
+        elif self.linear:
+            from .linear_attention import GatedDeltaNet
+            attn = GatedDeltaNet(cfg, name="linear_attn")(
+                n, positions, decode, state)
         elif cfg.latent_attention:
             from .mla import MLA
             attn = MLA(cfg, name="attention")(
@@ -928,8 +1017,13 @@ class Block(nn.Module):
                 n, positions, decode=decode, block_tables=block_tables)
         if cfg.parallel_block:       # one norm for both
             return x + attn + _feed_forward(cfg, self.sparse, n)
+        if cfg.sandwich_norm:
+            attn = _norm(cfg, "attn_post_norm")(attn)
         h = x + attn
-        return h + _feed_forward(cfg, self.sparse, _norm(cfg, "mlp_norm")(h))
+        ff = _feed_forward(cfg, self.sparse, _norm(cfg, "mlp_norm")(h))
+        if cfg.sandwich_norm:
+            ff = _norm(cfg, "mlp_post_norm")(ff)
+        return h + ff
 
 
 def _feed_forward(cfg: LlamaConfig, sparse: bool, hn):
@@ -947,6 +1041,7 @@ def _feed_forward(cfg: LlamaConfig, sparse: bool, hn):
                norm_topk=cfg.moe_norm_topk,
                routed_scale=cfg.moe_routed_scale,
                select_bias=cfg.moe_select_bias, held=cfg.experts_held,
+               swiglu_limit=cfg.swiglu_limit,
                dtype=cfg.dtype, param_dtype=cfg.store_dtype,
                name="moe_mlp")(hn)
     if cfg.n_shared_experts:
@@ -976,7 +1071,8 @@ class LlamaLM(nn.Module):
         path (``kv_page_tokens``/``kv_pool_pages`` on the config); for a
         model with ``kv_window_pool_pages`` it is ``{"full": ...,
         "window": ...}``, a table per kind of layer.  A model with
-        ``"conv"`` layers keeps their state by rows (:class:`ShortConv`):
+        ``"conv"`` or ``"linear_attention"`` layers keeps their state by
+        rows (:class:`ShortConv`, ``linear_attention.GatedDeltaNet``):
         ``state_rows`` ((B,) int32, traced) says which row each lane of the
         call addresses (None: lane i its own row i) and ``seq_lens`` ((B,))
         how many of a lane's positions are real (None: all of them).
@@ -1006,8 +1102,10 @@ class LlamaLM(nn.Module):
             mk_block = nn.remat(Block, static_argnums=(3,))  # FLOPs
         for i in range(cfg.n_layers):
             window = cfg.layer_window(i)
-            if cfg.layer_conv(i):
-                block = mk_block(cfg, sparse=cfg.sparse_layer(i), conv=True,
+            if cfg.layer_conv(i) or cfg.layer_linear(i):
+                block = mk_block(cfg, sparse=cfg.sparse_layer(i),
+                                 conv=cfg.layer_conv(i),
+                                 linear=cfg.layer_linear(i),
                                  name=f"layer_{i}")
                 x = block(x, positions, decode, None, (state_rows, seq_lens))
                 continue
@@ -1063,6 +1161,17 @@ _PUBLISHED_KEYS = {
     "norm_eps": "norm_eps", "conv_L_cache": "conv_kernel",
     "num_dense_layers": "first_dense_layers",
     "use_expert_bias": "moe_select_bias", "use_qk_norm": "qk_norm",
+    # the gigachat3_5 family's names
+    "linear_num_key_heads": "linear_key_heads",
+    "linear_num_value_heads": "linear_value_heads",
+    "linear_key_head_dim": "linear_key_head_dim",
+    "linear_value_head_dim": "linear_value_head_dim",
+    "linear_conv_kernel_dim": "linear_conv_kernel",
+    "linear_attn_o_norm_eps": "linear_norm_eps",
+    "linear_sigmoid_gate_scale": "linear_gate_scale",
+    "gated_attention": "gated_attention",
+    "layernorm_gating_weight": "norm_gate_scale",
+    "swiglu_limit": "swiglu_limit",
 }
 
 
@@ -1099,21 +1208,105 @@ def config_from_published(published) -> dict:
             "hidden_act", "silu") != "silu":
         raise ValueError("attention_bias and activations other than silu "
                          "are not computed here")
+    out.update(_linear_fields(published))
     if published.get("layer_types"):
         out["layer_types"] = tuple(published["layer_types"])
         unknown = set(out["layer_types"]) - LAYER_KINDS
         if unknown:
             raise ValueError(
                 f"layer_types entries {sorted(unknown)!r}: a layer mixes by "
-                "'full_attention', 'sliding_attention' or 'conv' here")
+                "'full_attention', 'sliding_attention', 'conv' or "
+                "'linear_attention' here")
         if "sliding_attention" not in out["layer_types"]:
             out.pop("sliding_window", None)
     else:       # a window no layer is said to have
         out.pop("sliding_window", None)
     if "conv" not in out.get("layer_types", ()):    # taps no layer has
         out.pop("conv_kernel", None)
+    if "linear_attention" in out.get("layer_types", ()) \
+            and not published.get("linear_attention_type"):
+        raise ValueError(
+            "layer_types entries 'linear_attention' without "
+            "linear_attention_type: which rule such a layer computes is "
+            "not said")
     out.update(_unnamed_fields(published))
     return out
+
+
+#: what a ``gigachat3_5`` config.json may name and is computed here
+_LINEAR_TYPE = "GigaChat35GatedDeltaNet"
+_LINEAR_GATING = "gated_rmsnorm_sigmoid_zero_centered"
+
+
+def _linear_fields(published: dict) -> dict:
+    """The fields of the keys the ``gigachat3_5`` family adds: linear-attention
+    layers wherever ``full_attention_layers`` leaves them, the layer's norms
+    and the latent attention's options.  What is not computed raises by name:
+    another ``linear_attention_type`` or ``linear_gating_type``, a
+    ``layernorm_type`` other than ``pre_post``, a ``norm_type`` other than
+    ``ZeroCenteredGatedNorm``, multi-token prediction modules
+    (``num_nextn_predict_layers``), a sigmoid on the shared expert, rotary
+    pairs that are not interleaved, a ``qk_head_dim`` that is not nope + rope,
+    and YaRN's ``mscale_all_dim`` without ``use_mla_scaling_factor``."""
+    fields = {}
+    kind = published.get("linear_attention_type")
+    if kind is not None and kind != _LINEAR_TYPE:
+        raise ValueError(f"linear_attention_type {kind!r}: only {_LINEAR_TYPE!r} "
+                         "(the gated delta rule) is computed here")
+    gating = published.get("linear_gating_type")
+    if gating is not None and gating != _LINEAR_GATING:
+        raise ValueError(f"linear_gating_type {gating!r}: only {_LINEAR_GATING!r} "
+                         "is computed here")
+    full = published.get("full_attention_layers")
+    if kind is not None:
+        if full is None or published.get("layer_types"):
+            raise ValueError(
+                "linear_attention_type needs full_attention_layers, and no "
+                "layer_types beside it: which layers are linear is not said")
+        n = int(published["num_hidden_layers"])
+        if any(not 0 <= int(i) < n for i in full):
+            raise ValueError(f"full_attention_layers {full!r}: not layers of {n}")
+        fields["layer_types"] = tuple(
+            "full_attention" if i in {int(j) for j in full}
+            else "linear_attention" for i in range(n))
+    elif full is not None:
+        raise ValueError("full_attention_layers without linear_attention_type: "
+                         "what the other layers compute is not said")
+    sandwich = published.get("layernorm_type")
+    if sandwich is not None:
+        if sandwich != "pre_post":
+            raise ValueError(f"layernorm_type {sandwich!r}: only 'pre_post' (norms "
+                             "before and after the mixer and the feed-forward) "
+                             "is computed here")
+        fields["sandwich_norm"] = True
+    norm = published.get("norm_type")
+    if norm is not None:
+        if norm != "ZeroCenteredGatedNorm":
+            raise ValueError(f"norm_type {norm!r}: only 'ZeroCenteredGatedNorm' is "
+                             "computed here")
+        fields["norm_kind"] = "gated"
+    if int(published.get("num_nextn_predict_layers") or 0) > 0:
+        raise ValueError(
+            f"num_nextn_predict_layers {published['num_nextn_predict_layers']}: "
+            "multi-token prediction modules are not built here (they draft "
+            "tokens and leave the main model's logits alone; the engine does "
+            "not speculate)")
+    if published.get("use_shared_expert_sigmoid"):
+        raise ValueError("use_shared_expert_sigmoid true: a sigmoid gate on the "
+                         "shared expert is not computed here")
+    if published.get("rope_interleave") is False:
+        raise ValueError("rope_interleave false: _rope turns interleaved pairs")
+    head = published.get("qk_head_dim")
+    if head is not None and int(head) != int(published.get("qk_nope_head_dim", 0)) \
+            + int(published.get("qk_rope_head_dim", 0)):
+        raise ValueError(f"qk_head_dim {head}: not qk_nope_head_dim + "
+                         "qk_rope_head_dim")
+    if published.get("use_mla_scaling_factor") is False and (
+            published.get("rope_scaling") or {}).get("mscale_all_dim"):
+        raise ValueError(
+            "use_mla_scaling_factor false with rope_scaling.mscale_all_dim: "
+            "latent attention's softmax scale takes YaRN's mscale squared here")
+    return fields
 
 
 def _unnamed_fields(published: dict) -> dict:

@@ -107,14 +107,17 @@ def route(scores, top_k: int, n_group: int = 1, topk_group: int = 1,
     return gates * scale, experts
 
 
-def expert_ffn(x, gates, experts, w_gate, w_up, w_down, first):
+def expert_ffn(x, gates, experts, w_gate, w_up, w_down, first,
+               limit: float = 0.0):
     """The held experts' part of ``sum_j gates[n, j] * E_experts[n, j](x[n])``.
 
     ``x`` (N, d); ``gates``, ``experts`` (N, k); ``w_gate``/``w_up``
     (count, d, f) and ``w_down`` (count, f, d) hold experts ``first`` ..
-    ``first + count - 1`` (``first`` may be traced).  Returns the (N, d)
-    float32 part, the (count,) int32 pairs each held expert computed and
-    the row tiles the grouped-matmul kernels visited (0: ``ragged_dot``)."""
+    ``first + count - 1`` (``first`` may be traced); ``limit`` > 0 clamps
+    every expert's SwiGLU (``ops/grouped_matmul.py::swiglu_act``).
+    Returns the (N, d) float32 part, the (count,) int32 pairs each held
+    expert computed and the row tiles the grouped-matmul kernels visited
+    (0: ``ragged_dot``)."""
     n, k = experts.shape
     count = w_gate.shape[0]
     local = experts.reshape(-1) - first
@@ -123,7 +126,7 @@ def expert_ffn(x, gates, experts, w_gate, w_up, w_down, first):
     order = jnp.argsort(key, stable=True)
     sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
     rows = x[order // k]                               # (N·k, d), by expert
-    y, tiles = grouped_swiglu(rows, w_gate, w_up, w_down, sizes)
+    y, tiles = grouped_swiglu(rows, w_gate, w_up, w_down, sizes, limit=limit)
     # back to (token, choice) order; rows past the held groups count nothing
     # (a select: the kernels leave them unwritten)
     back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
@@ -151,6 +154,8 @@ class MoEMLP(nn.Module):
     #: (first, count): the experts whose weights this layer holds; None =
     #: all of them
     held: Optional[Tuple[int, int]] = None
+    #: > 0: every expert's SwiGLU is clamped at this limit
+    swiglu_limit: float = 0.0
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     mesh: Optional[Any] = None
@@ -194,7 +199,8 @@ class MoEMLP(nn.Module):
 
             def part(xt, gates, experts, *w):
                 mine = first + jax.lax.axis_index(MODEL_AXIS) * per
-                out, sizes, tiles = expert_ffn(xt, gates, experts, *w, mine)
+                out, sizes, tiles = expert_ffn(xt, gates, experts, *w, mine,
+                                               self.swiglu_limit)
                 return (jax.lax.psum(out, MODEL_AXIS), sizes,
                         jax.lax.psum(tiles, MODEL_AXIS))
 
@@ -205,7 +211,8 @@ class MoEMLP(nn.Module):
                     xt, gates, experts,
                     *[_ep_constraint(m, self.mesh) for m in w])
         else:
-            out, sizes, tiles = expert_ffn(xt, gates, experts, *w, first)
+            out, sizes, tiles = expert_ffn(xt, gates, experts, *w, first,
+                                           self.swiglu_limit)
         self.sow(COUNTERS, "layer", jnp.stack(
             [sizes.sum(), (sizes > 0).sum(), sizes.max(), tiles]
         ).astype(jnp.int32))

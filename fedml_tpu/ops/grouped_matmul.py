@@ -29,7 +29,11 @@ computes — operands in the model's dtype, float32 accumulation:
   Only bfloat16 goes to the kernels (:func:`kernel_can_run`): the one dtype
   lowered, timed and compared on the chip.
 
-Group sizes are traced: one compiled program serves every routing.
+Group sizes are traced: one compiled program serves every routing.  A model
+whose SwiGLU is clamped (``swiglu_limit``) passes ``limit``, a static option of
+every function here: ``silu(min(gate, limit)) * clip(up, -limit, limit)``
+(:func:`swiglu_act`), inside the gate-and-up kernel as in ``ragged_dot``'s
+form.
 """
 
 from __future__ import annotations
@@ -52,12 +56,21 @@ WEIGHT_TILE_BYTES = 4 << 20
 VMEM_LIMIT_BYTES = 16 << 20
 
 
-def swiglu_ragged(rows, w_gate, w_up, w_down, sizes):
+def swiglu_act(gate, up, limit: float = 0.0):
+    """``silu(gate) * up``; where ``limit`` > 0 the gate clamped to at most
+    ``limit`` and the up projection to ``±limit`` first."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+def swiglu_ragged(rows, w_gate, w_up, w_down, sizes, limit: float = 0.0):
     """``(silu(rows @ gate_g) * (rows @ up_g)) @ down_g`` for every row of
-    held group g; float32.  Rows past the held groups read zero."""
+    held group g (the operands of the product clamped where ``limit`` > 0:
+    :func:`swiglu_act`); float32.  Rows past the held groups read zero."""
     dot = lambda a, w: jax.lax.ragged_dot(
         a, w, sizes, preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)).astype(rows.dtype)
+    act = swiglu_act(dot(rows, w_gate), dot(rows, w_up), limit).astype(rows.dtype)
     return dot(act, w_down)
 
 
@@ -196,45 +209,54 @@ def grouped_matmul(rows, w, sizes, *, interpret: bool = False):
     return _grouped(rows, (w,), sizes, lambda y: y, jnp.float32, interpret)
 
 
-def gated_matmul(rows, w_gate, w_up, sizes, *, interpret: bool = False):
-    """``silu(rows @ w_gate[g]) * (rows @ w_up[g])``, computed in float32
-    and written in the rows' dtype, on the rows of every held group g."""
+def gated_matmul(rows, w_gate, w_up, sizes, *, limit: float = 0.0,
+                 interpret: bool = False):
+    """``silu(rows @ w_gate[g]) * (rows @ w_up[g])`` (the two products
+    clamped where ``limit`` > 0, a static option of the kernel), computed in
+    float32 and written in the rows' dtype, on the rows of every held group
+    g."""
     return _grouped(rows, (w_gate, w_up), sizes,
-                    lambda g, u: jax.nn.silu(g) * u, rows.dtype, interpret)
+                    functools.partial(swiglu_act, limit=limit), rows.dtype,
+                    interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def swiglu_pallas(rows, w_gate, w_up, w_down, sizes, interpret=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def swiglu_pallas(rows, w_gate, w_up, w_down, sizes, interpret=False,
+                  limit=0.0):
     """:func:`swiglu_ragged` on the held rows by the two kernels; the
     backward is :func:`swiglu_ragged`'s."""
-    return _swiglu_fwd(rows, w_gate, w_up, w_down, sizes, interpret)[0]
+    return _swiglu_fwd(rows, w_gate, w_up, w_down, sizes, interpret, limit)[0]
 
 
-def _swiglu_fwd(rows, w_gate, w_up, w_down, sizes, interpret):
-    act = gated_matmul(rows, w_gate, w_up, sizes, interpret=interpret)
+def _swiglu_fwd(rows, w_gate, w_up, w_down, sizes, interpret, limit):
+    act = gated_matmul(rows, w_gate, w_up, sizes, limit=limit,
+                       interpret=interpret)
     return (grouped_matmul(act, w_down, sizes, interpret=interpret),
             (rows, w_gate, w_up, w_down, sizes))
 
 
-def _swiglu_bwd(interpret, saved, g):
+def _swiglu_bwd(interpret, limit, saved, g):
     *operands, sizes = saved
-    _, vjp = jax.vjp(lambda *o: swiglu_ragged(*o, sizes), *operands)
+    _, vjp = jax.vjp(lambda *o: swiglu_ragged(*o, sizes, limit), *operands)
     return (*vjp(g), None)
 
 
 swiglu_pallas.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
-@jax.jit     # a model's layers of one shape share one trace and one lowering
-def swiglu(rows, w_gate, w_up, w_down, sizes):
+# a model's layers of one shape share one trace and one lowering
+@functools.partial(jax.jit, static_argnames=("limit",))
+def swiglu(rows, w_gate, w_up, w_down, sizes, limit: float = 0.0):
     """The held experts' SwiGLU over the sorted rows and the row tiles the
     kernels visited: :func:`swiglu_pallas` where the program is lowered for
-    a TPU and :func:`kernel_can_run`, else :func:`swiglu_ragged` and 0."""
+    a TPU and :func:`kernel_can_run`, else :func:`swiglu_ragged` and 0.
+    ``limit`` > 0 clamps the operands of ``silu(gate) * up``
+    (:func:`swiglu_act`), in the kernel as in ``ragged_dot``'s form."""
     def ragged(*operands):
-        return swiglu_ragged(*operands), jnp.zeros((), jnp.int32)
+        return swiglu_ragged(*operands, limit), jnp.zeros((), jnp.int32)
 
     def kernels(*operands):
-        return (swiglu_pallas(*operands),
+        return (swiglu_pallas(*operands, False, limit),
                 visited_tiles(operands[-1], operands[0].shape[0]))
 
     operands = (rows, w_gate, w_up, w_down, sizes)
@@ -246,4 +268,5 @@ def swiglu(rows, w_gate, w_up, w_down, sizes):
 
 
 __all__ = ["swiglu", "swiglu_ragged", "swiglu_pallas", "grouped_matmul",
-           "gated_matmul", "visited_tiles", "kernel_can_run", "row_tile"]
+           "gated_matmul", "swiglu_act", "visited_tiles", "kernel_can_run",
+           "row_tile"]

@@ -18,8 +18,9 @@ docs/SERVING.md "Memory plane"): every slot addresses its own pages through
 a block table carried as traced data, admission reserves pages on the host,
 and a prompt enters in fixed-size chunks that share the tick with decode.
 A layer whose decode needs state that is not K/V (the short convolution's
-last rows of input) keeps it beside the pool, a row a slot, with no
-reservation (docs/SERVING.md "State that is not pages").
+last rows of input; a linear-attention layer's matrix state) keeps it beside
+the pool, a row a slot, with no reservation (docs/SERVING.md "State that is
+not pages").
 
 Multi-tenant LoRA (``adapter_slots``/``adapter_registry``, see
 :mod:`fedml_tpu.serving.adapters` and docs/SERVING.md): N adapters live
@@ -344,23 +345,24 @@ class ContinuousBatchingEngine:
         self._window_pages_freed = 0
         # state beside pages (docs/SERVING.md, "State that is not pages"): a
         # layer that mixes by the short convolution keeps, for every slot,
-        # the last rows of its input in one buffer a layer, a row a slot and
-        # a trash row.  A slot owns its row for as long as the engine lives:
-        # nothing is reserved and no request parks for state.  The programs
-        # keep three rules: a request's first chunk starts from zeros (its
-        # first position is 0: no upload, no host write), every chunk and
-        # tick writes its lanes' rows back, and a lane that is not live
-        # addresses the trash row, so a slot between two chunks rides a tick
-        # and keeps what its last chunk left.
-        self._stateful = bool(getattr(cfg, "conv_layers", 0))
+        # the last rows of its input, and a linear-attention layer its matrix
+        # state and its convolution's rows, in one buffer a leaf, a row a
+        # slot and a trash row.  A slot owns its row for as long as the
+        # engine lives: nothing is reserved and no request parks for state.
+        # The programs keep three rules: a request's first chunk starts from
+        # zeros (its first position is 0: no upload, no host write), every
+        # chunk and tick writes its lanes' rows back, and a lane that is not
+        # live addresses the trash row, so a slot between two chunks rides a
+        # tick and keeps what its last chunk left.
+        self._stateful = bool(getattr(cfg, "state_layers", 0))
         if self._stateful:
             if prefix_cache_slots:
                 raise PagedKVUnsupportedError(
                     "prefix pages are not shared by a model with "
-                    "convolution layers: a lent prefix would need the "
-                    "layers' state at its end, of which no snapshot is "
-                    "kept (prefix_cache_slots=0 for a model with conv "
-                    "layers)")
+                    "convolution layers or linear-attention layers: a lent "
+                    "prefix would need the layers' state at its end, of "
+                    "which no snapshot is kept (prefix_cache_slots=0 for a "
+                    "model with such layers)")
             geometry["state_slots"] = self.n_slots
         self.paged_model = type(model)(dataclasses.replace(cfg, **geometry))
         self.page_pool = PagedBlockPool(pool_pages)
@@ -612,13 +614,19 @@ class ContinuousBatchingEngine:
         # holds (K and V rows of every kv head; one latent row) and
         # whichever pool the layer's pages lie in; a leaf of state has no
         # pages and is counted apart, whole
-        paged, state = [], []
+        from ..llm.model import LINEAR_LEAVES, STATE_LEAVES
+        paged, state, linear = [], [], []
         for path, p in jax.tree_util.tree_leaves_with_path(self._pool):
-            (state if getattr(path[-1], "key", None) == "conv_state"
-             else paged).append(p)
+            leaf = getattr(path[-1], "key", None)
+            (state if leaf in STATE_LEAVES else paged).append(p)
+            if leaf in LINEAR_LEAVES:
+                linear.append(p)
         self._kv_bytes_per_token = sum(
             p.nbytes // (p.shape[0] * ptok) for p in paged)
         self._state_bytes = sum(p.nbytes for p in state)
+        # a row of every linear-attention layer's state (its matrix state and
+        # its convolution's rows): what a lane of a call reads and writes back
+        self._linear_row_bytes = sum(p.nbytes // p.shape[0] for p in linear)
         # sparse layers: the chunk program's first accumulator
         self._moe_layers = sum(
             cfg.sparse_layer(i) for i in range(cfg.n_layers))
@@ -1253,6 +1261,9 @@ class ContinuousBatchingEngine:
                     # the row the chunk wrote back, and whether it read it
                     # from an earlier chunk (a first chunk starts from zeros)
                     span.set(state_rows=1, state_carried=int(cs > 0))
+                if self._linear_row_bytes:
+                    # the linear-attention layers' row it wrote back
+                    span.set(linear_state_bytes=self._linear_row_bytes)
                 if self.window_pool is not None:
                     span.set(window_pages_freed=freed)
                 if self._counts_attn_pages:
@@ -1633,6 +1644,9 @@ class ContinuousBatchingEngine:
             # alone (their lanes address the trash row)
             args = {"state_rows": len(live), "state_held": sum(
                 s.prefilling and s.pf_next > 0 for s in self._slots)}
+            if self._linear_row_bytes:
+                # the linear-attention layers' rows the tick writes back
+                args["linear_state_bytes"] = len(live) * self._linear_row_bytes
         return tracer.span(
             "serve.tick", cat="engine", live=len(live),
             live_kv_tokens=(sum(self._slots[i].pos for i in live)

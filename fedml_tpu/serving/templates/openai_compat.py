@@ -94,8 +94,9 @@ def _sample_live(live, key, temp, top_k: int, top_p: float = 1.0):
 
 def _keeps_state(model) -> bool:
     """Does the model keep per-request state that is not K/V (the rows of
-    ``llm/model.py::ShortConv``), of which no snapshot can be taken?"""
-    return bool(getattr(getattr(model, "cfg", None), "conv_layers", 0))
+    ``llm/model.py::ShortConv`` or of ``llm/linear_attention.py``), of which
+    no snapshot can be taken?"""
+    return bool(getattr(getattr(model, "cfg", None), "state_layers", 0))
 
 
 @functools.lru_cache(maxsize=32)
@@ -394,8 +395,9 @@ def generate(apply_fn: Callable, params, prompt_ids: List[int],
         # never serve
         if prefix_cache is not None and _keeps_state(model):
             raise ValueError(
-                "prefix_cache with a model that has convolution layers: the "
-                "state at the end of a shared prefix is not kept")
+                "prefix_cache with a model that has convolution layers or "
+                "linear-attention layers: the state at the end of a shared "
+                "prefix is not kept")
         hit_len, hit_cache = (prefix_cache.lookup(prompt_ids, raw_params,
                                                   lora)
                               if prefix_cache is not None and n > 0
@@ -531,8 +533,8 @@ class OpenAICompatServer:
         if draft_model is not None and (_keeps_state(model)
                                         or _keeps_state(draft_model)):
             raise ValueError(
-                "draft_model with a model that has convolution layers: a "
-                "rejected draft token has already moved the layers' state, "
+                "draft_model with a model that has convolution layers or "
+                "linear-attention layers: a rejected draft token has already moved the layers' state, "
                 "and no snapshot is kept to take it back")
         # prefix_cache_slots > 0 (requires ``model``): reuse prefill KV
         # for shared prompt prefixes.  Non-engine path: one PrefixCache
@@ -544,7 +546,7 @@ class OpenAICompatServer:
         if prefix_cache_slots and _keeps_state(model):
             raise ValueError(
                 "prefix_cache_slots with a model that has convolution "
-                "layers: a cached prefix would need the layers' state at "
+                "layers or linear-attention layers: a cached prefix would need the layers' state at "
                 "the end of the part that is shared, of which no snapshot "
                 "is kept")
         if prefix_cache_slots and model is None:

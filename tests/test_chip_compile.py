@@ -561,6 +561,93 @@ def test_paged_program_never_moves_a_whole_pool(request, model, program):
     assert "slice-start" not in hlo and "copy-start" in hlo
 
 
+@pytest.fixture(scope="module")
+def linear_programs(one_chip):
+    """The same two programs of a model whose layers mix by the gated delta
+    rule or by gated latent attention, at the widths of ``benchmarks/configs/
+    gigachat3.5-ep16-d5.json`` (32 key and 64 value heads of 128, four taps;
+    latent attention of ``a.x-k1-ep16-d7``'s widths with a gate; 16 of 256
+    experts of 2,048 held, clamped at 10; sandwich gated norms), one linear
+    and one latent layer deep, both sparse, on 8 slots, a buffer of 1,040,
+    chunk 512 and 401 pages (what the state costs is per slot and layer).
+    The weights are shapes only."""
+    from fedml_tpu.llm.model import LlamaConfig, LlamaLM, YarnScaling
+    from fedml_tpu.serving.batching import ContinuousBatchingEngine
+
+    cfg = LlamaConfig(
+        vocab_size=512, dim=7168, n_layers=2, n_heads=64, n_kv_heads=64,
+        ffn_dim=18432, max_seq_len=1040, rope_theta=1e5, norm_eps=1e-6,
+        dtype=jnp.bfloat16, lora_rank=16, lora_alpha=16.0,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling=YarnScaling(8, 32768, 32, 1, 1, 1),
+        layer_types=("linear_attention", "full_attention"),
+        linear_key_heads=32, linear_value_heads=64, linear_key_head_dim=128,
+        linear_value_head_dim=128, linear_conv_kernel=4, gated_attention=True,
+        sandwich_norm=True, norm_kind="gated", swiglu_limit=10.0,
+        n_experts=256, moe_top_k=8, moe_ffn_dim=2048, n_shared_experts=1,
+        moe_scoring="sigmoid", moe_select_bias=True, moe_routed_scale=2.5,
+        experts_held=(0, 16))
+    model = LlamaLM(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ContinuousBatchingEngine(
+        model, params, slots=8, buf_len=1040, adapter_slots=17,
+        kv_page_tokens=16, kv_pool_pages=401, prefill_chunk_tokens=512)
+    assert eng._state_bytes == 9 * (64 * 128 * 128 * 4 + 3 * 16384 * 2)
+    return _engine_programs(eng, one_chip)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_linear_state_is_written_where_it_lies(linear_programs, program):
+    """The matrix state (a float32 row of 64 x 128 x 128 a slot and the trash
+    row) and the convolution's rows are each updated in place by one scatter
+    or update, donated and aliased, and nothing else of their size is copied
+    or re-laid out; the latent pool likewise.  The experts run as the two
+    grouped-matmul kernels (the clamp inside them), the latent read as its
+    kernel, and the core's instructions lie under ``layer_0/linear_attn/core``
+    in the program's map."""
+    from fedml_tpu.obs import programs
+    compiled, donated = linear_programs
+    states = [p for p in donated if p.shape[:1] == (9,) and p.ndim == 4]
+    convs = [p for p in donated if p.shape == (9, 3, 16384)]
+    pools = [p for p in donated if p.shape[:1] == (401,)]
+    assert [p.dtype for p in states] == [jnp.float32] and len(convs) == 1 and len(pools) == 1
+    hlo = compiled[program].as_text()
+    assert "ragged-dot" not in hlo and "%gated_matmul" in hlo and "%grouped_matmul" in hlo
+    assert len(re.findall(r"%latent_attention[.\d]* = ", hlo)) == 1
+    naming = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+    for leaf in states + convs + pools:
+        instrs = _pool_sized_instructions(hlo, leaf.size)
+        # the tick's gather of its lanes' rows may be the compiler's loop of
+        # row reads, which passes the buffer through untouched (a ``while``)
+        moving = [(op, line[:200]) for op, line in instrs if op not in naming | {
+            "scatter", "dynamic-update-slice", "fusion", "copy-start", "copy-done", "while"}]
+        assert not moving, (leaf.shape, moving)
+    aliases = re.search(r"input_output_alias=\{(.*?) \}, ", hlo).group(1)
+    assert aliases.count("-alias)") == len(donated)
+    held = sum(p.size * p.dtype.itemsize for p in states + convs + pools)
+    assert compiled[program].memory_analysis().alias_size_in_bytes >= held
+    paths = {row["path"] for row in programs.parse_hlo(hlo).values()}
+    assert any(p.startswith("layer_0/linear_attn/core") for p in paths), sorted(paths)[:40]
+
+
+def test_the_tick_reads_its_lanes_rows_under_the_core(linear_programs):
+    """The tick's loop of row reads that gathers its 8 lanes' matrix state
+    (``f32[8,64,128,128]``) is the core's own: every fusion of that size lies
+    under ``layer_0/linear_attn/core`` in the program's map, so the device
+    time of the gather is counted with the rule's."""
+    from fedml_tpu.obs import programs
+    compiled, _ = linear_programs
+    hlo = compiled["decode_step"].as_text()
+    rows = programs.parse_hlo(hlo)
+    fusions = [line.split(" = ")[0].split()[-1].lstrip("%")
+               for op, line in _pool_sized_instructions(hlo, 8 * 64 * 128 * 128) if op == "fusion"]
+    assert any("dynamic-slice" in name for name in fusions), fusions
+    assert all(rows[name]["path"].startswith("layer_0/linear_attn/core") for name in fusions), \
+        {name: rows[name]["path"] for name in fusions}
+
+
 # -- the instruction -> module map of the programs the chip runs ----------------
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])

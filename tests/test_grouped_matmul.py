@@ -126,13 +126,13 @@ def test_gated_matmul_equals_silu_dot_times_dot(dtype):
 
 # -- expert_ffn through the kernels against expert_ffn through ragged_dot -------
 
-def _through_the_kernels(*operands):
-    return (gm.swiglu_pallas(*operands, True),
+def _through_the_kernels(*operands, limit=0.0):
+    return (gm.swiglu_pallas(*operands, True, limit),
             gm.visited_tiles(operands[-1], operands[0].shape[0]))
 
 
-def _through_ragged_dot(*operands):
-    return gm.swiglu_ragged(*operands), jnp.zeros((), jnp.int32)
+def _through_ragged_dot(*operands, limit=0.0):
+    return gm.swiglu_ragged(*operands, limit), jnp.zeros((), jnp.int32)
 
 
 def _layer(dtype, n=48, k=4, e=8, d=128, f=256, held=(2, 5), one_expert=False):

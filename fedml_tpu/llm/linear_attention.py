@@ -13,7 +13,8 @@ token's normed input ``n``:
   head h reads key head ``h // (value heads / key heads)``;
 - ``beta = sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)``;
 - per value head a matrix state ``S`` (d_k x d_v): ``S <- e^g S``, then
-  ``S <- S + k (beta (v - S^T k))^T``, and the output ``o = S^T q``;
+  ``S <- S + k (beta (v - S^T k))^T``, and the output ``o = S^T q``
+  (``step`` computes it re-associated, reading ``S`` once for both sums);
 - ``y = W_o [RMS(o_h) (1 + w) * linear_gate_scale * sigmoid(z_h)]_h``, the norm
   over a head's d_v numbers with eps ``linear_norm_eps`` and one weight ``w``
   of d_v (zero-centred).
@@ -31,9 +32,14 @@ What a later call needs of the past, on the decode paths, is two leaves of the
 rules, ``model.py``): ``linear_state``, the matrix state in ``STATE_DTYPE``
 (float32), ``(rows, value heads, d_k, d_v)``, and ``linear_conv``, the last
 ``taps - 1`` rows of ``[q; k; v]`` before the convolution, in the model's
-dtype.  A lane whose first position is 0 starts from zeros, every call writes
-its lanes' rows back, and a lane that must leave a slot's state alone is given
-the trash row by its caller.
+dtype.  A lane whose first position is 0 starts from zeros (only where it has a
+real position), and every call writes its lanes' rows back.  The decode tick
+addresses state by slot: no ``state_rows`` (lane i is row i) and ``seq_lens``
+1 or 0, so the first ``b`` rows are read where they lie and written back by one
+in-place update at row 0, and a lane with ``seq_lens`` 0 gets back the rows it
+read, bit for bit (``model.py::write_rows``).  A prefill chunk addresses its
+one slot's row by index (``_rows_of``, which serves the chunk alone) and
+scatters it back.
 
 Everything between the projections runs under the scope ``core``
 (``layer_*/linear_attn/core`` in a program's module map), so the device time of
@@ -48,7 +54,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .model import LlamaConfig, _projection
+from .model import LlamaConfig, _projection, last_rows, starts_fresh, write_rows
 
 #: positions a block of the chunked form holds
 BLOCK = 64
@@ -66,7 +72,8 @@ def l2norm(x, eps: float = L2_EPS):
 def _rows_of(table, rows):
     """``table[rows]`` as a loop of row reads written here, so that each
     read carries this scope's name into the program's module map (the
-    compiler's own loop for a gather of whole rows carries none)."""
+    compiler's own loop for a gather of whole rows carries none).  The
+    chunk's one row; the tick reads its rows where they lie."""
     return jax.lax.map(
         lambda r: jax.lax.dynamic_index_in_dim(table, r, keepdims=False), rows)
 
@@ -74,13 +81,19 @@ def _rows_of(table, rows):
 def step(state, q, k, v, beta, g):
     """One position: ``state`` (b, H, dk, dv) float32, ``q``/``k`` (b, H, dk),
     ``v`` (b, H, dv), ``beta``/``g`` (b, H) -> the next state and ``o`` (b, H,
-    dv).  Products and sums over d_k elementwise, in float32: a product at
-    ``HIGHEST`` would split the state into three bfloat16 copies (at a
-    tick's 96 lanes, 600 MB a product and layer)."""
-    state = state * jnp.exp(g)[..., None, None]
-    kv = jnp.sum(state * k[..., :, None], axis=-2)                     # S^T k
-    state = state + k[..., :, None] * ((v - kv) * beta[..., None])[..., None, :]
-    return state, jnp.sum(state * q[..., :, None], axis=-2)            # S^T q
+    dv).  The rule re-associated so that the state is read once for both sums
+    over d_k, ``kv = e^g S^T k`` and ``qs = e^g S^T q``, and once more for the
+    update: ``delta = beta (v - kv)``, ``o = qs + (k^T q) delta`` (which is
+    ``S_new^T q``) and ``S_new = e^g S + k delta^T``.  Products and sums
+    elementwise, in float32: a product at ``HIGHEST`` would split the state
+    into three bfloat16 copies (at a tick's 96 lanes, 600 MB a product and
+    layer)."""
+    decay = jnp.exp(g)[..., None]
+    kv = decay * jnp.sum(state * k[..., :, None], axis=-2)             # e^g S^T k
+    qs = decay * jnp.sum(state * q[..., :, None], axis=-2)             # e^g S^T q
+    delta = (v - kv) * beta[..., None]
+    o = qs + jnp.sum(k * q, axis=-1, keepdims=True) * delta
+    return state * decay[..., None] + k[..., :, None] * delta[..., None, :], o
 
 
 def chunked(q, k, v, beta, g, state, block: int = BLOCK):
@@ -163,13 +176,15 @@ class GatedDeltaNet(nn.Module):
                                           (n_rows, taps - 1, width), cfg.dtype)
                 carried = self.variable("cache", "linear_state", jnp.zeros,
                                         (n_rows, hv, dk, dv), STATE_DTYPE)
-                if rows is None:
-                    rows = jnp.arange(b)
-                fresh = jnp.reshape(positions[..., 0] == 0, (-1,))
-                prev = jnp.where(fresh[:, None, None], 0,
-                                 conv_rows.value[rows]).astype(mixed.dtype)
+                fresh = starts_fresh(positions, lens)
+                if rows is None:    # lane i is row i: read where they lie
+                    old_conv, old_state = conv_rows.value[:b], carried.value[:b]
+                else:
+                    old_conv = conv_rows.value[rows]
+                    old_state = _rows_of(carried.value, rows)
+                prev = jnp.where(fresh[:, None, None], 0, old_conv).astype(mixed.dtype)
                 s0 = jnp.where(fresh[:, None, None, None], 0.0,
-                               _rows_of(carried.value, rows).astype(jnp.float32))
+                               old_state.astype(jnp.float32))
             else:
                 prev = jnp.zeros((b, taps - 1, width), mixed.dtype)
                 s0 = jnp.zeros((b, hv, dk, dv), jnp.float32)
@@ -194,13 +209,10 @@ class GatedDeltaNet(nn.Module):
                 o, s1 = chunked(heads(q), heads(k), heads(v), heads(beta), heads(g), s0,
                                 BLOCK)
             if decode:
-                if lens is None:
-                    last = ext[:, s:]
-                else:   # [q; k; v] of positions lens-K+1 .. lens-1, in ext's rows
-                    at = lens[:, None] + jnp.arange(taps - 1)[None, :]
-                    last = jnp.take_along_axis(ext, at[:, :, None], axis=1)
-                conv_rows.value = conv_rows.value.at[rows].set(last.astype(cfg.dtype))
-                carried.value = carried.value.at[rows].set(s1.astype(STATE_DTYPE))
+                conv_rows.value = write_rows(conv_rows.value, rows, lens, old_conv,
+                                             last_rows(ext, s, lens).astype(cfg.dtype))
+                carried.value = write_rows(carried.value, rows, lens, old_state,
+                                           s1.astype(STATE_DTYPE))
             o = jnp.moveaxis(o, 1, 2)                           # (b, s, H, dv)
             o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
                                   + cfg.linear_norm_eps) * (1.0 + o_norm)

@@ -206,8 +206,9 @@ class LlamaConfig:
     #: >0 (the engine sets it for a model with ``"conv"`` or
     #: ``"linear_attention"`` layers): on the paged path such a layer's state
     #: is one buffer a leaf of this many rows and a trash row, a row a slot,
-    #: addressed by the call's ``state_rows``.  0: a row for each row of the
-    #: batch (the single-request path).
+    #: addressed by the call's ``state_rows`` (the tick's: none, lane i is
+    #: row i).  0: a row for each row of the batch (the single-request
+    #: path).
     state_slots: int = 0
 
     def __post_init__(self):
@@ -907,6 +908,41 @@ class Attention(nn.Module):
         return project(out)
 
 
+def starts_fresh(positions, lens):
+    """(b,) bool: the lanes of a stateful layer's call that start from zeros,
+    those whose first position is 0 and that have a real position (``lens``
+    None: all have).  A lane with ``lens == 0`` starts nothing: its slot may
+    be held at any position, position 0 among them."""
+    fresh = jnp.reshape(positions[..., 0] == 0, (-1,))
+    return fresh if lens is None else fresh & (lens > 0)
+
+
+def last_rows(ext, s, lens):
+    """What a stateful layer's call of ``s`` positions carries to the next:
+    of ``ext`` (b, K - 1 + s, ...), the carried rows then the call's own, the
+    K - 1 rows of positions ``lens - K + 1 .. lens - 1``.  Where every
+    position is real (``lens`` None) or the call has one (a lane with
+    ``lens`` 0 keeps its row in ``write_rows``) they are the last K - 1."""
+    if lens is None or s == 1:
+        return ext[:, s:]
+    at = lens[:, None] + jnp.arange(ext.shape[1] - s)[None, :]
+    return jnp.take_along_axis(ext, at[:, :, None], axis=1)
+
+
+def write_rows(table, rows, lens, old, new):
+    """A stateful layer's buffer of rows with each lane's row set to ``new``,
+    or kept as ``old`` (what the call read of it) where the lane's ``lens`` is
+    0, bit for bit.  ``rows`` None: lane i is row i, and the first ``b`` rows
+    are written where they lie by one update at row 0 (no scatter); else the
+    lanes' ``rows``, by a scatter."""
+    if lens is not None:
+        hold = jnp.reshape(lens == 0, (-1,) + (1,) * (new.ndim - 1))
+        new = jnp.where(hold, old, new)
+    if rows is None:
+        return jax.lax.dynamic_update_slice_in_dim(table, new, 0, axis=0)
+    return table.at[rows].set(new)
+
+
 class ShortConv(nn.Module):
     """The gated short convolution, a mixer in place of attention
     (``layer_types[i] == "conv"``): ``[B | C | z] = in_proj(x)``, ``u = B * z``,
@@ -920,15 +956,17 @@ class ShortConv(nn.Module):
     ``(state_slots + 1, K - 1, dim)`` for the engine's paged model (a row a
     slot and the trash row), else a row for each row of the batch.
     ``state = (rows, lens)``: the (b,) rows this call's lanes address
-    (None: ``arange(b)``) and how many of each lane's ``s`` positions are real
-    (None: all; the positions behind them are a chunk's padding).  Three rules,
-    all inside the call: a lane whose first position is 0 starts from zeros,
-    whatever its row holds (nothing precedes position 0: a request's first
-    chunk uploads and clears nothing); every call writes each lane's last
-    ``K - 1`` real rows back to its row; and a lane that must leave a slot's
-    state alone is given the trash row by its caller.  A call of ``s``
-    positions computes its convolution from the carried rows and its own ``u``
-    in one shifted sum."""
+    (None: lane i is row i, read where it lies and written back by one update
+    at row 0, as the decode tick calls it) and how many of each lane's ``s``
+    positions are real (None: all; the positions behind them are a chunk's
+    padding; 0: none, and the lane keeps its row).  Three rules, all inside
+    the call: a lane whose first position is 0 and that has a real position
+    starts from zeros, whatever its row holds (nothing precedes position 0: a
+    request's first chunk uploads and clears nothing); every call writes each
+    lane's last ``K - 1`` real rows back to its row; and a lane with ``lens``
+    0 gets back, bit for bit, the row it read (``write_rows``).  A call of
+    ``s`` positions computes its convolution from the carried rows and its
+    own ``u`` in one shifted sum."""
     cfg: LlamaConfig
 
     @nn.compact
@@ -947,22 +985,17 @@ class ShortConv(nn.Module):
                 "cache", "conv_state", jnp.zeros,
                 (cfg.state_slots + 1 if cfg.state_slots else b, taps - 1, d),
                 cfg.dtype)
-            if rows is None:
-                rows = jnp.arange(b)
-            fresh = jnp.reshape(positions[..., 0] == 0, (-1, 1, 1))
-            prev = jnp.where(fresh, 0, carried.value[rows]).astype(u.dtype)
+            old = carried.value[:b] if rows is None else carried.value[rows]
+            fresh = starts_fresh(positions, lens)[:, None, None]
+            prev = jnp.where(fresh, 0, old).astype(u.dtype)
         else:
             prev = jnp.zeros((b, taps - 1, d), u.dtype)
         ext = jnp.concatenate([prev, u], axis=1)        # (b, K-1+s, d)
         c = sum(ext[:, j:j + s].astype(jnp.float32) * w[:, j]
                 for j in range(taps)).astype(u.dtype)
         if decode:
-            if lens is None:
-                last = ext[:, s:]
-            else:       # u of positions lens-K+1 .. lens-1, in ext's rows
-                at = lens[:, None] + jnp.arange(taps - 1)[None, :]
-                last = jnp.take_along_axis(ext, at[:, :, None], axis=1)
-            carried.value = carried.value.at[rows].set(last.astype(cfg.dtype))
+            carried.value = write_rows(carried.value, rows, lens, old,
+                                       last_rows(ext, s, lens).astype(cfg.dtype))
         return dense(d, "out_proj")(gate_out * c)
 
 
@@ -1074,8 +1107,9 @@ class LlamaLM(nn.Module):
         ``"conv"`` or ``"linear_attention"`` layers keeps their state by
         rows (:class:`ShortConv`, ``linear_attention.GatedDeltaNet``):
         ``state_rows`` ((B,) int32, traced) says which row each lane of the
-        call addresses (None: lane i its own row i) and ``seq_lens`` ((B,))
-        how many of a lane's positions are real (None: all of them).
+        call addresses (None: lane i its own row i, read and written where it
+        lies) and ``seq_lens`` ((B,)) how many of a lane's positions are real
+        (None: all of them; 0: none, and the lane's rows stay as they were).
         ``return_hidden=True`` returns final-norm hidden states without
         the lm_head projection (the streaming cross-entropy path)."""
         cfg = self.cfg

@@ -352,8 +352,9 @@ class ContinuousBatchingEngine:
         # The programs keep three rules: a request's first chunk starts from
         # zeros (its first position is 0: no upload, no host write), every
         # chunk and tick writes its lanes' rows back, and a lane that is not
-        # live addresses the trash row, so a slot between two chunks rides a
-        # tick and keeps what its last chunk left.
+        # live has no real position in the tick (``seq_lens`` 0) and gets
+        # back the rows it read, so a slot between two chunks rides a tick
+        # and keeps what its last chunk left.
         self._stateful = bool(getattr(cfg, "state_layers", 0))
         if self._stateful:
             if prefix_cache_slots:
@@ -394,7 +395,6 @@ class ContinuousBatchingEngine:
         key_words = int(np.asarray(jax.random.PRNGKey(0)).size)
         two_pools = self.window_pool is not None
         stateful = self._stateful
-        n_slots = self.n_slots
 
         def tables(state, pick):
             """What the model takes as ``block_tables``: ``pick`` of the
@@ -426,11 +426,10 @@ class ContinuousBatchingEngine:
             params = dequantize_params(params, wdtype)
             live = state["left"] > 0
             btabs = tables(state, lambda t: jnp.where(live[:, None], t, 0))
-            # the rows of state, for a model that keeps any: a lane that is
-            # not live addresses the trash row, the twin of its all-trash
-            # table
-            rows = {"state_rows": jnp.where(live, jnp.arange(n_slots),
-                                            n_slots)} if stateful else {}
+            # the rows of state, for a model that keeps any: lane i is slot
+            # i's row, read and written where it lies; a lane that is not
+            # live has no real position and leaves its row as it was
+            rows = {"seq_lens": live.astype(jnp.int32)} if stateful else {}
 
             def body(carry, _):
                 pool, toks, poss, keys = carry
@@ -1641,7 +1640,7 @@ class ContinuousBatchingEngine:
         if self._stateful and tracing:
             # rows of state the tick writes back (its live lanes), and the
             # slots between two chunks of a prompt, whose rows it leaves
-            # alone (their lanes address the trash row)
+            # alone (their lanes have no real position)
             args = {"state_rows": len(live), "state_held": sum(
                 s.prefilling and s.pf_next > 0 for s in self._slots)}
             if self._linear_row_bytes:
@@ -1811,8 +1810,9 @@ class ContinuousBatchingEngine:
                 self._lanes_burned += burned
         with tracer.span("serve.tick.free", cat="engine"):
             # the record's device arrays go here, by name, and not at the
-            # return: the first one freed after its program has run blocks
-            # for milliseconds on the TPU runtime
+            # return: dropping them lets go of the interpreter's lock, and
+            # the thread waits here, not in the runtime, while the HTTP
+            # threads stream, with the next tick running under it
             if rec:
                 rec.clear()
             del toks, firsts

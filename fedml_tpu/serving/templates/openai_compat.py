@@ -61,6 +61,48 @@ class ByteTokenizer:
         return data.decode("utf-8", errors="replace")
 
 
+class IncrementalText:
+    """The text each new token id adds to a stream, on character
+    boundaries: what ``tokenizer.decode`` of all the ids so far adds to the
+    text already given out, read from a window of the last few ids.  A
+    token's delta costs the same at the four-thousandth token as at the
+    first (decoding every id again at every token costs the answer's
+    length, and at reasoning lengths that work holds the interpreter
+    through most of a tick).  A trailing replacement character marks an
+    incomplete UTF-8 sequence (a byte tokenizer splits multi-byte
+    characters): it is held back until the sequence completes, and the
+    window moves up only once nothing is held, keeping ``CONTEXT`` ids
+    before the next token so that a tokenizer that spells a piece by its
+    neighbour (a leading space) spells it as in the whole text."""
+
+    #: ids kept before the next token when the window moves up
+    CONTEXT = 4
+
+    def __init__(self, tokenizer):
+        self.tok = tokenizer
+        self.ids: List[int] = []
+        self.start = 0      # the window: ids[start:]
+        self.sent = 0       # characters of the window's text given out
+
+    def push(self, token: int) -> str:
+        """Add one id; the text it completes ("" while a character is
+        incomplete)."""
+        self.ids.append(token)
+        text = self.tok.decode(self.ids[self.start:])
+        clean = text.rstrip("\ufffd")
+        delta = clean[self.sent:]
+        self.sent = max(self.sent, len(clean))
+        start = len(self.ids) - self.CONTEXT
+        if len(clean) == len(text) == self.sent and start > self.start:
+            self.start = start
+            self.sent = len(self.tok.decode(self.ids[start:]))
+        return delta
+
+    def tail(self) -> str:
+        """What is still held back at the end of the stream."""
+        return self.tok.decode(self.ids[self.start:])[self.sent:]
+
+
 def _sample_live(live, key, temp, top_k: int, top_p: float = 1.0):
     """live: (V,) logits → sampled token id (greedy at temp 0).
 
@@ -665,20 +707,13 @@ class OpenAICompatServer:
         (validated W3C header value) joins the request's span tree to the
         caller's fedscope trace."""
         tok = self.tokenizer
-        ids: List[int] = []
-        sent = 0
+        stream = IncrementalText(tok)
         t_submit = time.monotonic()
 
         def emit(t: int):
-            nonlocal sent
-            ids.append(t)
-            text = tok.decode(ids)
-            # trailing replacement chars mark an incomplete UTF-8 sequence;
-            # hold those bytes back until the sequence completes
-            clean = text.rstrip("�")
-            if len(clean) > sent:
-                on_text(clean[sent:])
-                sent = len(clean)
+            delta = stream.push(t)
+            if delta:
+                on_text(delta)
 
         # adapter routing: an explicit {"adapter": name} field, or —
         # multi-tenant OpenAI convention — {"model": name} naming anything
@@ -824,10 +859,11 @@ class OpenAICompatServer:
                     adapter=adapter_name or "base",
                     output_tokens=len(out), e2e_s=round(e2e_s, 6),
                     traceparent=traceparent, path="fallthrough")
-        text = tok.decode(out)
-        if on_text and len(text) > sent:
-            on_text(text[sent:])  # flush any held-back tail
-        return text
+        if on_text:
+            rest = stream.tail()
+            if rest:
+                on_text(rest)  # flush any held-back tail
+        return tok.decode(out)
 
     def _make_handler(self):
         outer = self

@@ -619,10 +619,8 @@ def test_linear_state_is_written_where_it_lies(linear_programs, program):
     naming = {"parameter", "get-tuple-element", "tuple", "bitcast"}
     for leaf in states + convs + pools:
         instrs = _pool_sized_instructions(hlo, leaf.size)
-        # the tick's gather of its lanes' rows may be the compiler's loop of
-        # row reads, which passes the buffer through untouched (a ``while``)
         moving = [(op, line[:200]) for op, line in instrs if op not in naming | {
-            "scatter", "dynamic-update-slice", "fusion", "copy-start", "copy-done", "while"}]
+            "scatter", "dynamic-update-slice", "fusion", "copy-start", "copy-done"}]
         assert not moving, (leaf.shape, moving)
     aliases = re.search(r"input_output_alias=\{(.*?) \}, ", hlo).group(1)
     assert aliases.count("-alias)") == len(donated)
@@ -632,20 +630,62 @@ def test_linear_state_is_written_where_it_lies(linear_programs, program):
     assert any(p.startswith("layer_0/linear_attn/core") for p in paths), sorted(paths)[:40]
 
 
+def _instructions_of_shape(hlo: str, shape: str):
+    """(opcode, name, line) of every instruction, in any computation, whose
+    result holds an array of exactly ``shape`` (``"8,64,128,128"``)."""
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m and shape in _HLO_ARRAY.findall(m.group("type")):
+            name = line.split(" = ")[0].split()[-1].lstrip("%")
+            found.append((m.group("op"), name, line.strip()))
+    return found
+
+
 def test_the_tick_reads_its_lanes_rows_under_the_core(linear_programs):
-    """The tick's loop of row reads that gathers its 8 lanes' matrix state
-    (``f32[8,64,128,128]``) is the core's own: every fusion of that size lies
-    under ``layer_0/linear_attn/core`` in the program's map, so the device
-    time of the gather is counted with the rule's."""
+    """The tick's 8 lanes are slots 0-7, so their rows of matrix state are
+    read where they lie in the ``f32[9,64,128,128]`` buffer: by two fusions,
+    one that makes both sums over d_k from one read (a fusion of two results)
+    and one that writes the next state back in place (its root a
+    dynamic-update-slice of the whole buffer), both under
+    ``layer_0/linear_attn/core`` in the program's map."""
     from fedml_tpu.obs import programs
     compiled, _ = linear_programs
     hlo = compiled["decode_step"].as_text()
+    buffer = {name for _, name, _ in _instructions_of_shape(hlo, "9,64,128,128")}
+    readers = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) fusion\((.*?)\), kind=", line)
+        if m and set(re.findall(r"%([\w.\-]+)", m.group(3))) & buffer:
+            readers[m.group(1)] = m.group(2)
     rows = programs.parse_hlo(hlo)
-    fusions = [line.split(" = ")[0].split()[-1].lstrip("%")
-               for op, line in _pool_sized_instructions(hlo, 8 * 64 * 128 * 128) if op == "fusion"]
-    assert any("dynamic-slice" in name for name in fusions), fusions
-    assert all(rows[name]["path"].startswith("layer_0/linear_attn/core") for name in fusions), \
-        {name: rows[name]["path"] for name in fusions}
+    assert all(rows[name]["path"].startswith("layer_0/linear_attn/core") for name in readers), \
+        {name: rows[name]["path"] for name in readers}
+    assert sorted(readers.values()) == sorted([
+        "(f32[8,64,128]{2,1,0:T(8,128)}, f32[8,64,128]{2,1,0:T(8,128)})",
+        "f32[9,64,128,128]{3,2,1,0:T(8,128)}"]), readers
+    update = [name for name, kind in readers.items() if kind.startswith("f32[9,")]
+    assert "dynamic-update-slice" in update[0], readers
+
+
+@pytest.mark.parametrize("leaf, shapes, allowed", [
+    ("linear_state", ("8,64,128,128", "9,64,128,128"), set()),
+    # the convolution's bfloat16 rows are re-laid out inside its fusions
+    ("linear_conv", ("8,3,16384", "9,3,16384"), {"copy"}),
+], ids=["matrix state", "convolution rows"])
+def test_the_tick_moves_no_rows_of_state(linear_programs, leaf, shapes, allowed):
+    """No ``while``, loop of row reads, gather, scatter, transpose or copy of
+    the size of the tick's rows or of the whole buffer in the tick: the rows
+    are sliced and updated in place."""
+    compiled, _ = linear_programs
+    hlo = compiled["decode_step"].as_text()
+    found = [(op, name, line[:200]) for shape in shapes
+             for op, name, line in _instructions_of_shape(hlo, shape)]
+    assert found, leaf
+    moving = [(op, line) for op, name, line in found
+              if op in {"while", "gather", "scatter", "transpose", "copy", "dynamic-slice"} - allowed
+              or "dynamic-slice" in name]
+    assert not moving, (leaf, moving)
 
 
 # -- the instruction -> module map of the programs the chip runs ----------------

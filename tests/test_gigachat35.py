@@ -10,6 +10,7 @@ through the paged programs: chunks of several blocks, ticks, the reset, the
 hold and the trash row, (d) through the engine: the single-request path, a
 slot used again, spans, gauges and ``kv_stats()``, and the planted faults."""
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -101,6 +102,28 @@ def test_chunked_form_is_the_recurrence(s, block):
         want.append(ot)
     assert rel(o, jnp.stack(want, axis=2)) < 1e-5
     assert rel(s1, state) < 1e-5
+
+
+def _step_two_passes(state, q, k, v, beta, g):
+    """The rule as written: decay, correct, then read the corrected state for
+    ``o`` (a pass over the state for each sum)."""
+    state = state * jnp.exp(g)[..., None, None]
+    kv = jnp.sum(state * k[..., :, None], axis=-2)
+    state = state + k[..., :, None] * ((v - kv) * beta[..., None])[..., None, :]
+    return state, jnp.sum(state * q[..., :, None], axis=-2)
+
+
+def test_the_step_reads_the_state_once_for_both_sums_and_is_the_rule():
+    """``step`` re-associated (``o = e^g S^T q + (k^T q) delta``) against the
+    rule as written, float32, from a state that is not zero, over steps."""
+    rng = np.random.default_rng(11)
+    q, k, v, beta, g = _rule_inputs(rng, 3, 4, 6, 16, 8)
+    state = want = jnp.asarray(rng.normal(size=(3, 4, 16, 8)), jnp.float32)
+    for t in range(6):
+        x = (q[:, :, t], k[:, :, t], v[:, :, t], beta[:, :, t], g[:, :, t])
+        state, o = la.step(state, *x)
+        want, o_want = _step_two_passes(want, *x)
+        assert rel(o, o_want) < 1e-6 and rel(state, want) < 1e-6
 
 
 def test_padding_past_a_lane_leaves_its_state_alone():
@@ -358,16 +381,20 @@ def _tables():
     return tabs
 
 
-def _tick(pm, base, loras, pool, toks, poss, live, tabs):
-    """One tick of all lanes as the engine's tick program calls the model: a
-    lane that is not live gets the all-trash table and the trash row."""
+def _tick(pm, base, loras, pool, toks, poss, live, tabs, gather=False):
+    """One tick of all lanes as the engine's tick program calls the model: lane
+    i is slot i's row, read and written where it lies, and a lane that is not
+    live gets the all-trash table and ``seq_lens`` 0 (it holds its row).
+    ``gather``: the lanes' rows addressed by index instead, a lane that is not
+    live sent to the trash row (gathered, stepped, scattered back)."""
     stacked = jax.tree_util.tree_map(lambda *rows: jnp.stack(rows), *loras)
     live = np.asarray(live)
+    rows = {"state_rows": jnp.asarray(np.where(live, np.arange(SLOTS), SLOTS), jnp.int32)} \
+        if gather else {"seq_lens": jnp.asarray(live, jnp.int32)}
     logits, mut = pm.apply(
         {"params": base, "lora": stacked, "cache": pool}, jnp.asarray(toks, jnp.int32)[:, None],
         decode=True, start_pos=jnp.asarray(poss, jnp.int32),
-        block_tables=jnp.asarray(np.where(live[:, None], tabs, 0)),
-        state_rows=jnp.asarray(np.where(live, np.arange(SLOTS), SLOTS), jnp.int32), mutable=["cache"])
+        block_tables=jnp.asarray(np.where(live[:, None], tabs, 0)), mutable=["cache"], **rows)
     return np.asarray(logits[:, 0]), mut["cache"]
 
 
@@ -381,8 +408,8 @@ def test_chunks_of_several_blocks_then_ticks_agree_by_logits(setting, small_bloc
     """Two requests on two adapters in slots 0 and 2: each prompt goes in
     over chunks of four blocks (the last one padded), the state carried from
     chunk to chunk and handed to the ticks; then both decode in ONE batch
-    whose middle lane is not live and writes the trash row.  Every position's
-    logits against the reference's full forward (the rule step by step)."""
+    whose middle lane is not live and holds its row.  Every position's logits
+    against the reference's full forward (the rule step by step)."""
     lcfg, base, _, _ = setting
     pm = _paged(lcfg)
     loras = [weights.make_lora(TINY, 5, index=i + 1) for i in range(3)]
@@ -407,9 +434,9 @@ def test_chunks_of_several_blocks_then_ticks_agree_by_logits(setting, small_bloc
     for r in (0, 2):
         want, _ = ref.logits(base, loras[r], jnp.asarray(seqs[r])[None], TINY)
         assert rel(got[r], want[0]) < 2 * TOL
-    # the trash row took the idle lane's writes; slot 1's rows were never touched
+    # the idle lane held slot 1's rows, and no tick addressed the trash row
     state = np.asarray(pool["layer_0"]["linear_attn"]["linear_state"])
-    assert not state[1].any() and state[SLOTS].any()
+    assert not state[1].any() and not state[SLOTS].any()
 
 
 def test_a_first_chunk_starts_from_zeros_whatever_its_row_holds(setting):
@@ -434,13 +461,13 @@ def test_a_first_chunk_starts_from_zeros_whatever_its_row_holds(setting):
     assert rel(again[32:], want[0][32:]) > 100 * TOL
 
 
-@pytest.mark.parametrize("address", ["the trash row", "its own row"])
+@pytest.mark.parametrize("address", ["the trash row", "its own row", "held in place"])
 def test_a_slot_between_two_chunks_rides_ticks_and_keeps_its_state(setting, address):
     """The hold: slot 1 has had its first chunk and waits for its second while
-    two ticks run for slot 0; its lane is not live and addresses the trash
-    row, so its second chunk reads what the first left.  Were the lane to
-    address its own row (the fault), the ticks' writes would be what the
-    second chunk reads."""
+    two ticks run for slot 0; its lane is not live and either holds its row in
+    place (the engine's tick) or addresses the trash row (the gather form), so
+    its second chunk reads what the first left.  Were the lane live on its own
+    row (the fault), the ticks' writes would be what the second chunk reads."""
     lcfg, base, _, _ = setting
     pm = _paged(lcfg)
     loras = [weights.make_lora(TINY, 5, index=i + 1) for i in range(3)]
@@ -454,13 +481,93 @@ def test_a_slot_between_two_chunks_rides_ticks_and_keeps_its_state(setting, addr
     for t in range(2):
         live = [True, address == "its own row", False]
         _, pool = _tick(pm, base, loras, pool, [running[40 + t], 0, 0], [40 + t, 0, 0], live,
-                        tabs if address == "the trash row" else np.where([[1], [0], [1]], tabs, 0))
+                        tabs if address != "its own row" else np.where([[1], [0], [1]], tabs, 0),
+                        gather=address == "the trash row")
     _chunks(pm, base, loras[1], pool, waiting, 60, 1, tabs[1], got, start=32)
     want, _ = ref.logits(base, loras[1], jnp.asarray(waiting)[None], TINY)
-    if address == "the trash row":
+    if address != "its own row":
         assert rel(got, want[0]) < 2 * TOL
     else:
         assert rel(got[32:], want[0][32:]) > 100 * TOL
+
+
+def _lanes_of_every_kind(lcfg, base, loras):
+    """A pool and a tick's inputs over the four kinds of row a tick meets:
+    slot 0 live mid-answer, slot 1 held between its two chunks, slot 2 free at
+    position 0 with its last request's state still in its row, and the trash
+    row with garbage in it."""
+    pm = _paged(lcfg)
+    rng = np.random.default_rng(17)
+    tabs = _tables()
+    pool = _empty_pool(pm, base, loras[0])
+    for slot, n, upto in ((0, 40, None), (1, 60, 32), (2, 36, None)):
+        pool = _chunks(pm, base, loras[slot], pool, rng.integers(1, 256, size=n), n, slot,
+                       tabs[slot], np.zeros((n, 256)), upto=upto)
+    pool = jax.tree_util.tree_map_with_path(
+        lambda path, p: p.at[SLOTS].set(3.0)
+        if _is(path, "linear_state") or _is(path, "linear_conv") else p, pool)
+    return pm, pool, ([17, 0, 0], [40, 32, 0], [True, False, False], tabs)
+
+
+def _linear_rows(pool):
+    return [(name, np.asarray(pool["layer_0"]["linear_attn"][name]))
+            for name in ("linear_state", "linear_conv")]
+
+
+def test_a_tick_by_slot_is_the_gather_step_scatter_form(setting, monkeypatch):
+    """The engine's tick (rows where they lie, ``seq_lens`` 0 or 1, the step
+    that reads the state once for both sums) against the form it replaced (the
+    lanes' rows gathered by index, idle lanes to the trash row, the rule in two
+    passes, scattered back), float32: the live lane's logits and rows agree to
+    1e-6, the held and the free lane's rows are both forms' input bit for bit,
+    and only the gather form writes the trash row."""
+    lcfg, base, _, _ = setting
+    loras = [weights.make_lora(TINY, 5, index=i + 1) for i in range(3)]
+    pm, pool, lanes = _lanes_of_every_kind(lcfg, base, loras)
+    by_slot, after = _tick(pm, base, loras, pool, *lanes)
+    monkeypatch.setattr(la, "step", _step_two_passes)
+    gathered, before = _tick(pm, base, loras, pool, *lanes, gather=True)
+    assert rel(by_slot[0], gathered[0]) < 1e-6
+    for (name, was), (_, now), (_, then) in zip(_linear_rows(pool), _linear_rows(after),
+                                                _linear_rows(before)):
+        assert rel(now[0], then[0]) < 1e-6 and not np.array_equal(now[0], was[0]), name
+        assert np.array_equal(now[1:], was[1:]) and np.array_equal(then[1:SLOTS], was[1:SLOTS]), name
+        assert not np.array_equal(then[SLOTS], was[SLOTS]), name
+
+
+@pytest.mark.parametrize("slot", [1, 2], ids=["held between two chunks", "free at position 0"])
+def test_a_lane_that_is_not_live_leaves_its_rows_bit_for_bit(setting, slot):
+    """``seq_lens`` 0: a lane's matrix state and convolution rows are what they
+    were, bit for bit, over two ticks, whatever its position (a free lane at
+    position 0 does not reset its row)."""
+    lcfg, base, _, _ = setting
+    loras = [weights.make_lora(TINY, 5, index=i + 1) for i in range(3)]
+    pm, pool, (toks, poss, live, tabs) = _lanes_of_every_kind(lcfg, base, loras)
+    after = pool
+    for t in range(2):
+        _, after = _tick(pm, base, loras, after, toks, [poss[0] + t] + poss[1:], live, tabs)
+    for (name, was), (_, now) in zip(_linear_rows(pool), _linear_rows(after)):
+        assert was[slot].any() and np.array_equal(now[slot], was[slot]), name
+
+
+def test_the_state_kept_in_bfloat16_still_reaches_the_rows(setting):
+    """The calibration's first planted fault under the tick by slot: the rows
+    are bfloat16, the held slot's row is the bfloat16 rounding of the sound
+    one and the tick holds it bit for bit, and the live slot's row moves off
+    the sound one by more than float32 rounding."""
+    import calibrate_gigachat35 as cal
+    lcfg, base, _, _ = setting
+    loras = [weights.make_lora(TINY, 5, index=i + 1) for i in range(3)]
+    rows = {}
+    for fault in (None, "the matrix state kept in bfloat16 between steps"):
+        with cal.FAULTS[fault]() if fault else contextlib.nullcontext():
+            pm, pool, lanes = _lanes_of_every_kind(lcfg, base, loras)
+            _, after = _tick(pm, base, loras, pool, *lanes)
+        rows[fault] = [np.asarray(p["layer_0"]["linear_attn"]["linear_state"]) for p in (pool, after)]
+    (sound, sound_after), (held, held_after) = rows[None], rows[fault]
+    assert held.dtype == jnp.bfloat16 and np.array_equal(held_after[1], held[1])
+    assert 1e-4 < rel(held[1].astype(np.float32), sound[1]) < 1e-2
+    assert rel(held_after[0].astype(np.float32), sound_after[0]) > 100 * 1e-6
 
 
 # -- (d) through the engine ---------------------------------------------------------------

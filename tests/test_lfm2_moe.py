@@ -238,16 +238,20 @@ def _tables():
     return tabs
 
 
-def _tick(pm, base, loras, pool, toks, poss, live, tabs):
-    """One tick of all lanes as the engine's tick program calls the model: a
-    lane that is not live gets the all-trash table and the trash row."""
+def _tick(pm, base, loras, pool, toks, poss, live, tabs, gather=False):
+    """One tick of all lanes as the engine's tick program calls the model: lane
+    i is slot i's row, read and written where it lies, and a lane that is not
+    live gets the all-trash table and ``seq_lens`` 0 (it holds its row).
+    ``gather``: the lanes' rows addressed by index instead, a lane that is not
+    live sent to the trash row."""
     stacked = jax.tree_util.tree_map(lambda *rows: jnp.stack(rows), *loras)
     live = np.asarray(live)
+    rows = {"state_rows": jnp.asarray(np.where(live, np.arange(SLOTS), SLOTS), jnp.int32)} \
+        if gather else {"seq_lens": jnp.asarray(live, jnp.int32)}
     logits, mut = pm.apply(
         {"params": base, "lora": stacked, "cache": pool}, jnp.asarray(toks, jnp.int32)[:, None],
         decode=True, start_pos=jnp.asarray(poss, jnp.int32),
-        block_tables=jnp.asarray(np.where(live[:, None], tabs, 0)),
-        state_rows=jnp.asarray(np.where(live, np.arange(SLOTS), SLOTS), jnp.int32), mutable=["cache"])
+        block_tables=jnp.asarray(np.where(live[:, None], tabs, 0)), mutable=["cache"], **rows)
     return np.asarray(logits[:, 0]), mut["cache"]
 
 
@@ -310,13 +314,14 @@ def test_a_first_chunk_starts_from_zeros_whatever_its_row_holds(setting):
     assert rel(again[16:], want[0][16:]) > 100 * TOL
 
 
-@pytest.mark.parametrize("address", ["the trash row", "its own row"])
+@pytest.mark.parametrize("address", ["the trash row", "its own row", "held in place"])
 def test_a_slot_between_two_chunks_rides_ticks_and_keeps_its_state(setting, address):
     """The hold: slot 1 has had its first chunk and waits for its second while
-    two ticks run for slot 0; its lane is not live and addresses the trash
-    row, so its second chunk reads what the first left and its logits are
-    those of a slot that rode no tick.  Were the lane to address its own row
-    (the fault), the ticks' garbage would be what the second chunk reads."""
+    two ticks run for slot 0; its lane is not live and either holds its row in
+    place (the engine's tick) or addresses the trash row (the gather form), so
+    its second chunk reads what the first left and its logits are those of a
+    slot that rode no tick.  Were the lane live on its own row (the fault),
+    the ticks' garbage would be what the second chunk reads."""
     lcfg, base, _, _ = setting
     pm = _paged(lcfg)
     loras = [weights.make_lora(TINY, 5, index=i + 1) for i in range(3)]
@@ -330,13 +335,40 @@ def test_a_slot_between_two_chunks_rides_ticks_and_keeps_its_state(setting, addr
     for t in range(2):
         live = [True, address == "its own row", False]
         _, pool = _tick(pm, base, loras, pool, [running[20 + t], 0, 0], [20 + t, 0, 0], live,
-                        tabs if address == "the trash row" else np.where([[1], [0], [1]], tabs, 0))
+                        tabs if address != "its own row" else np.where([[1], [0], [1]], tabs, 0),
+                        gather=address == "the trash row")
     _chunks(pm, base, loras[1], pool, waiting, 30, 1, tabs[1], got, start=16)
     want, _ = ref.logits(base, loras[1], jnp.asarray(waiting)[None], TINY)
-    if address == "the trash row":
+    if address != "its own row":
         assert rel(got, want[0]) < 2 * TOL
     else:
         assert rel(got[16:], want[0][16:]) > 100 * TOL
+
+
+@pytest.mark.parametrize("slot", [1, 2], ids=["held between two chunks", "free at position 0"])
+def test_a_lane_that_is_not_live_leaves_its_rows_bit_for_bit(setting, slot):
+    """``seq_lens`` 0: every convolution layer's row of a lane that is not live
+    is what it was, bit for bit, over two ticks, whatever its position (a free
+    lane at position 0 does not reset its row); the trash row is not
+    touched."""
+    lcfg, base, _, _ = setting
+    pm = _paged(lcfg)
+    loras = [weights.make_lora(TINY, 5, index=i + 1) for i in range(3)]
+    rng = np.random.default_rng(19)
+    tabs = _tables()
+    pool = _empty_pool(pm, base, loras[0])
+    for r, n, upto in ((0, 20, None), (1, 30, 16), (2, 18, None)):
+        pool = _chunks(pm, base, loras[r], pool, rng.integers(1, 256, size=n), n, r, tabs[r],
+                       np.zeros((n, 256)), upto=upto)
+    pool = jax.tree_util.tree_map_with_path(
+        lambda path, p: p.at[SLOTS].set(3.0) if _is(path, "conv_state") else p, pool)
+    after = pool
+    for t in range(2):
+        _, after = _tick(pm, base, loras, after, [9, 0, 0], [20 + t, 16, 0], [True, False, False], tabs)
+    for name in ("layer_0", "layer_1", "layer_3", "layer_4"):
+        was, now = (np.asarray(p[name]["conv"]["conv_state"]) for p in (pool, after))
+        assert was[slot].any() and np.array_equal(now[slot], was[slot]), name
+        assert np.array_equal(now[SLOTS], was[SLOTS]) and not np.array_equal(now[0], was[0]), name
 
 
 # -- (d) through the engine ---------------------------------------------------------------

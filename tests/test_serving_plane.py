@@ -182,6 +182,83 @@ def test_streaming_preserves_multibyte_utf8():
         srv.stop()
 
 
+class _SpacedIds:
+    """Ids as words joined by spaces, the first of a text without one (the
+    way a tokenizer spells a piece by its neighbour); counts the ids each
+    ``decode`` reads."""
+
+    def __init__(self):
+        self.longest = 0
+
+    def decode(self, ids):
+        self.longest = max(self.longest, len(ids))
+        return " ".join(str(int(i)) for i in ids)
+
+
+def _whole_text_deltas(tok, ids):
+    """The rule ``IncrementalText`` keeps: decode every id so far at every
+    token and give out what the text, less a trailing incomplete UTF-8
+    sequence, has grown by; at the end, what is still held back."""
+    deltas, sent = [], 0
+    for n in range(1, len(ids) + 1):
+        clean = tok.decode(ids[:n]).rstrip("\ufffd")
+        deltas.append(clean[sent:])
+        sent = max(sent, len(clean))
+    return deltas, tok.decode(ids)[sent:]
+
+
+def _utf8_ids(rng, n):
+    """Bytes of ASCII, two-, three- and four-byte characters, with a lone
+    continuation byte or a cut-off lead byte now and then."""
+    out = []
+    while len(out) < n:
+        r = rng.random()
+        if r < 0.4:
+            out += list(chr(int(rng.integers(32, 127))).encode())
+        elif r < 0.9:
+            cp = int(rng.choice([rng.integers(0xA0, 0x800), rng.integers(0x800, 0xD800),
+                                 rng.integers(0x10000, 0x110000)]))
+            out += list(chr(cp).encode())
+        elif r < 0.95:
+            out.append(int(rng.integers(0x80, 0xC0)))
+        else:
+            out.append(int(rng.choice([0xC3, 0xE2, 0xF0])))
+    return out[:n]
+
+
+@pytest.mark.parametrize("kind", ["bytes", "spaced_ids"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_incremental_text_gives_the_whole_texts_deltas(kind, seed):
+    """Token by token, the windowed stream gives out exactly what decoding
+    every id at every token would, multi-byte characters held back until
+    they complete, and the same tail at the end."""
+    from fedml_tpu.serving.templates import ByteTokenizer
+    from fedml_tpu.serving.templates.openai_compat import IncrementalText
+
+    rng = np.random.default_rng(seed)
+    if kind == "bytes":
+        tok, ids = ByteTokenizer(), _utf8_ids(rng, 600)
+    else:
+        tok, ids = _SpacedIds(), [int(i) for i in rng.integers(0, 20000, 600)]
+    want, want_tail = _whole_text_deltas(tok, ids)
+    stream = IncrementalText(tok)
+    assert [stream.push(t) for t in ids] == want
+    assert stream.tail() == want_tail
+    if kind == "bytes":
+        assert "".join(want) + want_tail == tok.decode(ids)
+
+
+def test_incremental_text_reads_a_window_of_ids():
+    """A token's delta decodes a few ids, not the answer so far."""
+    from fedml_tpu.serving.templates.openai_compat import IncrementalText
+
+    tok = _SpacedIds()
+    stream = IncrementalText(tok)
+    text = "".join(stream.push(t) for t in range(4000))
+    assert text == " ".join(str(t) for t in range(4000))
+    assert tok.longest <= IncrementalText.CONTEXT + 1
+
+
 @pytest.mark.slow
 def test_kv_cache_decode_matches_full_forward():
     """Decode-mode (prefill + cached single-token steps) must reproduce the
